@@ -742,6 +742,8 @@ if __name__ == "__main__":
     ap.add_argument("--json-out", default="BENCH_serve.json",
                     help="machine-readable report path ('' disables)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     run(args.arch, full=args.full, paged=args.paged, mesh=args.mesh,
         json_out=args.json_out)

@@ -13,6 +13,8 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=50)
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     def want(name):
         return only is None or name in only

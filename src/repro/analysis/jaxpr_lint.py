@@ -40,10 +40,7 @@ from dataclasses import dataclass
 import jax
 import numpy as np
 
-try:                                     # moved in newer jax releases
-    from jax.core import ClosedJaxpr, Jaxpr
-except ImportError:                      # pragma: no cover - version shim
-    from jax.extend.core import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 # cache-layout ops that must never touch a cache-sized operand in a serving
 # step (each one is a full-cache copy per token / per chunk)
@@ -51,8 +48,8 @@ LAYOUT_PRIMS = ("transpose", "pad", "copy", "convert_element_type")
 
 # host-boundary primitives that must not appear inside a jitted serving step
 CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "host_callback", "outside_call",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback", "host_callback", "outside_call",
 })
 
 

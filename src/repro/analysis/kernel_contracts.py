@@ -13,7 +13,8 @@ Checks (the kernel half of the serving contract):
 
 * ``vmem-budget`` — per-program VMEM working-set estimate:
   ``2 x (input blocks + output blocks) + scratch`` (the factor 2 is
-  Mosaic's double-buffered pipeline), against a per-core budget, plus a
+  Mosaic's double-buffered pipeline), each block counted in its tiled
+  layout (``tiled_bytes``), against a per-core budget, plus a
   per-operand block cap — the class of bug the decode kernel's
   ``_fold_factor`` 2 MB K/V cap exists to prevent, caught at analysis time
   instead of as a Mosaic OOM on hardware.
@@ -120,22 +121,41 @@ def _dim_semantics(compiler_params):
     return tuple(ds) if ds is not None else None
 
 
+def tiled_bytes(shape, dtype) -> int:
+    """Bytes a VMEM block of ``shape`` occupies in Mosaic's tiled layout:
+    the last dim pads to 128 lanes; the second-minor dim pads to the dtype's
+    sublane tile (8 rows of 32 bits: 8 fp32, 16 bf16, 32 int8), or, when it
+    is shorter than a tile, to the next power of two (Mosaic's small
+    tilings). So an all-heads scale block ``(rows, hkv)`` costs ``rows``
+    full lane rows, and a ``(…, hkv, dk)`` K/V block costs little more than
+    its data."""
+    itemsize = np.dtype(dtype).itemsize
+    dims = [int(d) for d in shape] or [1]
+    dims[-1] = -(-dims[-1] // 128) * 128
+    if len(dims) >= 2:
+        tile = 8 * max(1, 4 // itemsize)
+        r = dims[-2]
+        dims[-2] = (-(-r // tile) * tile if r >= tile
+                    else 1 << max(0, (r - 1).bit_length()))
+    return int(np.prod(dims, dtype=np.int64)) * itemsize
+
+
 def _block_info(spec, shape, dtype, index_map_default=None) -> BlockInfo:
     bshape = tuple(getattr(spec, "block_shape", None) or shape)
     bshape = tuple(int(d) for d in bshape if d is not None)
-    nbytes = int(np.prod(bshape, dtype=np.int64)) * np.dtype(dtype).itemsize
-    return BlockInfo(bshape, str(np.dtype(dtype)), nbytes, _mem_space(spec),
+    space = _mem_space(spec)
+    nbytes = (int(np.prod(bshape, dtype=np.int64)) * np.dtype(dtype).itemsize
+              if space == "smem" else tiled_bytes(bshape, dtype))
+    return BlockInfo(bshape, str(np.dtype(dtype)), nbytes, space,
                      getattr(spec, "index_map", index_map_default))
 
 
 def _scratch_bytes(scratch_shapes) -> int:
     total = 0
     for s in jax.tree_util.tree_leaves(scratch_shapes):
-        shape = tuple(getattr(s, "shape", ()))
         dt = getattr(s, "dtype", None)
         if dt is not None:
-            total += (int(np.prod(shape, dtype=np.int64))
-                      * np.dtype(dt).itemsize)
+            total += tiled_bytes(tuple(getattr(s, "shape", ())), dt)
     return total
 
 

@@ -325,8 +325,8 @@ def append_attention(q, k, v, index, lengths, *, norm_kind, norm_params,
         if k_scale is not None:
             ks = jax.lax.dynamic_slice_in_dim(k_scale, j * kc, kc, axis=1)
             vs = jax.lax.dynamic_slice_in_dim(v_scale, j * kc, kc, axis=1)
-            k_blk = CL.dequant_block(k_blk, ks, q.dtype)
-            v_blk = CL.dequant_block(v_blk, vs, q.dtype)
+            k_blk = CL.dequantize_kv(k_blk, ks, q.dtype)
+            v_blk = CL.dequantize_kv(v_blk, vs, q.dtype)
         return k_blk, v_blk
 
     return _kv_walk(q, index, lengths, gather, hi, kc, k.shape[2],
@@ -398,8 +398,8 @@ def paged_attention(q, kp, vp, page_table, index, lengths, *, norm_kind,
         pid = jnp.maximum(page_table[:, j], 0)               # (b,)
         k_blk, v_blk = kp[pid], vp[pid]                      # (b, ps, hkv, dk)
         if k_scale is not None:
-            k_blk = CL.dequant_block(k_blk, k_scale[pid], q.dtype)
-            v_blk = CL.dequant_block(v_blk, v_scale[pid], q.dtype)
+            k_blk = CL.dequantize_kv(k_blk, k_scale[pid], q.dtype)
+            v_blk = CL.dequantize_kv(v_blk, v_scale[pid], q.dtype)
         return k_blk, v_blk
 
     return _kv_walk(q, index, lengths, gather, hi, ps, kp.shape[2],
@@ -425,8 +425,8 @@ def decode_attention(q, k, v, index, *, norm_kind, norm_params, window=0,
     L_, hkv = k.shape[1], k.shape[2]
     g = H // hkv
     if k_scale is not None:
-        k = CL.dequant_block(k, k_scale, q.dtype)
-        v = CL.dequant_block(v, v_scale, q.dtype)
+        k = CL.dequantize_kv(k, k_scale, q.dtype)
+        v = CL.dequantize_kv(v, v_scale, q.dtype)
     qg = q.reshape(b, hkv, g, dk)
     s = jnp.einsum("bhgd,bchd->bhgc", qg, k,
                    preferred_element_type=jnp.float32)

@@ -51,7 +51,6 @@ import dataclasses
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ServeConfig
@@ -136,11 +135,11 @@ class MeshPlan:
 
     # ---------------------------------------------------------- wrapping ----
     def wrap(self, fn, in_specs, out_specs):
-        """shard_map ``fn`` over the plan's mesh. ``check_rep=False``:
+        """shard_map ``fn`` over the plan's mesh. ``check_vma=False``:
         the bodies contain Pallas launches and data-dependent gathers
         whose replication the checker cannot infer."""
-        return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def put(self, tree, shardings):
         return jax.device_put(tree, shardings)

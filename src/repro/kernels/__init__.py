@@ -1,17 +1,1 @@
 # Compute hot-spot kernels (<name>/kernel.py + ops.py + ref.py per op).
-"""Version compatibility for the Pallas TPU API surface.
-
-jax renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``; this
-container ships the older name. All kernels build their compiler params
-through :func:`tpu_compiler_params` so they run on either version.
-"""
-from __future__ import annotations
-
-from jax.experimental.pallas import tpu as pltpu
-
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
-
-def tpu_compiler_params(**kwargs):
-    return _CompilerParams(**kwargs)

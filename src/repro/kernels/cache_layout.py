@@ -2,9 +2,14 @@
 
 The decode (``consmax_decode``) and prefill (``consmax_prefill``) kernels
 block the model's KV-cache layout ``(b, L, hkv, dk)`` (or the page pool's
-``(P, ps, hkv, dk)``) *directly* — the hkv axis is a unit grid dimension in
-the BlockSpec, so no per-step ``swapaxes`` copy of the cache is ever
-materialized. Everything both kernel families agree on lives here:
+``(P, ps, hkv, dk)``) *directly*: one grid program covers a block of rows
+of **all** hkv heads — K/V blocks ``(…, rows, hkv, dk)``, scale blocks
+``(…, rows, hkv)`` — and loops over the heads in its body. The last two
+block dims are then the full ``(hkv, dk)`` / ``(rows, hkv)`` extents, which
+is what Mosaic's tiling rule accepts on the TPU (a unit hkv block in the
+second-minor dim is refused whenever 1 < hkv). No per-step ``swapaxes``
+copy of the cache is ever traced. Everything both kernel families agree
+on lives here:
 
 * ``divisor_block`` — pick a block size that tiles the cache axis exactly,
   so blocking never needs a full-cache ``jnp.pad`` copy either.
@@ -12,7 +17,11 @@ materialized. Everything both kernel families agree on lives here:
   one KV head into the q rows (row = position * g + group-head, i.e.
   position-major), so a chunk's score tile is ``(c*g, bk)``-shaped for the
   MXU without materializing repeated K/V.
-* ``tile_head_params`` — per-row beta/gamma matching that folding.
+* ``head_rows`` — per-row beta/gamma matching that folding, read from the
+  ``(H,)`` SMEM parameter vector inside a kernel body.
+* ``kv_head`` / ``consmax_partial`` — one KV head's (dequantized) tile out
+  of an all-heads block, and one block's ``p @ v`` partial: the body every
+  serving kernel runs once per head.
 * ``kv_mask`` — the one causal/length/window mask formula shared by the
   kernels and the jnp walks (``core.attention._kv_walk``): a query at
   absolute position ``qpos`` sees cache row ``kpos`` iff ``kpos < kv_len``,
@@ -103,16 +112,42 @@ def unfold_gqa(out: jnp.ndarray, b: int, c: int, H: int) -> jnp.ndarray:
     return out.reshape(b, hkv, c, g, dk).swapaxes(1, 2).reshape(b, c, H, dk)
 
 
-def tile_head_params(beta: jnp.ndarray, gamma: jnp.ndarray, hkv: int,
-                     c: int = 1) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(H,) per-head beta/gamma -> (hkv, c*g) rows matching ``fold_gqa``."""
-    g = beta.shape[0] // hkv
+def head_rows(ref, h: int, g: int, rows: int):
+    """(rows, 1) fp32 column of the per-head parameter in SMEM ``ref`` (H,)
+    for KV head ``h``: row ``r`` of a ``fold_gqa`` tile (position-major, so
+    decode's g rows too) belongs to query head ``h*g + r % g``. Built from
+    scalar reads and selects — the values are copied, never recomputed, so
+    every kernel sees exactly the per-head parameters."""
+    col = jnp.full((rows, 1), ref[h * g], jnp.float32)
+    if g > 1:
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) % g
+        for j in range(1, g):
+            col = jnp.where(r == j, ref[h * g + j], col)
+    return col
 
-    def tile(p):
-        p = p.reshape(hkv, 1, g).astype(jnp.float32)
-        return jnp.broadcast_to(p, (hkv, c, g)).reshape(hkv, c * g)
 
-    return tile(beta), tile(gamma)
+def kv_head(x_ref, s_ref, i: int, h: int, dtype):
+    """KV head ``h`` of leading block row ``i`` of an all-heads K/V block
+    ``(n, rows, hkv, dk)`` as a ``(rows, dk)`` tile in ``dtype`` —
+    dequantized in VMEM when its ``(n, rows, hkv)`` scale block ``s_ref``
+    rides along (None = cache stored as-is)."""
+    if s_ref is None:
+        return x_ref[i, :, h].astype(dtype)
+    return dequant_block(x_ref[i, :, h], s_ref[i, :, h:h + 1], dtype)
+
+
+def consmax_partial(q, k, v, mask, beta, gamma, *, scale: float,
+                    softcap: float, merged: bool):
+    """One KV block's ConSmax partial ``p @ v`` in fp32: q (m, dk), k/v
+    (rows, dk), ``mask`` (m, rows), beta/gamma (m, 1). No running max and no
+    denominator, so partials of different blocks combine by addition."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if softcap > 0:
+        s = softcap * jnp.tanh(s / softcap)
+    p = jnp.where(mask, consmax_weights(s, beta, gamma, merged), 0.0)
+    return jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 def kv_mask(qpos, kpos, kv_len, window: int):
@@ -302,11 +337,11 @@ def dequantize_kv(q, scale, out_dtype=jnp.float32):
 
 
 def dequant_block(x, scale, out_dtype):
-    """In-kernel per-block dequant: ``x`` a (..., rows, dk) VMEM tile,
-    ``scale`` its (..., rows) fp32 scales. Identical arithmetic to
+    """In-kernel per-block dequant: ``x`` a (rows, dk) VMEM tile, ``scale``
+    its (rows, 1) fp32 scale column. Identical arithmetic to
     ``dequantize_kv`` (f32 multiply, then cast) so the Pallas kernels and
     the jnp oracles round-trip bit-identically."""
-    return (x.astype(jnp.float32) * scale[..., None]).astype(out_dtype)
+    return (x.astype(jnp.float32) * scale).astype(out_dtype)
 
 
 def block_scale_rows(s, bk_eff: int, n_blocks: int):

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _kernel(beta_ref, gamma_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, *,
             scale: float, causal: bool, window: int, softcap: float,
@@ -125,7 +123,7 @@ def consmax_attention(q, k, v, beta, gamma, *, causal: bool = True,
         out_shape=jax.ShapeDtypeStruct((b, nh, nq * bq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
     )(beta2, gamma2, q, k, v)

@@ -13,23 +13,26 @@ cross-shard ordering. This is the decode-time analogue of flash-decoding's
 split-KV, but without the log-sum-exp combine step softmax forces.
 
 Both variants block the model's cache layout **directly** — contiguous
-``(b, L, hkv, dk)`` rows or the shared ``(P, ps, hkv, dk)`` page pool — with
-the hkv axis as a unit grid dimension in the BlockSpec, so a decode step
-never materializes a transposed (or padded) copy of the cache. The block
-size is chosen by ``cache_layout.divisor_block`` to tile L exactly. Layout
-folding, the mask formula, and the ConSmax weights are shared with the
+``(b, L, hkv, dk)`` rows or the shared ``(P, ps, hkv, dk)`` page pool — so a
+decode step never traces a transposed (or padded) copy of the cache. One
+grid program covers a KV shard (or page) of **all** hkv heads: K/V blocks
+``(…, bk, hkv, dk)``, scale blocks ``(…, bk, hkv)``, per-head beta/gamma
+read from SMEM, and a static loop over the heads in the body. The last two
+block dims are therefore full extents, which Mosaic's TPU tiling rule
+accepts at every width. The block size is chosen by
+``cache_layout.divisor_block`` to tile L exactly. Layout folding, the mask
+formula, the per-head body and the ConSmax weights are shared with the
 prefill kernel via ``kernels/cache_layout.py``.
 
-Per (batch, kv-head, kv-shard) program:
+Per (slot, kv-head, kv-shard) — the head a static loop inside a program:
 
     s = q @ k^T * scale            (MXU; q is the g-row GQA group)
     p = exp(s - beta) / gamma      (VPU; masked by per-slot length)
     o = p @ v                      (MXU; partial, summed across shards later)
 
 **Fill bounding** (``fill_bound=True``, the default): serving caches are
-allocated at capacity but filled to the per-slot ``lengths``, and the old
-grid paid a program (and a partials slot) for every capacity shard. The KV
-grid axis is now clamped to the traced *batch-max* live shard count
+allocated at capacity but filled to the per-slot ``lengths``. The KV grid
+axis is clamped to the traced *batch-max* live shard count
 (``cache_layout.live_blocks`` — a value, so one compiled step serves every
 fill level), each program ``pl.when``-skips shards the per-slot lengths (or
 the sliding window) already zero — writing exact zeros to its partial
@@ -42,29 +45,21 @@ and no denominator term, so "skip" is literally "contribute zero".
 baseline for the benchmark's fill sweep.
 
 The contiguous bounded kernel additionally *folds the batch into the
-block*: a decode program's per-shard compute is a (g, bk) score tile — so
-small that per-program pipeline overhead (block DMA setup on TPU, the
-full-operand grid sweep in interpret mode) dominates the actual math. The
-bounded grid is therefore ``(b/bf, hkv, ns_live)`` with ``bf`` slots
-(largest divisor of b <= 8, VMEM-bounded) stacked in every block: the
-per-program overhead is amortized ``bf``-fold and the batched dot is
-bit-identical to ``bf`` per-slot dots. The ``pl.when`` skip then fires per
-(slot-group, shard) — a shard past every folded slot's fill (or behind
-every window) still writes zeros without computing — and per-slot raggedness
-inside a live group is handled by the same length mask as before, which is
-exactly what the capacity sweep computed for those lanes. The paged variant
-keeps the per-slot grid: its page-table gather is a per-(slot, page) index
-map that a folded block cannot express.
+block*: ``bf`` slots (largest divisor of b <= 8, VMEM-bounded) share every
+program, so the per-program overhead (block DMA setup on TPU, the
+full-operand grid sweep in interpret mode) is amortized ``bf``-fold. Each
+folded slot still runs its own 2-D dots, so the result is bit-identical to
+the capacity sweep (``bf = 1``). The ``pl.when`` skip fires per
+(slot-group, shard); per-slot raggedness inside a live group is handled by
+the length mask. The paged variant keeps the per-slot grid: its page-table
+gather is a per-(slot, page) index map that a folded block cannot express.
 
 GQA is folded into the q rows: the g = n_heads/n_kv_heads query heads that
-share one KV head form the (g, d) left operand, so the score tile is (g, bk)
-— well shaped for the MXU even though a decode step has a single token.
+share one KV head form the (g, d) left operand, so the score tile is (g, bk).
 
-VMEM per program @ (g, bk, d) = (8, 256, 128) fp32: q g·d·4 + k/v 2·bk·d·4 +
-s/p 2·g·bk·4 + out g·d·4 ≈ 0.3 MB — tiny; the Mosaic pipeline double-buffers
-KV shards from HBM. The folded bounded kernel multiplies the block set by
-``bf``, and ``_fold_factor`` caps the fold so each K/V block stays under
-2 MB — comfortably double-bufferable.
+VMEM per program: K/V blocks ``bf·bk·hkv·d`` each plus a (bf, hkv, g, d)
+q/out block; ``_fold_factor`` caps the fold so each K/V block stays under
+2 MB counted at fp32 — comfortably double-bufferable.
 """
 from __future__ import annotations
 
@@ -76,95 +71,71 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 from repro.kernels import cache_layout as CL
 
 
-def _kernel(len_ref, beta_ref, gamma_ref, q_ref, k_ref, v_ref, *rest,
-            scale: float, window: int, softcap: float, bk: int, g: int,
-            merged: bool):
-    *scale_refs, o_ref = rest                        # quantized KV: (ks, vs)
-    n = len_ref[0, 0]                                # valid kv count (<= L)
-    q = q_ref[0, 0]                                  # (g, d)
-    if scale_refs:                                   # dequant per-block in
-        ks_ref, vs_ref = scale_refs                  # VMEM — HBM stays narrow
-        k = CL.dequant_block(k_ref[0, :, 0], ks_ref[0, :, 0], q.dtype)
-        v = CL.dequant_block(v_ref[0, :, 0], vs_ref[0, :, 0], q.dtype)
-    else:
-        k = k_ref[0, :, 0].astype(q.dtype)           # (bk, d) — cache layout
-        v = v_ref[0, :, 0].astype(q.dtype)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if softcap > 0:
-        s = softcap * jnp.tanh(s / softcap)
-
-    kpos = pl.program_id(2) * bk + jax.lax.broadcasted_iota(
-        jnp.int32, (g, bk), 1)
-    mask = CL.kv_mask(n - 1, kpos, n, window)        # decode row sits at n-1
-
-    p = CL.consmax_weights(s, beta_ref[0][:, None], gamma_ref[0][:, None],
-                           merged)
-    p = jnp.where(mask, p, 0.0)
-
-    o_ref[0, 0, 0] = jax.lax.dot_general(            # independent partial
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-
-def _folded_kernel(len_ref, beta_ref, gamma_ref, q_ref, k_ref, v_ref, *rest,
-                   scale: float, window: int, softcap: float, bk: int,
-                   g: int, merged: bool, bf: int):
-    """The fill-bounded contiguous kernel: ``bf`` slots per block, so the
-    per-program overhead is paid once per (slot-group, head, shard) instead
-    of once per (slot, head, shard). The batched dots are bit-identical to
-    ``bf`` per-slot dots; dead lanes inside a live group are masked to the
-    exact zeros the capacity sweep computed for them."""
-    *scale_refs, o_ref = rest                        # quantized KV: (ks, vs)
-    ik = pl.program_id(2)
-    n = jnp.stack([len_ref[i, 0] for i in range(bf)])    # (bf,) SMEM scalars
+def _decode_blocks(n_of, slots: int, start, rows, refs, o_ref, *, live_gate,
+                   bounded: bool, g: int, window: int, **partial_kw):
+    """The body every decode program runs: block slot ``i < slots`` has
+    ``n_of(i)`` valid rows (its decode row sits at ``n_of(i) - 1``), the
+    block holds cache rows ``[start, start + rows)`` of every KV head, and
+    each (slot, head) writes its own fp32 partial ``o_ref[i, h, 0]``.
+    ``live_gate`` is an extra liveness condition (the paged table entry is
+    mapped). Slots run in a loop, not unrolled: Mosaic's compile time grows
+    much faster than linearly in unrolled bodies."""
+    beta_ref, gamma_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref = refs
+    hkv = q_ref.shape[1]
 
     def compute():
-        q = q_ref[:, 0]                              # (bf, g, d)
-        if scale_refs:                               # per-block VMEM dequant
-            ks_ref, vs_ref = scale_refs
-            k = CL.dequant_block(k_ref[:, :, 0], ks_ref[:, :, 0], q.dtype)
-            v = CL.dequant_block(v_ref[:, :, 0], vs_ref[:, :, 0], q.dtype)
-        else:
-            k = k_ref[:, :, 0].astype(q.dtype)       # (bf, bk, d)
-            v = v_ref[:, :, 0].astype(q.dtype)
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap > 0:
-            s = softcap * jnp.tanh(s / softcap)
+        kpos = start + jax.lax.broadcasted_iota(jnp.int32, (g, rows), 1)
+        for h in range(hkv):
+            beta = CL.head_rows(beta_ref, h, g, g)
+            gamma = CL.head_rows(gamma_ref, h, g, g)
 
-        kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bf, g, bk), 2)
-        nb = n[:, None, None]
-        mask = CL.kv_mask(nb - 1, kpos, nb, window)  # decode row sits at n-1
+            @pl.loop(0, slots)
+            def _slot(i):
+                n = n_of(i)
+                q = q_ref[i, h]                              # (g, d)
+                o_ref[i, h, 0] = CL.consmax_partial(
+                    q, CL.kv_head(k_ref, ks_ref, i, h, q.dtype),
+                    CL.kv_head(v_ref, vs_ref, i, h, q.dtype),
+                    CL.kv_mask(n - 1, kpos, n, window), beta, gamma,
+                    **partial_kw)
 
-        p = CL.consmax_weights(s, beta_ref[0][:, None], gamma_ref[0][:, None],
-                               merged)
-        p = jnp.where(mask, p, 0.0)
-
-        o_ref[:, 0, 0] = jax.lax.dot_general(        # (bf, g, d) partials
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-
-    # fill bounding: a shard past every folded slot's fill (or entirely
-    # behind every window) would compute only masked-to-zero weights —
-    # write the zeros directly. The decode row sits at n - 1 per slot.
-    live = jnp.any(CL.shard_live(ik * bk, bk, n, qpos_lo=n - 1,
-                                 window=window))
+    if not bounded:
+        compute()
+        return
+    # fill bounding: a block past every slot's fill, entirely behind every
+    # window, or an unmapped page would compute only masked-to-zero weights
+    # — write the zeros directly
+    live = functools.reduce(jnp.logical_or, [
+        CL.shard_live(start, rows, n_of(i), qpos_lo=n_of(i) - 1,
+                      window=window) for i in range(slots)])
+    live = jnp.logical_and(live, live_gate)
     pl.when(live)(compute)
 
     @pl.when(jnp.logical_not(live))
     def _skip():
-        o_ref[:, 0, 0] = jnp.zeros((bf, g, o_ref.shape[-1]), jnp.float32)
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
 
 
-def _fold_factor(b: int, bk: int, d: int, limit_bytes: int = 2 << 20) -> int:
+def _kernel(len_ref, beta_ref, gamma_ref, q_ref, k_ref, v_ref, *rest,
+            bk: int, bf: int, **kw):
+    """Contiguous cache: program (slot-group, shard), ``bf`` slots."""
+    *scale_refs, o_ref = rest                        # quantized KV: (ks, vs)
+    ig, ik = pl.program_id(0), pl.program_id(1)
+    _decode_blocks(lambda i: len_ref[ig * bf + i], bf, ik * bk, bk,
+                   (beta_ref, gamma_ref, q_ref, k_ref, v_ref,
+                    *(scale_refs or (None, None))),
+                   o_ref, live_gate=True, **kw)
+
+
+def _fold_factor(b: int, bk: int, hkv: int, d: int,
+                 limit_bytes: int = 2 << 20) -> int:
     """Slots folded per bounded-decode block: the largest divisor of ``b``
-    whose K/V blocks stay under ``limit_bytes`` each (fp32), capped at 8."""
-    cap = max(1, limit_bytes // (bk * d * 4))
+    whose all-heads K/V blocks stay under ``limit_bytes`` each (fp32),
+    capped at 8."""
+    cap = max(1, limit_bytes // (bk * hkv * d * 4))
     return max(f for f in range(1, min(b, 8, cap) + 1) if b % f == 0)
 
 
@@ -182,17 +153,16 @@ def consmax_decode(q, k, v, lengths, beta, gamma, *, window: int = 0,
     the kernel upcasts each KV block in VMEM (``cache_layout.dequant_block``),
     so the HBM KV walk moves the narrow bytes. None = cache stored as-is.
 
-    Grid (b, hkv, n_shards) — ALL dims parallel. Shard partials are summed
-    in fp32 by the caller-side reduction below (a pure addition; the absence
-    of a softmax combine step is the point). With ``fill_bound`` (default)
-    the shard axis is clamped to the traced batch-max live shard count,
-    the batch axis is folded into the block (grid (b/bf, hkv, ns_live) —
-    per-program overhead amortized across ``bf`` slots), and dead
-    (slot-group, shard) programs are ``pl.when``-skipped — KV work tracks
-    *fill*, not cache capacity, bit-identically (dead shards contribute
-    exact zeros either way). ``fill_bound=False`` sweeps the full
-    per-slot capacity grid (the pre-fill-bounding behaviour, kept as the
-    benchmark baseline).
+    Grid (b/bf, n_shards) — ALL dims parallel; every program covers all hkv
+    heads. Shard partials are summed in fp32 by the caller-side reduction
+    below (a pure addition; the absence of a softmax combine step is the
+    point). With ``fill_bound`` (default) the shard axis is clamped to the
+    traced batch-max live shard count, ``bf`` slots share a block
+    (per-program overhead amortized), and dead (slot-group, shard) programs
+    are ``pl.when``-skipped — KV work tracks *fill*, not cache capacity,
+    bit-identically (dead shards contribute exact zeros either way).
+    ``fill_bound=False`` sweeps the full per-slot capacity grid (``bf = 1``,
+    the pre-fill-bounding behaviour, kept as the benchmark baseline).
     The shard size is the largest divisor of L <= ``bk``, so serving shapes
     are never padded (padding, like the old (b, hkv, L, d) transpose, would
     copy the full cache every step); only a degenerate-divisor L (prime-ish
@@ -210,79 +180,38 @@ def consmax_decode(q, k, v, lengths, beta, gamma, *, window: int = 0,
         k_scale = CL.block_scale_rows(k_scale, bk, ns)
         v_scale = CL.block_scale_rows(v_scale, bk, ns)
 
-    qg = q.reshape(b, hkv, g, d)
-    beta2, gamma2 = CL.tile_head_params(beta, gamma, hkv)
-    len2 = lengths.reshape(b, 1).astype(jnp.int32)
     # the grid clamp: a traced VALUE, never a shape — the partials buffer
     # stays capacity-sized but its slots >= ns_live are never written (and
     # never read by the fill-bounded combine below)
     ns_live = CL.live_blocks(jnp.max(lengths), bk, ns) if fill_bound else ns
-
-    if fill_bound:
-        bf = _fold_factor(b, bk, d)
-        kernel = functools.partial(_folded_kernel, scale=scale, window=window,
-                                   softcap=softcap, bk=bk, g=g, merged=merged,
-                                   bf=bf)
-        in_specs = [
-            pl.BlockSpec((bf, 1), lambda ig, ih, ik: (ig, 0),
-                         memory_space=pltpu.SMEM),              # lengths
-            pl.BlockSpec((1, g), lambda ig, ih, ik: (ih, 0)),   # beta
-            pl.BlockSpec((1, g), lambda ig, ih, ik: (ih, 0)),   # gamma
-            pl.BlockSpec((bf, 1, g, d),
-                         lambda ig, ih, ik: (ig, ih, 0, 0)),
-            pl.BlockSpec((bf, bk, 1, d),
-                         lambda ig, ih, ik: (ig, ik, ih, 0)),
-            pl.BlockSpec((bf, bk, 1, d),
-                         lambda ig, ih, ik: (ig, ik, ih, 0)),
-        ]
-        operands = [len2, beta2, gamma2, qg, k, v]
-        if quant:
-            # fp32 row scales, blocked alongside their K/V shard (dk/4x
-            # smaller than the data operand they rescale)
-            in_specs += [pl.BlockSpec((bf, bk, 1),
-                                      lambda ig, ih, ik: (ig, ik, ih))] * 2
-            operands += [k_scale, v_scale]
-        partials = pl.pallas_call(
-            kernel,
-            grid=(b // bf, hkv, ns_live),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((bf, 1, 1, g, d),
-                                   lambda ig, ih, ik: (ig, ih, ik, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((b, hkv, ns, g, d), jnp.float32),
-            interpret=interpret,
-            compiler_params=tpu_compiler_params(
-                dimension_semantics=("parallel", "parallel", "parallel")),
-        )(*operands)
-    else:
-        kernel = functools.partial(_kernel, scale=scale, window=window,
-                                   softcap=softcap, bk=bk, g=g, merged=merged)
-        in_specs = [
-            pl.BlockSpec((1, 1), lambda ib, ih, ik: (ib, 0),
-                         memory_space=pltpu.SMEM),              # lengths
-            pl.BlockSpec((1, g), lambda ib, ih, ik: (ih, 0)),   # beta
-            pl.BlockSpec((1, g), lambda ib, ih, ik: (ih, 0)),   # gamma
-            pl.BlockSpec((1, 1, g, d), lambda ib, ih, ik: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda ib, ih, ik: (ib, ik, ih, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda ib, ih, ik: (ib, ik, ih, 0)),
-        ]
-        operands = [len2, beta2, gamma2, qg, k, v]
-        if quant:
-            in_specs += [pl.BlockSpec((1, bk, 1),
-                                      lambda ib, ih, ik: (ib, ik, ih))] * 2
-            operands += [k_scale, v_scale]
-        partials = pl.pallas_call(
-            kernel,
-            grid=(b, hkv, ns),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, 1, g, d),
-                                   lambda ib, ih, ik: (ib, ih, ik, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((b, hkv, ns, g, d), jnp.float32),
-            interpret=interpret,
-            compiler_params=tpu_compiler_params(
-                dimension_semantics=("parallel", "parallel", "parallel")),
-        )(*operands)
+    bf = _fold_factor(b, bk, hkv, d) if fill_bound else 1
+    kernel = functools.partial(_kernel, scale=scale, window=window,
+                               softcap=softcap, bk=bk, g=g, merged=merged,
+                               bf=bf, bounded=fill_bound)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    kv_spec = pl.BlockSpec((bf, bk, hkv, d), lambda ig, ik: (ig, ik, 0, 0))
+    in_specs = [smem, smem, smem,                    # lengths, beta, gamma
+                pl.BlockSpec((bf, hkv, g, d), lambda ig, ik: (ig, 0, 0, 0)),
+                kv_spec, kv_spec]
+    operands = [lengths.astype(jnp.int32), beta.astype(jnp.float32),
+                gamma.astype(jnp.float32), q.reshape(b, hkv, g, d), k, v]
+    if quant:
+        # fp32 row scales, blocked alongside their K/V shard (dk/4x
+        # smaller than the data operand they rescale)
+        in_specs += [pl.BlockSpec((bf, bk, hkv),
+                                  lambda ig, ik: (ig, ik, 0))] * 2
+        operands += [k_scale, v_scale]
+    partials = pl.pallas_call(
+        kernel,
+        grid=(b // bf, ns_live),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bf, hkv, 1, g, d),
+                               lambda ig, ik: (ig, 0, ik, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, ns, g, d), jnp.float32),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+    )(*operands)
 
     out = CL.fill_bounded_sum(partials, ns_live)     # the sync-free combine
     return out.reshape(b, nh, d).astype(q.dtype)
@@ -290,51 +219,16 @@ def consmax_decode(q, k, v, lengths, beta, gamma, *, window: int = 0,
 
 # ------------------------------------------------------------- paged KV ----
 def _paged_kernel(tab_ref, len_ref, beta_ref, gamma_ref, q_ref, k_ref, v_ref,
-                  *rest, scale: float, window: int, softcap: float,
-                  ps: int, g: int, merged: bool, bounded: bool):
+                  *rest, ps: int, **kw):
+    """Paged pool: program (slot, table entry) — one page of every head.
+    An unmapped entry clamps to page 0; its rows sit at kpos >= n and are
+    masked (or, bounded, the program is skipped)."""
     *scale_refs, o_ref = rest                        # quantized KV: (ks, vs)
-    ib, ij = pl.program_id(0), pl.program_id(2)
-    n = len_ref[ib]                                  # valid logical rows
-
-    def compute():
-        q = q_ref[0, 0]                              # (g, d)
-        if scale_refs:                               # per-page VMEM dequant
-            ks_ref, vs_ref = scale_refs
-            k = CL.dequant_block(k_ref[0, :, 0], ks_ref[0, :, 0], q.dtype)
-            v = CL.dequant_block(v_ref[0, :, 0], vs_ref[0, :, 0], q.dtype)
-        else:
-            k = k_ref[0, :, 0].astype(q.dtype)       # (ps, d) — one page
-            v = v_ref[0, :, 0].astype(q.dtype)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap > 0:
-            s = softcap * jnp.tanh(s / softcap)
-
-        kpos = ij * ps + jax.lax.broadcasted_iota(jnp.int32, (g, ps), 1)
-        mask = CL.kv_mask(n - 1, kpos, n, window)    # unmapped page => all
-                                                     # kpos >= n => zeroed
-        p = CL.consmax_weights(s, beta_ref[0][:, None], gamma_ref[0][:, None],
-                               merged)
-        p = jnp.where(mask, p, 0.0)
-
-        o_ref[0, 0, 0] = jax.lax.dot_general(        # independent partial
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if not bounded:
-        compute()
-        return
-
-    # fill bounding: an unmapped table entry, a page past this slot's fill,
-    # or one entirely behind its window stops DMA-multiplying zeros out of
-    # clamped page 0 — its partial is written as exact zeros instead
-    live = (tab_ref[ib, ij] >= 0) & CL.shard_live(
-        ij * ps, ps, n, qpos_lo=n - 1, window=window)
-    pl.when(live)(compute)
-
-    @pl.when(jnp.logical_not(live))
-    def _skip():
-        o_ref[0, 0, 0] = jnp.zeros((g, o_ref.shape[-1]), jnp.float32)
+    ib, ij = pl.program_id(0), pl.program_id(1)
+    _decode_blocks(lambda i: len_ref[ib], 1, ij * ps, ps,
+                   (beta_ref, gamma_ref, q_ref, k_ref, v_ref,
+                    *(scale_refs or (None, None))),
+                   o_ref, live_gate=tab_ref[ib, ij] >= 0, **kw)
 
 
 def consmax_decode_paged(q, kp, vp, page_table, lengths, beta, gamma, *,
@@ -350,18 +244,18 @@ def consmax_decode_paged(q, kp, vp, page_table, lengths, beta, gamma, *,
     gathered through the same page index map and upcast per-page in VMEM.
 
     The KV grid axis iterates *page-table entries*: the table rides in as a
-    scalar-prefetch operand, so program (ib, ih, ij) DMAs pool page
-    ``page_table[ib, ij]`` straight from HBM — the gather lives in the
-    BlockSpec index map, no materialized per-slot contiguous cache. Every
-    grid dim stays ``parallel``: page partials are independent (no running
-    max, no denominator) and combine by the same caller-side fp32 addition
-    as the contiguous kernel. With ``fill_bound`` (default) the page axis
-    is clamped to the traced batch-max live page count and per-slot dead
-    pages (unmapped entries, pages past the fill, pages behind the window)
-    are ``pl.when``-skipped, so the table's capacity-sized tail stops
-    costing a program per entry; ``fill_bound=False`` sweeps every table
-    column (the pre-fill-bounding baseline). Unmapped entries clamp to
-    page 0 and contribute exact zeros either way.
+    scalar-prefetch operand, so program (ib, ij) DMAs pool page
+    ``page_table[ib, ij]`` (all heads) straight from HBM — the gather lives
+    in the BlockSpec index map, no materialized per-slot contiguous cache.
+    Every grid dim stays ``parallel``: page partials are independent (no
+    running max, no denominator) and combine by the same caller-side fp32
+    addition as the contiguous kernel. With ``fill_bound`` (default) the
+    page axis is clamped to the traced batch-max live page count and
+    per-slot dead pages (unmapped entries, pages past the fill, pages behind
+    the window) are ``pl.when``-skipped, so the table's capacity-sized tail
+    stops costing a program per entry; ``fill_bound=False`` sweeps every
+    table column (the pre-fill-bounding baseline). Unmapped entries clamp
+    to page 0 and contribute exact zeros either way.
     """
     b, nh, d = q.shape
     P, ps, nkv = kp.shape[0], kp.shape[1], kp.shape[2]
@@ -370,8 +264,6 @@ def consmax_decode_paged(q, kp, vp, page_table, lengths, beta, gamma, *,
     if scale is None:
         scale = 1.0 / math.sqrt(d)
 
-    qg = q.reshape(b, nkv, g, d)
-    beta2, gamma2 = CL.tile_head_params(beta, gamma, nkv)
     tab = page_table.astype(jnp.int32)
     len1 = lengths.astype(jnp.int32)
     npg_live = (CL.live_blocks(jnp.max(len1), ps, npg) if fill_bound
@@ -381,39 +273,39 @@ def consmax_decode_paged(q, kp, vp, page_table, lengths, beta, gamma, *,
                                softcap=softcap, ps=ps, g=g, merged=merged,
                                bounded=fill_bound)
 
-    def page_map(ib, ih, ij, tab_ref, len_ref):
-        return (jnp.maximum(tab_ref[ib, ij], 0), 0, ih, 0)
+    def page_map(ib, ij, tab_ref, len_ref):
+        return (jnp.maximum(tab_ref[ib, ij], 0), 0, 0, 0)
 
-    def scale_page_map(ib, ih, ij, tab_ref, len_ref):
-        return (jnp.maximum(tab_ref[ib, ij], 0), 0, ih)
+    def scale_page_map(ib, ij, tab_ref, len_ref):
+        return (jnp.maximum(tab_ref[ib, ij], 0), 0, 0)
 
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [
-        pl.BlockSpec((1, g), lambda ib, ih, ij, *_: (ih, 0)),   # beta
-        pl.BlockSpec((1, g), lambda ib, ih, ij, *_: (ih, 0)),   # gamma
-        pl.BlockSpec((1, 1, g, d),
-                     lambda ib, ih, ij, *_: (ib, ih, 0, 0)),    # q
-        pl.BlockSpec((1, ps, 1, d), page_map),                  # k page
-        pl.BlockSpec((1, ps, 1, d), page_map),                  # v page
+        smem, smem,                                             # beta, gamma
+        pl.BlockSpec((1, nkv, g, d), lambda ib, ij, *_: (ib, 0, 0, 0)),
+        pl.BlockSpec((1, ps, nkv, d), page_map),                # k page
+        pl.BlockSpec((1, ps, nkv, d), page_map),                # v page
     ]
-    operands = [beta2, gamma2, qg, kp, vp]
+    operands = [beta.astype(jnp.float32), gamma.astype(jnp.float32),
+                q.reshape(b, nkv, g, d), kp, vp]
     if k_scale is not None:
-        in_specs += [pl.BlockSpec((1, ps, 1), scale_page_map)] * 2
+        in_specs += [pl.BlockSpec((1, ps, nkv), scale_page_map)] * 2
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                       # page table + lengths
-        grid=(b, nkv, npg_live),
+        grid=(b, npg_live),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, 1, g, d),
-                               lambda ib, ih, ij, *_: (ib, ih, ij, 0, 0)),
+        out_specs=pl.BlockSpec((1, nkv, 1, g, d),
+                               lambda ib, ij, *_: (ib, 0, ij, 0, 0)),
     )
     partials = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, npg, g, d), jnp.float32),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
     )(tab, len1, *operands)
 
     out = CL.fill_bounded_sum(partials, npg_live)    # the sync-free combine

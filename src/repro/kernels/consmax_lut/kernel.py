@@ -18,8 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def make_luts(scale: float):
@@ -67,7 +66,7 @@ def consmax_lut(scores_int8, c, scale: float, *, block: int = 1024,
         out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
     )(jnp.asarray(c, jnp.float32).reshape(1, 1),
       msb_lut.reshape(1, 16), lsb_lut.reshape(1, 16),

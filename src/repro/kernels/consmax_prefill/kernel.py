@@ -14,30 +14,34 @@ exchange, no final divide. That a multi-row prefill chunk needs *nothing*
 beyond what single-token decode needs is the paper's sync-free property
 doing the work.
 
-The cache is consumed in its stored layout ``(b, L, hkv, dk)`` — the hkv
-axis is a unit grid dimension in the BlockSpec (shared design with the
-decode kernel, helpers in ../cache_layout.py), so a prefill chunk never
-materializes a transposed or padded copy of the cache. GQA is folded into
-the q rows position-major (row = chunk position * g + group head), giving a
+The cache is consumed in its stored layout ``(b, L, hkv, dk)``: one grid
+program covers a KV shard of **all** hkv heads (K/V blocks
+``(1, bk, hkv, dk)``, per-head beta/gamma in SMEM, a static head loop in
+the body — shared design with the decode kernel, helpers in
+../cache_layout.py), so every block's last two dims are full extents, as
+Mosaic's TPU tiling rule requires, and a prefill chunk never traces a
+transposed or padded copy of the cache. GQA is folded into the q rows
+position-major (row = chunk position * g + group head), giving a
 ``(bq*g, bk)`` score tile for the MXU without repeating K/V.
 
-Per (batch, kv-head, q-block, kv-shard) program:
+Per (batch, q-block, kv-shard) program, for each kv-head:
 
     s = q @ k^T * scale            (MXU; q is a bq*g row block)
     p = exp(s - beta) / gamma      (VPU; causal/length/window mask)
     o = p @ v                      (MXU; partial, summed across shards)
 
-VMEM per program @ (bq*g, bk, d) = (1024, 512, 128) fp32: q + out
-2·1024·128·4 + k/v 2·512·128·4 + s/p 2·1024·512·4 ≈ 5.8 MB — inside the
-~16 MB/core budget with Mosaic's double-buffered KV pipeline. The parallel
-split costs ``ns`` output-sized fp32 partial buffers in HBM; pick ``bk``
-(ServeConfig.prefill_kv_block) so ns = L/bk stays small on long caches.
+VMEM per program: the (hkv, bq*g, dk) q and fp32 out blocks, (bk, hkv, dk)
+K/V blocks and one head's (bq*g, bk) fp32 s/p tiles at a time —
+``analysis.kernel_contracts`` models it against the per-core budget. The
+parallel split costs ``ns`` output-sized fp32 partial buffers in HBM; pick
+``bk`` (ServeConfig.prefill_kv_block) so ns = L/bk stays small on long
+caches.
 
 The paged variant walks *page-table entries* via a scalar-prefetch operand
-(mirroring ``consmax_decode_paged``): program (ib, ih, iq, ij) DMAs pool
-page ``page_table[ib, ij]`` straight from HBM. Its page axis accumulates
-into VMEM scratch ('arbitrary' trailing dim) instead of per-page partial
-buffers: a chunk's partials are (c*g, d)-sized, so per-page slots would
+(mirroring ``consmax_decode_paged``): program (ib, iq, ij) DMAs pool
+page ``page_table[ib, ij]`` (all heads) straight from HBM. Its page axis
+accumulates into VMEM scratch ('arbitrary' trailing dim) instead of
+per-page partial buffers: a chunk's partials are (c*g, d)-sized, so per-page slots would
 cost max_pages_per_slot × chunk-output HBM — at 500k context that is
 thousands of copies, defeating the page pool's memory saving. The
 accumulation is still a bare ``acc += p @ v``: ConSmax removes the (m, l)
@@ -68,7 +72,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 from repro.kernels import cache_layout as CL
 
 # ceiling on the contiguous kernel's parallel KV split: each shard owns a
@@ -76,40 +79,45 @@ from repro.kernels import cache_layout as CL
 MAX_KV_SHARDS = 64
 
 
+def _head_partials(q_ref, k_ref, v_ref, ks_ref, vs_ref, beta_ref,
+                   gamma_ref, mask, *, g: int, **partial_kw):
+    """Yield ``(h, partial)`` for every KV head of an all-heads block: the
+    chunk's (bqg, dk) q rows of head ``h`` against the block's rows."""
+    bqg = q_ref.shape[2]
+    for h in range(q_ref.shape[1]):
+        q = q_ref[0, h]                              # (bqg, d)
+        yield h, CL.consmax_partial(
+            q, CL.kv_head(k_ref, ks_ref, 0, h, q.dtype),
+            CL.kv_head(v_ref, vs_ref, 0, h, q.dtype), mask,
+            CL.head_rows(beta_ref, h, g, bqg),
+            CL.head_rows(gamma_ref, h, g, bqg), **partial_kw)
+
+
+def _chunk_mask(idx, kvl, iq, start, rows, *, bqg: int, g: int,
+                window: int):
+    """(bqg, rows) mask of q-block ``iq`` (chunk starts at ``idx``, rows
+    position-major) against cache rows ``[start, start + rows)``."""
+    row = iq * bqg + jax.lax.broadcasted_iota(jnp.int32, (bqg, rows), 0)
+    kpos = start + jax.lax.broadcasted_iota(jnp.int32, (bqg, rows), 1)
+    return CL.kv_mask(idx + row // g, kpos, kvl, window)
+
+
 def _kernel(idx_ref, kvl_ref, beta_ref, gamma_ref, q_ref, k_ref, v_ref,
-            *rest, scale: float, window: int, softcap: float, bqg: int,
-            bk: int, bq: int, g: int, merged: bool, bounded: bool):
+            *rest, window: int, bqg: int, bk: int, bq: int, g: int,
+            bounded: bool, **partial_kw):
     *scale_refs, o_ref = rest                        # quantized KV: (ks, vs)
-    iq, ik = pl.program_id(2), pl.program_id(3)
-    idx = idx_ref[0, 0]                              # chunk start position
-    kvl = kvl_ref[0, 0]                              # index + real length
+    ks_ref, vs_ref = scale_refs or (None, None)
+    ib, iq, ik = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    idx = idx_ref[ib]                                # chunk start position
+    kvl = kvl_ref[ib]                                # index + real length
 
     def compute():
-        q = q_ref[0, 0]                              # (bqg, d)
-        if scale_refs:                               # per-block VMEM dequant
-            ks_ref, vs_ref = scale_refs
-            k = CL.dequant_block(k_ref[0, :, 0], ks_ref[0, :, 0], q.dtype)
-            v = CL.dequant_block(v_ref[0, :, 0], vs_ref[0, :, 0], q.dtype)
-        else:
-            k = k_ref[0, :, 0].astype(q.dtype)       # (bk, d) — cache layout
-            v = v_ref[0, :, 0].astype(q.dtype)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap > 0:
-            s = softcap * jnp.tanh(s / softcap)
-
-        row = iq * bqg + jax.lax.broadcasted_iota(jnp.int32, (bqg, bk), 0)
-        qpos = idx + row // g                        # position-major rows
-        kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bqg, bk), 1)
-        mask = CL.kv_mask(qpos, kpos, kvl, window)
-
-        p = CL.consmax_weights(s, beta_ref[0][:, None],
-                               gamma_ref[0][:, None], merged)
-        p = jnp.where(mask, p, 0.0)
-
-        o_ref[0, 0, 0] = jax.lax.dot_general(        # independent partial
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        mask = _chunk_mask(idx, kvl, iq, ik * bk, bk, bqg=bqg, g=g,
+                           window=window)
+        for h, part in _head_partials(q_ref, k_ref, v_ref, ks_ref, vs_ref,
+                                      beta_ref, gamma_ref, mask, g=g,
+                                      **partial_kw):
+            o_ref[0, h, 0] = part                    # independent partial
 
     if not bounded:
         compute()
@@ -122,7 +130,7 @@ def _kernel(idx_ref, kvl_ref, beta_ref, gamma_ref, q_ref, k_ref, v_ref,
 
     @pl.when(jnp.logical_not(live))
     def _dead():                                     # exact-zero partial
-        o_ref[0, 0, 0] = jnp.zeros((bqg, o_ref.shape[-1]), jnp.float32)
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
 
 
 def consmax_prefill(q, k, v, index, lengths, beta, gamma, *, window: int = 0,
@@ -138,12 +146,13 @@ def consmax_prefill(q, k, v, index, lengths, beta, gamma, *, window: int = 0,
     ``k_scale``/``v_scale``: (b, L, hkv) fp32 per-row-per-head quant scales
     for a quantized cache, upcast per-block in VMEM (None = stored as-is).
 
-    Grid (b, hkv, nq, ns) — ALL dims parallel; shard partials are summed in
-    fp32 by the caller-side reduction (pure addition, the sync-free
-    combine). Query rows >= lengths are pad rows: their output is garbage
-    and must be ignored by the caller (their K/V never entered the cache),
-    exactly as in ``append_attention``. Block sizes prefer the largest
-    divisors of c / L <= ``bq`` / ``bk`` so operands are not padded
+    Grid (b, nq, ns) — ALL dims parallel, every program covering all hkv
+    heads; shard partials are summed in fp32 by the caller-side reduction
+    (pure addition, the sync-free combine). Query rows >= lengths are pad
+    rows: their output is garbage and must be ignored by the caller (their
+    K/V never entered the cache), exactly as in ``append_attention``. Block
+    sizes prefer the largest divisors of c / L <= ``bq`` / ``bk`` so
+    operands are not padded
     (``cache_layout.block_cache_rows`` handles degenerate-divisor L); the
     shard count is additionally capped at ``MAX_KV_SHARDS`` by growing the
     shard — the parallel split buys its independence with ``ns``
@@ -170,48 +179,39 @@ def consmax_prefill(q, k, v, index, lengths, beta, gamma, *, window: int = 0,
         k_scale = CL.block_scale_rows(k_scale, bk, ns)
         v_scale = CL.block_scale_rows(v_scale, bk, ns)
 
-    qf = CL.fold_gqa(q, hkv)                         # (b, hkv, c*g, dk)
-    beta2, gamma2 = CL.tile_head_params(beta, gamma, hkv, c)
-    idx2 = index.reshape(b, 1).astype(jnp.int32)
-    kvl2 = (index + lengths).reshape(b, 1).astype(jnp.int32)
+    idx1 = index.astype(jnp.int32)
+    kvl1 = (index + lengths).astype(jnp.int32)
 
-    ns_live = CL.live_blocks(jnp.max(kvl2), bk, ns) if fill_bound else ns
+    ns_live = CL.live_blocks(jnp.max(kvl1), bk, ns) if fill_bound else ns
 
     kernel = functools.partial(_kernel, scale=scale, window=window,
                                softcap=softcap, bqg=bqg, bk=bk, bq=bq, g=g,
                                merged=merged, bounded=fill_bound)
 
-    in_specs = [
-        pl.BlockSpec((1, 1), lambda ib, ih, iq, ik: (ib, 0),
-                     memory_space=pltpu.SMEM),                  # index
-        pl.BlockSpec((1, 1), lambda ib, ih, iq, ik: (ib, 0),
-                     memory_space=pltpu.SMEM),                  # kv_len
-        pl.BlockSpec((1, bqg), lambda ib, ih, iq, ik: (ih, iq)),  # beta
-        pl.BlockSpec((1, bqg), lambda ib, ih, iq, ik: (ih, iq)),  # gamma
-        pl.BlockSpec((1, 1, bqg, dk),
-                     lambda ib, ih, iq, ik: (ib, ih, iq, 0)),   # q rows
-        pl.BlockSpec((1, bk, 1, dk),
-                     lambda ib, ih, iq, ik: (ib, ik, ih, 0)),   # k shard
-        pl.BlockSpec((1, bk, 1, dk),
-                     lambda ib, ih, iq, ik: (ib, ik, ih, 0)),   # v shard
-    ]
-    operands = [idx2, kvl2, beta2, gamma2, qf, k, v]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    kv_spec = pl.BlockSpec((1, bk, hkv, dk),
+                           lambda ib, iq, ik: (ib, ik, 0, 0))
+    in_specs = [smem, smem, smem, smem,          # index, kv_len, beta, gamma
+                pl.BlockSpec((1, hkv, bqg, dk),
+                             lambda ib, iq, ik: (ib, 0, iq, 0)),  # q rows
+                kv_spec, kv_spec]
+    operands = [idx1, kvl1, beta.astype(jnp.float32),
+                gamma.astype(jnp.float32), CL.fold_gqa(q, hkv), k, v]
     if quant:
-        in_specs += [pl.BlockSpec((1, bk, 1),
-                                  lambda ib, ih, iq, ik: (ib, ik, ih))] * 2
+        in_specs += [pl.BlockSpec((1, bk, hkv),
+                                  lambda ib, iq, ik: (ib, ik, 0))] * 2
         operands += [k_scale, v_scale]
 
     partials = pl.pallas_call(
         kernel,
-        grid=(b, hkv, nq, ns_live),
+        grid=(b, nq, ns_live),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, 1, bqg, dk),
-                               lambda ib, ih, iq, ik: (ib, ih, ik, iq, 0)),
+        out_specs=pl.BlockSpec((1, hkv, 1, bqg, dk),
+                               lambda ib, iq, ik: (ib, 0, ik, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hkv, ns, c * g, dk), jnp.float32),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
     )(*operands)
 
     out = CL.fill_bounded_sum(partials, ns_live)     # the sync-free combine
@@ -220,12 +220,12 @@ def consmax_prefill(q, k, v, index, lengths, beta, gamma, *, window: int = 0,
 
 # ------------------------------------------------------------- paged KV ----
 def _paged_kernel(tab_ref, idx_ref, kvl_ref, beta_ref, gamma_ref, q_ref,
-                  k_ref, v_ref, *rest, scale: float, window: int,
-                  softcap: float, bqg: int, ps: int, bq: int, g: int,
-                  merged: bool, bounded: bool):
+                  k_ref, v_ref, *rest, window: int, bqg: int, ps: int,
+                  bq: int, g: int, bounded: bool, **partial_kw):
     *scale_refs, o_ref, acc_ref = rest               # quantized KV: (ks, vs)
-    ib, iq, ij = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-    nj = pl.num_programs(3)
+    ks_ref, vs_ref = scale_refs or (None, None)
+    ib, iq, ij = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nj = pl.num_programs(2)
     idx = idx_ref[ib]
     kvl = kvl_ref[ib]
 
@@ -234,31 +234,13 @@ def _paged_kernel(tab_ref, idx_ref, kvl_ref, beta_ref, gamma_ref, q_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def accumulate():
-        q = q_ref[0, 0]                              # (bqg, d)
-        if scale_refs:                               # per-page VMEM dequant
-            ks_ref, vs_ref = scale_refs
-            k = CL.dequant_block(k_ref[0, :, 0], ks_ref[0, :, 0], q.dtype)
-            v = CL.dequant_block(v_ref[0, :, 0], vs_ref[0, :, 0], q.dtype)
-        else:
-            k = k_ref[0, :, 0].astype(q.dtype)       # (ps, d) — one page
-            v = v_ref[0, :, 0].astype(q.dtype)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap > 0:
-            s = softcap * jnp.tanh(s / softcap)
-
-        row = iq * bqg + jax.lax.broadcasted_iota(jnp.int32, (bqg, ps), 0)
-        qpos = idx + row // g
-        kpos = ij * ps + jax.lax.broadcasted_iota(jnp.int32, (bqg, ps), 1)
-        mask = CL.kv_mask(qpos, kpos, kvl, window)   # unmapped page => all
+        mask = _chunk_mask(idx, kvl, iq, ij * ps, ps, bqg=bqg, g=g,
+                           window=window)            # unmapped page => all
                                                      # kpos >= kvl => zeroed
-        p = CL.consmax_weights(s, beta_ref[0][:, None],
-                               gamma_ref[0][:, None], merged)
-        p = jnp.where(mask, p, 0.0)
-
-        acc_ref[...] += jax.lax.dot_general(         # bare add — no rescale
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        for h, part in _head_partials(q_ref, k_ref, v_ref, ks_ref, vs_ref,
+                                      beta_ref, gamma_ref, mask, g=g,
+                                      **partial_kw):
+            acc_ref[h] += part                       # bare add — no rescale
 
     if bounded:
         live = (tab_ref[ib, ij] >= 0) & CL.shard_live(
@@ -270,7 +252,7 @@ def _paged_kernel(tab_ref, idx_ref, kvl_ref, beta_ref, gamma_ref, q_ref,
 
     @pl.when(ij == nj - 1)
     def _flush():
-        o_ref[0, 0] = acc_ref[...]
+        o_ref[0] = acc_ref[...]
 
 
 def consmax_prefill_paged(q, kp, vp, page_table, index, lengths, beta,
@@ -312,8 +294,6 @@ def consmax_prefill_paged(q, kp, vp, page_table, index, lengths, beta,
     bqg = bq * g
     nq = c // bq
 
-    qf = CL.fold_gqa(q, hkv)                         # (b, hkv, c*g, dk)
-    beta2, gamma2 = CL.tile_head_params(beta, gamma, hkv, c)
     tab = page_table.astype(jnp.int32)
     idx1 = index.astype(jnp.int32)
     kvl1 = (index + lengths).astype(jnp.int32)
@@ -324,41 +304,41 @@ def consmax_prefill_paged(q, kp, vp, page_table, index, lengths, beta,
                                softcap=softcap, bqg=bqg, ps=ps, bq=bq, g=g,
                                merged=merged, bounded=fill_bound)
 
-    def page_map(ib, ih, iq, ij, tab_ref, idx_ref, kvl_ref):
-        return (jnp.maximum(tab_ref[ib, ij], 0), 0, ih, 0)
+    def page_map(ib, iq, ij, tab_ref, idx_ref, kvl_ref):
+        return (jnp.maximum(tab_ref[ib, ij], 0), 0, 0, 0)
 
-    def scale_page_map(ib, ih, iq, ij, tab_ref, idx_ref, kvl_ref):
-        return (jnp.maximum(tab_ref[ib, ij], 0), 0, ih)
+    def scale_page_map(ib, iq, ij, tab_ref, idx_ref, kvl_ref):
+        return (jnp.maximum(tab_ref[ib, ij], 0), 0, 0)
 
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [
-        pl.BlockSpec((1, bqg), lambda ib, ih, iq, ij, *_: (ih, iq)),
-        pl.BlockSpec((1, bqg), lambda ib, ih, iq, ij, *_: (ih, iq)),
-        pl.BlockSpec((1, 1, bqg, dk),
-                     lambda ib, ih, iq, ij, *_: (ib, ih, iq, 0)),   # q
-        pl.BlockSpec((1, ps, 1, dk), page_map),                 # k page
-        pl.BlockSpec((1, ps, 1, dk), page_map),                 # v page
+        smem, smem,                                             # beta, gamma
+        pl.BlockSpec((1, hkv, bqg, dk),
+                     lambda ib, iq, ij, *_: (ib, 0, iq, 0)),    # q
+        pl.BlockSpec((1, ps, hkv, dk), page_map),               # k page
+        pl.BlockSpec((1, ps, hkv, dk), page_map),               # v page
     ]
-    operands = [beta2, gamma2, qf, kp, vp]
+    operands = [beta.astype(jnp.float32), gamma.astype(jnp.float32),
+                CL.fold_gqa(q, hkv), kp, vp]
     if k_scale is not None:
-        in_specs += [pl.BlockSpec((1, ps, 1), scale_page_map)] * 2
+        in_specs += [pl.BlockSpec((1, ps, hkv), scale_page_map)] * 2
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,                       # table, index, kv_len
-        grid=(b, hkv, nq, npg_live),
+        grid=(b, nq, npg_live),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, bqg, dk),
-                               lambda ib, ih, iq, ij, *_: (ib, ih, iq, 0)),
-        scratch_shapes=[pltpu.VMEM((bqg, dk), jnp.float32)],
+        out_specs=pl.BlockSpec((1, hkv, bqg, dk),
+                               lambda ib, iq, ij, *_: (ib, 0, iq, 0)),
+        scratch_shapes=[pltpu.VMEM((hkv, bqg, dk), jnp.float32)],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, c * g, dk), jnp.float32),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(tab, idx1, kvl1, *operands)
 
     return CL.unfold_gqa(out, b, c, H).astype(q.dtype)

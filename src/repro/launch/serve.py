@@ -1,8 +1,11 @@
-"""Serving launcher CLI: batched generation on any assigned arch (smoke
-config on CPU; full config on a real mesh via the same sharding rules the
-dry-run validates).
+"""Serving launcher CLI: batched generation on any assigned arch (the smoke
+config by default, for the CPU; ``--published`` serves the arch's published
+config, for a chip).
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --steps 16
+    PYTHONPATH=src python -m repro.launch.serve --published --engine \
+        continuous --paged --page-size 256 --prefill-chunk 512 \
+        --max-seq 4096 --max-slots 8 --decode-kernel --prefill-kernel
     PYTHONPATH=src python -m repro.launch.serve --engine continuous \
         --requests 12 --max-slots 4 --decode-kernel
     PYTHONPATH=src python -m repro.launch.serve --engine continuous \
@@ -28,6 +31,11 @@ every request's stream is reproducible regardless of scheduling.
 ``--decode-kernel`` requires a consmax arch; requesting it on a softmax/
 softermax config raises at construction instead of silently serving the
 jnp row path.
+
+Weights are random, from ``lm_init`` keyed by ``--seed``. ``build_engine``
+is the one place a continuous engine is built from these flags
+(``chip_smoke.py`` builds its engines through it too). ``main`` turns on
+the persistent compilation cache (``launch.compile_cache``).
 """
 from __future__ import annotations
 
@@ -35,9 +43,13 @@ import argparse
 import time
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
+    ap.add_argument("--published", action="store_true",
+                    help="serve the arch's published config (full widths "
+                         "and depth) instead of the smoke config; for a "
+                         "chip, not the CPU")
     ap.add_argument("--kv-heads", type=int, default=0,
                     help="override n_kv_heads (0 = arch default). Smoke "
                          "configs default to 1 KV head, which --tp > 1 "
@@ -58,13 +70,17 @@ def main():
                     help="min prob relative to the max, [0, 1) "
                          "(0 = disabled)")
     ap.add_argument("--seed", type=int, default=0,
-                    help="sampling seed; continuous requests use seed + i")
+                    help="weight seed and sampling seed; continuous "
+                         "requests sample with seed + i")
     ap.add_argument("--host-sampling", action="store_true",
                     help="legacy host-side sampling (logits shipped per "
                          "token) instead of the fused in-step epilogue")
     # continuous-engine knobs
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-slots", type=int, default=4)
+    ap.add_argument("--max-seq", type=int, default=0,
+                    help="KV rows per slot (0 = 2 * (prompt-len + steps) "
+                         "+ 8)")
     ap.add_argument("--prefill-chunk", type=int, default=16,
                     help="append-at-index prefill chunk (ONE compiled shape)")
     ap.add_argument("--prefill-budget", type=int, default=0,
@@ -122,31 +138,72 @@ def main():
                     help="sequence shards: paged pool pages split across "
                          "the 'seq' mesh axis in per-position blocks "
                          "(requires --paged; num_pages must divide evenly)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.mesh:
         try:
             args.tp, args.seq_shards = map(int, args.mesh.lower().split("x"))
         except ValueError:
             raise SystemExit(f"--mesh must be TPxNS, got {args.mesh!r}")
+    return args
+
+
+def build_model(args):
+    """(cfg, params): the arch's smoke or published config and random
+    weights from ``lm_init`` keyed by ``args.seed``."""
+    from jax import random
+
+    from repro.configs.registry import get_config
+    from repro.models import transformer as T
+    from repro.nn.module import Ctx
+
+    cfg = get_config(args.arch, smoke=not args.published,
+                     **({"n_kv_heads": args.kv_heads} if args.kv_heads
+                        else {}))
+    if cfg.frontend != "tokens":
+        raise SystemExit(f"{args.arch}: embedding-frontend serving demo is "
+                         "exercised by the dry-run decode cells")
+    return cfg, T.lm_init(Ctx(random.key(args.seed)), cfg)
+
+
+def build_engine(args):
+    """The continuous-batching engine these flags describe."""
+    from repro.configs.base import ServeConfig
+    from repro.serve.engine import ContinuousBatchingEngine
+
+    cfg, params = build_model(args)
+    scfg = ServeConfig(max_seq=(args.max_seq
+                                or 2 * (args.prompt_len + args.steps) + 8),
+                       kv_cache_dtype=args.kv_dtype,
+                       prefill_chunk=args.prefill_chunk,
+                       prefill_budget=args.prefill_budget,
+                       max_slots=args.max_slots,
+                       decode_kernel=args.decode_kernel,
+                       prefill_kernel=args.prefill_kernel,
+                       prefill_kv_block=args.prefill_kv_block,
+                       fill_bound=not args.no_fill_bound,
+                       fused_sampling=not args.host_sampling,
+                       score_norm=cfg.score_norm,
+                       paged_kv=args.paged, page_size=args.page_size,
+                       num_pages=args.num_pages,
+                       prefix_cache=not args.no_prefix_cache,
+                       prefix_evict=args.prefix_evict,
+                       tp=args.tp, seq_shards=args.seq_shards)
+    return ContinuousBatchingEngine(cfg, scfg, params)
+
+
+def main(argv=None):
+    args = parse_args(argv)
 
     import dataclasses
 
     from jax import random
 
     from repro.configs.base import ServeConfig
-    from repro.configs.registry import get_config
-    from repro.models import transformer as T
-    from repro.nn.module import Ctx
-    from repro.serve.engine import ContinuousBatchingEngine, ServeSession
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.serve.engine import ServeSession
     from repro.serve.sampling import SamplingParams
 
-    cfg = get_config(args.arch, smoke=True,
-                     **({"n_kv_heads": args.kv_heads} if args.kv_heads
-                        else {}))
-    if cfg.frontend != "tokens":
-        raise SystemExit(f"{args.arch}: embedding-frontend serving demo is "
-                         "exercised by the dry-run decode cells")
-    params = T.lm_init(Ctx(random.key(0)), cfg)
+    enable_compile_cache()
     sp = SamplingParams(temperature=args.temperature, top_k=args.top_k,
                         top_p=args.top_p, min_p=args.min_p, seed=args.seed)
     fused = not args.host_sampling
@@ -156,8 +213,10 @@ def main():
             raise SystemExit("--mesh/--tp/--seq-shards require --engine "
                              "continuous (the static session is the "
                              "single-device A/B reference)")
+        cfg, params = build_model(args)
         sess = ServeSession(
-            cfg, ServeConfig(max_seq=args.prompt_len + args.steps + 8,
+            cfg, ServeConfig(max_seq=(args.max_seq
+                                      or args.prompt_len + args.steps + 8),
                              kv_cache_dtype=args.kv_dtype,
                              decode_kernel=args.decode_kernel,
                              prefill_kernel=args.prefill_kernel,
@@ -179,23 +238,8 @@ def main():
         print("[serve] sample:", out[0].tolist())
         return
 
-    scfg = ServeConfig(max_seq=2 * (args.prompt_len + args.steps) + 8,
-                       kv_cache_dtype=args.kv_dtype,
-                       prefill_chunk=args.prefill_chunk,
-                       prefill_budget=args.prefill_budget,
-                       max_slots=args.max_slots,
-                       decode_kernel=args.decode_kernel,
-                       prefill_kernel=args.prefill_kernel,
-                       prefill_kv_block=args.prefill_kv_block,
-                       fill_bound=not args.no_fill_bound,
-                       fused_sampling=fused,
-                       score_norm=cfg.score_norm,
-                       paged_kv=args.paged, page_size=args.page_size,
-                       num_pages=args.num_pages,
-                       prefix_cache=not args.no_prefix_cache,
-                       prefix_evict=args.prefix_evict,
-                       tp=args.tp, seq_shards=args.seq_shards)
-    eng = ContinuousBatchingEngine(cfg, scfg, params)
+    eng = build_engine(args)
+    cfg, scfg = eng.cfg, eng.scfg
     if eng.plan is not None:
         print(f"[serve/continuous] mesh: tp={args.tp} x "
               f"seq_shards={args.seq_shards} over "

@@ -646,6 +646,28 @@ class ContinuousBatchingEngine:
             return  # nothing queued, nothing active
         self._steps += 1
 
+    def compile_step(self, name: str):
+        """Compile the ``"prefill"`` or ``"decode"`` step ahead of any
+        traffic, at the engine's own argument shapes and placements, and
+        return it (``jax.stages.Compiled``: ``as_text()`` shows the
+        optimized HLO). The step's first call reuses this executable
+        instead of compiling again."""
+        bank = self.bank if self.fused else None
+        if name == "prefill":
+            page_row = self._device_table()[:1] if self.paged else None
+            return self._prefill.lower(
+                self.params, self.caches, jnp.asarray(0, jnp.int32),
+                jnp.zeros((1, self._chunk), jnp.int32),
+                jnp.ones((1,), jnp.int32), bank, page_row).compile()
+        if name == "decode":
+            tokens = (self._last if self.fused else
+                      jnp.zeros((self.scfg.max_slots, 1), jnp.int32))
+            inactive = np.zeros((self.scfg.max_slots,), bool)
+            return self._decode.lower(
+                self.params, self.caches,
+                self._decode_inputs(tokens, inactive), bank).compile()
+        raise ValueError(f"compile_step: unknown step {name!r}")
+
     @property
     def prefill_cache_size(self) -> int:
         """Compiled prefill variants so far (1 for the whole lifetime —
@@ -686,6 +708,12 @@ class ContinuousBatchingEngine:
             self._table_dev = table
             self._table_version = self.pool.version
         return self._table_dev
+
+    def _decode_inputs(self, tokens, active) -> dict:
+        inputs = {"tokens": tokens, "active": jnp.asarray(active)}
+        if self.paged:
+            inputs["page_table"] = self._device_table()
+        return inputs
 
     def _prefill_one(self, slot: int, start: int, n: int):
         prompt = self.scheduler.slots[slot].request.prompt
@@ -751,11 +779,9 @@ class ContinuousBatchingEngine:
         if self.fused:
             # device-side feedback: last tokens in, next tokens out — the
             # only host traffic is draining the (max_slots,) token vector
-            inputs = {"tokens": self._last, "active": jnp.asarray(active)}
-            if self.paged:
-                inputs["page_table"] = self._device_table()
-            self._last, self.caches = self._decode(self.params, self.caches,
-                                                   inputs, self.bank)
+            self._last, self.caches = self._decode(
+                self.params, self.caches,
+                self._decode_inputs(self._last, active), self.bank)
             sampled = np.asarray(self._last)
         else:
             # legacy A/B baseline: ship (max_slots, vocab) logits to the
@@ -763,12 +789,9 @@ class ContinuousBatchingEngine:
             toks = np.zeros((self.scfg.max_slots, 1), np.int32)
             for slot, state in decoding:
                 toks[slot, 0] = state.last_token
-            inputs = {"tokens": jnp.asarray(toks),
-                      "active": jnp.asarray(active)}
-            if self.paged:
-                inputs["page_table"] = self._device_table()
-            logits, self.caches = self._decode(self.params, self.caches,
-                                               inputs, None)
+            logits, self.caches = self._decode(
+                self.params, self.caches,
+                self._decode_inputs(jnp.asarray(toks), active), None)
             rows = np.asarray([slot for slot, _ in decoding])
             pos = jnp.asarray([st.filled + len(st.generated)
                                for _, st in decoding], jnp.int32)

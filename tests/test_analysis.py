@@ -257,9 +257,20 @@ def test_real_serving_kernel_launches_pass_all_contracts(paged):
     launches = serving_launches(cfg, scfg)
     kind = "paged" if paged else "contiguous"
     assert set(launches) == {f"decode_{kind}", f"prefill_{kind}"}
+    hkv, d = cfg.n_kv_heads, cfg.head_dim_
     for label, launch in launches.items():
         assert launch.grid and all(isinstance(g, int) for g in launch.grid)
         assert not check_launch(launch), label
+        # one program per (slot or slot-group, q-block, KV shard or page)
+        # covering ALL kv heads: no hkv grid axis, and every K/V block ends
+        # in the full (hkv, dk) extents the TPU tiling rule accepts
+        assert len(launch.grid) == (2 if label.startswith("decode") else 3)
+        kv = [b for b in launch.in_blocks if len(b.block_shape) == 4
+              and b.block_shape[-2:] == (hkv, d) and b.dtype != "float32"]
+        assert len(kv) == 2, (label, [b.block_shape for b in
+                                      launch.in_blocks])
+        assert all(b.memory_space == "smem" for b in launch.in_blocks
+                   if len(b.block_shape) == 1)      # beta/gamma (+ scalars)
     if paged:
         assert launches[f"decode_{kind}"].num_scalar_prefetch == 2
         assert launches[f"prefill_{kind}"].num_scalar_prefetch == 3

@@ -49,7 +49,7 @@ print(json.dumps(out))
 @pytest.mark.slow
 def test_cp_decode_collective_structure():
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"          # virtual CPU devices, never the chip
     r = subprocess.run([sys.executable, "-c", SCRIPT], cwd=os.getcwd(),
                        capture_output=True, text=True, env=env, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]

@@ -50,7 +50,7 @@ print(json.dumps({"flops": cost["flops"], "bytes": cost["bytes"],
 @pytest.mark.slow
 def test_dryrun_8dev_subprocess():
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"          # virtual CPU devices, never the chip
     out = subprocess.run([sys.executable, "-c", SCRIPT], cwd=os.getcwd(),
                          capture_output=True, text=True, env=env,
                          timeout=600)

@@ -62,7 +62,7 @@ print(json.dumps({"err": err}))
 
 def _run(script):
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"          # virtual CPU devices, never the chip
     r = subprocess.run([sys.executable, "-c", script], cwd=os.getcwd(),
                        capture_output=True, text=True, env=env, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]

@@ -79,14 +79,16 @@ def test_prefill_kernel_matches_append_attention(shape, merged):
                               norm_params=params, merged=merged, kv_chunk=32)
     ref = consmax_prefill_ref(q, k, v, index, lengths, beta, gamma,
                               merged=merged, scale=1.0)
-    # the jnp walk accumulates in a different block order; compare at fp32
-    # round-off scale relative to the output magnitude
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(oracle, np.float32),
-                               rtol=2e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=2e-5, atol=1e-5)
+    # the three sum the same L fp32 terms p*v in different orders; two
+    # orders differ by at most 2*gamma_L*sum|p*v| (plus a few roundings
+    # of p), so bound each element by that, with sum|p*v| = ref on |v|
+    mag = consmax_prefill_ref(q, k, jnp.abs(v), index, lengths, beta, gamma,
+                              merged=merged, scale=1.0)
+    tol = 2 * (L + 4) * 2.0 ** -24 * np.asarray(mag, np.float32)
+    for want in (oracle, ref):
+        err = np.abs(np.asarray(got, np.float32)
+                     - np.asarray(want, np.float32))
+        assert (err <= tol).all(), float((err - tol).max())
 
 
 @pytest.mark.parametrize("window,softcap", [(6, 0.0), (64, 0.0), (0, 30.0)])

@@ -1,0 +1,92 @@
+"""The four serving kernels compile for a TPU v5e at published widths.
+
+Interpret mode (every other kernel test) runs the kernel bodies on the CPU
+and checks none of the TPU's tiling rules. Here Mosaic compiles each kernel
+for a *described* v5e chip — nothing runs, so this says nothing about
+results or speed — at the widths of qwen2-1.5b (H=12, hkv=2, dk=128) and
+gemma2-2b (H=8, hkv=4, dk=256, window 4096, softcap 50), with a bf16 and an
+int8 KV cache, at the serving shapes the chip smoke test uses: 8 slots of
+4096 rows, 256-row pages, 512-token prefill chunks.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library, and every pytest-xdist worker
+imports every test file.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.registry import get_config
+from repro.kernels import cache_layout as CL
+from repro.kernels.consmax_decode.kernel import (consmax_decode,
+                                                 consmax_decode_paged)
+from repro.kernels.consmax_prefill.kernel import (consmax_prefill,
+                                                  consmax_prefill_paged)
+
+SLOTS, MAX_SEQ, PAGE, CHUNK = 8, 4096, 256, 512
+PAGES = SLOTS * MAX_SEQ // PAGE
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _launch(kernel, cfg, kv_dtype, shape):
+    """(fn, argument shapes) for one kernel at ``cfg``'s widths."""
+    H, hkv, dk = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
+    dt = CL.kv_cache_dtype(kv_dtype)
+    quant = CL.kv_quantized(dt)
+    i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
+    kw = dict(window=cfg.window, softcap=cfg.attn_softcap, interpret=False)
+    params = [shape((H,), f32), shape((H,), f32)]              # beta, gamma
+    if kernel in ("decode", "prefill"):
+        b = SLOTS if kernel == "decode" else 1
+        cache = [shape((b, MAX_SEQ, hkv, dk), dt)] * 2
+        scales = [shape((b, MAX_SEQ, hkv), f32)] * 2 if quant else []
+    else:
+        b = SLOTS if kernel == "decode_paged" else 1
+        cache = [shape((PAGES, PAGE, hkv, dk), dt)] * 2
+        scales = [shape((PAGES, PAGE, hkv), f32)] * 2 if quant else []
+        table = shape((b, MAX_SEQ // PAGE), i32)
+    if kernel.startswith("decode"):
+        q = shape((b, H, dk), bf16)
+        rows = [shape((b,), i32)]                              # lengths
+    else:
+        q = shape((b, CHUNK, H, dk), bf16)
+        rows = [shape((b,), i32)] * 2                          # index, lengths
+    fn = {"decode": consmax_decode, "decode_paged": consmax_decode_paged,
+          "prefill": consmax_prefill,
+          "prefill_paged": consmax_prefill_paged}[kernel]
+    pos = ([q, *cache] + ([] if kernel in ("decode", "prefill") else [table])
+           + rows + params)
+
+    def call(*args):
+        n = len(pos)
+        ks, vs = args[n:] if quant else (None, None)
+        return fn(*args[:n], k_scale=ks, v_scale=vs, **kw)
+
+    return call, pos + scales
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "gemma2-2b"])
+@pytest.mark.parametrize("kernel", ["decode", "decode_paged", "prefill",
+                                    "prefill_paged"])
+def test_serving_kernel_compiles_for_v5e(one_chip, kernel, arch, kv_dtype):
+    cfg = get_config(arch)
+    shape = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    fn, args = _launch(kernel, cfg, kv_dtype, shape)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
