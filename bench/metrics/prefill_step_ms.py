@@ -1,0 +1,7 @@
+"""Model step: device time per run of the prefill program
+(``jit_prefill_chunk_step`` in the trace's ``XLA Modules`` line)."""
+
+
+def read(run):
+    t = run.trace.get("modules", {}).get("jit_prefill_chunk_step")
+    return 1000.0 * sum(t) / len(t) if t else None
