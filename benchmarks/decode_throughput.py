@@ -492,7 +492,9 @@ def _prefix_share_rows(cfg, params, rows, report):
         eng.run()
         dt = time.perf_counter() - t0
         tps = n_req * P / dt
-        ttft_ms = 1e3 * float(np.mean([eng.ttft[u] for u in uids]))
+        times = [eng.request_times[u] for u in uids]
+        ttft_ms = 1e3 * float(np.mean([t["first_token"] - t["submitted"]
+                                       for t in times]))
         tok_s[label] = tps
         rows.append((f"serve/prefix_share_{label}_prefill_tok_s",
                      f"{tps:.1f}", f"share={share};P={P};n={n_req}"))

@@ -502,19 +502,32 @@ def attention_apply(p, x, cfg: ModelConfig, *, kind: str = "global",
     cross = cond is not None
     window = cfg.window if kind == "local" else 0
 
-    q = L.heads_proj(p["q"], x, dtype=cdt) * (1.0 / math.sqrt(dk))
-    src = cond if cross else x
-    k = L.heads_proj(p["k"], src, dtype=cdt)
-    v = L.heads_proj(p["v"], src, dtype=cdt)
-    q = shard(q, "act_batch,act_seq,act_heads,")
-    k = shard(k, "act_batch,act_seq,act_kv_heads,")
-    v = shard(v, "act_batch,act_seq,act_kv_heads,")
+    # named scopes: ``attn_proj`` (projections, rope), ``kv_cache`` (every
+    # write of new K/V into the cache or pool, and conversions of the
+    # cache), ``attn`` (the kernel or jnp attention and the mesh combine)
+    with jax.named_scope("attn_proj"):
+        q = L.heads_proj(p["q"], x, dtype=cdt) * (1.0 / math.sqrt(dk))
+        src = cond if cross else x
+        k = L.heads_proj(p["k"], src, dtype=cdt)
+        v = L.heads_proj(p["v"], src, dtype=cdt)
+        q = shard(q, "act_batch,act_seq,act_heads,")
+        k = shard(k, "act_batch,act_seq,act_kv_heads,")
+        v = shard(v, "act_batch,act_seq,act_kv_heads,")
 
     rope_on = cfg.rope_style != "none" and not cross
     interleaved = cfg.rope_style == "interleaved"
     rot = int(dk * cfg.rope_fraction)
     if rot % 2:
         rot -= 1
+
+    def rope(q, k, pos):
+        if not rope_on:
+            return q, k
+        with jax.named_scope("attn_proj"):
+            return tuple(R.apply_rope(a, pos, rotary_dim=rot,
+                                      theta=cfg.rope_theta,
+                                      interleaved=interleaved)
+                         for a in (q, k))
 
     if cache is not None and page_table is not None and not cross:
         # paged KV: cache k/v leaves are shared (P, ps, hkv, dk) page pools.
@@ -533,52 +546,52 @@ def attention_apply(p, x, cfg: ModelConfig, *, kind: str = "global",
         else:
             lengths = (jnp.ones((b,), jnp.int32) if decode_active is None
                        else decode_active.astype(jnp.int32))
-        if rope_on:
-            pos = idx[:, None] + jnp.arange(s)[None, :]
-            q = R.apply_rope(q, pos, rotary_dim=rot, theta=cfg.rope_theta,
-                             interleaved=interleaved)
-            k = R.apply_rope(k, pos, rotary_dim=rot, theta=cfg.rope_theta,
-                             interleaved=interleaved)
+        q, k = rope(q, k, idx[:, None] + jnp.arange(s)[None, :])
         # pad rows / inactive slots are dropped by the scatter itself
         ksp = vsp = None
-        if "k_scale" in cache:
-            # quantize fresh rows before they enter the pool; the per-row
-            # fp32 scales ride the same page-table scatter as the data
-            k, ksc = CL.quantize_kv(k, cache["k"].dtype)
-            v, vsc = CL.quantize_kv(v, cache["v"].dtype)
-            ksp = _paged_cache_write(cache["k_scale"], ksc, idx, lengths,
-                                     page_table)
-            vsp = _paged_cache_write(cache["v_scale"], vsc, idx, lengths,
-                                     page_table)
-        kp = _paged_cache_write(cache["k"], k, idx, lengths, page_table)
-        vp = _paged_cache_write(cache["v"], v, idx, lengths, page_table)
-        if (prefill_append is not None and prefill_kernel
-                and cfg.score_norm == "consmax"):
-            # fused paged prefill kernel: walks page-table entries via
-            # scalar prefetch; pool consumed in cache layout, q pre-scaled
-            from repro.kernels.consmax_prefill.ops import (
-                consmax_prefill_paged_op)
-            out = consmax_prefill_paged_op(
-                q, kp, vp, page_table, idx, lengths,
-                jnp.broadcast_to(p["score_norm"]["beta"], (H,)),
-                jnp.broadcast_to(p["score_norm"]["gamma"], (H,)),
-                window=window, softcap=cfg.attn_softcap, merged=merged,
-                scale=1.0, fill_bound=fill_bound, k_scale=ksp, v_scale=vsp)
-        elif (prefill_append is None and decode_kernel
-                and cfg.score_norm == "consmax"):
-            from repro.kernels.consmax_decode.ops import consmax_decode_paged_op
-            out = consmax_decode_paged_op(
-                q, kp, vp, page_table, idx + lengths,
-                jnp.broadcast_to(p["score_norm"]["beta"], (H,)),
-                jnp.broadcast_to(p["score_norm"]["gamma"], (H,)),
-                window=window, softcap=cfg.attn_softcap, merged=merged,
-                scale=1.0, fill_bound=fill_bound, k_scale=ksp, v_scale=vsp)
-        else:
-            out = paged_attention(
-                q, kp, vp, page_table, idx, lengths,
-                norm_kind=cfg.score_norm, norm_params=p["score_norm"],
-                window=window, softcap=cfg.attn_softcap, merged=merged,
-                k_scale=ksp, v_scale=vsp)
+        with jax.named_scope("kv_cache"):
+            if "k_scale" in cache:
+                # quantize fresh rows before they enter the pool; the
+                # per-row fp32 scales ride the same page-table scatter as
+                # the data
+                k, ksc = CL.quantize_kv(k, cache["k"].dtype)
+                v, vsc = CL.quantize_kv(v, cache["v"].dtype)
+                ksp = _paged_cache_write(cache["k_scale"], ksc, idx, lengths,
+                                         page_table)
+                vsp = _paged_cache_write(cache["v_scale"], vsc, idx, lengths,
+                                         page_table)
+            kp = _paged_cache_write(cache["k"], k, idx, lengths, page_table)
+            vp = _paged_cache_write(cache["v"], v, idx, lengths, page_table)
+        with jax.named_scope("attn"):
+            if (prefill_append is not None and prefill_kernel
+                    and cfg.score_norm == "consmax"):
+                # fused paged prefill kernel: walks page-table entries
+                # via scalar prefetch; pool consumed in cache layout, q
+                # pre-scaled
+                from repro.kernels.consmax_prefill.ops import (
+                    consmax_prefill_paged_op)
+                out = consmax_prefill_paged_op(
+                    q, kp, vp, page_table, idx, lengths,
+                    jnp.broadcast_to(p["score_norm"]["beta"], (H,)),
+                    jnp.broadcast_to(p["score_norm"]["gamma"], (H,)),
+                    window=window, softcap=cfg.attn_softcap, merged=merged,
+                    scale=1.0, fill_bound=fill_bound, k_scale=ksp, v_scale=vsp)
+            elif (prefill_append is None and decode_kernel
+                    and cfg.score_norm == "consmax"):
+                from repro.kernels.consmax_decode.ops import (
+                    consmax_decode_paged_op)
+                out = consmax_decode_paged_op(
+                    q, kp, vp, page_table, idx + lengths,
+                    jnp.broadcast_to(p["score_norm"]["beta"], (H,)),
+                    jnp.broadcast_to(p["score_norm"]["gamma"], (H,)),
+                    window=window, softcap=cfg.attn_softcap, merged=merged,
+                    scale=1.0, fill_bound=fill_bound, k_scale=ksp, v_scale=vsp)
+            else:
+                out = paged_attention(
+                    q, kp, vp, page_table, idx, lengths,
+                    norm_kind=cfg.score_norm, norm_params=p["score_norm"],
+                    window=window, softcap=cfg.attn_softcap, merged=merged,
+                    k_scale=ksp, v_scale=vsp)
         new_cache = {"k": kp, "v": vp, "index": idx + lengths}
         if ksp is not None:
             new_cache.update(k_scale=ksp, v_scale=vsp)
@@ -587,99 +600,96 @@ def attention_apply(p, x, cfg: ModelConfig, *, kind: str = "global",
         # cache position ``index``; prefill_append holds real chunk lengths
         idx = cache["index"]                                 # (b,) int32
         lengths = prefill_append.astype(jnp.int32)
-        if rope_on:
-            pos = idx[:, None] + jnp.arange(s)[None, :]
-            q = R.apply_rope(q, pos, rotary_dim=rot, theta=cfg.rope_theta,
-                             interleaved=interleaved)
-            k = R.apply_rope(k, pos, rotary_dim=rot, theta=cfg.rope_theta,
-                             interleaved=interleaved)
-        # zero pad rows (>= lengths) so they never enter the cache
-        keep = (jnp.arange(s)[None, :] < lengths[:, None])[..., None, None]
-        k = jnp.where(keep, k, 0).astype(k.dtype)
-        v = jnp.where(keep, v, 0).astype(v.dtype)
-        ks_cache = vs_cache = None
-        if "k_scale" in cache:
-            # quantize after pad-zeroing: zero rows quantize to (0, 1.0)
-            # and dequantize back to exact zeros
-            k, ksc = CL.quantize_kv(k, cache["k"].dtype)
-            v, vsc = CL.quantize_kv(v, cache["v"].dtype)
-            ks_cache = _append_cache_write(cache["k_scale"], ksc, idx)
-            vs_cache = _append_cache_write(cache["v_scale"], vsc, idx)
-        k_cache = _append_cache_write(cache["k"], k, idx)
-        v_cache = _append_cache_write(cache["v"], v, idx)
-        k_cache = shard(k_cache, "act_batch,act_kv_seq,act_kv_heads,")
-        v_cache = shard(v_cache, "act_batch,act_kv_seq,act_kv_heads,")
+        q, k = rope(q, k, idx[:, None] + jnp.arange(s)[None, :])
+        with jax.named_scope("kv_cache"):
+            # zero pad rows (>= lengths) so they never enter the cache
+            keep = (jnp.arange(s)[None, :]
+                    < lengths[:, None])[..., None, None]
+            k = jnp.where(keep, k, 0).astype(k.dtype)
+            v = jnp.where(keep, v, 0).astype(v.dtype)
+            ks_cache = vs_cache = None
+            if "k_scale" in cache:
+                # quantize after pad-zeroing: zero rows quantize to (0, 1.0)
+                # and dequantize back to exact zeros
+                k, ksc = CL.quantize_kv(k, cache["k"].dtype)
+                v, vsc = CL.quantize_kv(v, cache["v"].dtype)
+                ks_cache = _append_cache_write(cache["k_scale"], ksc, idx)
+                vs_cache = _append_cache_write(cache["v_scale"], vsc, idx)
+            k_cache = _append_cache_write(cache["k"], k, idx)
+            v_cache = _append_cache_write(cache["v"], v, idx)
+            k_cache = shard(k_cache, "act_batch,act_kv_seq,act_kv_heads,")
+            v_cache = shard(v_cache, "act_batch,act_kv_seq,act_kv_heads,")
         if prefill_kernel and cfg.score_norm == "consmax":
             # fused append-prefill kernel: cache consumed in its stored
             # (b, L, hkv, dk) layout (no transpose/astype copy), KV grid
             # axis fully parallel, partials combined by pure addition
             from repro.kernels.consmax_prefill.ops import consmax_prefill_op
-            out = consmax_prefill_op(
-                q, k_cache, v_cache, idx, lengths,
-                jnp.broadcast_to(p["score_norm"]["beta"], (H,)),
-                jnp.broadcast_to(p["score_norm"]["gamma"], (H,)),
-                window=window, softcap=cfg.attn_softcap, merged=merged,
-                scale=1.0, bk=prefill_kv_block, fill_bound=fill_bound,
-                k_scale=ks_cache, v_scale=vs_cache)
+            with jax.named_scope("attn"):
+                out = consmax_prefill_op(
+                    q, k_cache, v_cache, idx, lengths,
+                    jnp.broadcast_to(p["score_norm"]["beta"], (H,)),
+                    jnp.broadcast_to(p["score_norm"]["gamma"], (H,)),
+                    window=window, softcap=cfg.attn_softcap, merged=merged,
+                    scale=1.0, bk=prefill_kv_block, fill_bound=fill_bound,
+                    k_scale=ks_cache, v_scale=vs_cache)
         else:
-            app_k = k_cache if ks_cache is not None else k_cache.astype(cdt)
-            app_v = v_cache if vs_cache is not None else v_cache.astype(cdt)
-            out = append_attention(
-                q, app_k, app_v, idx, lengths,
-                norm_kind=cfg.score_norm, norm_params=p["score_norm"],
-                window=window, softcap=cfg.attn_softcap, merged=merged,
-                kv_chunk=kv_chunk, k_scale=ks_cache, v_scale=vs_cache)
+            with jax.named_scope("kv_cache"):
+                app_k = (k_cache if ks_cache is not None
+                         else k_cache.astype(cdt))
+                app_v = (v_cache if vs_cache is not None
+                         else v_cache.astype(cdt))
+            with jax.named_scope("attn"):
+                out = append_attention(
+                    q, app_k, app_v, idx, lengths,
+                    norm_kind=cfg.score_norm, norm_params=p["score_norm"],
+                    window=window, softcap=cfg.attn_softcap, merged=merged,
+                    kv_chunk=kv_chunk, k_scale=ks_cache, v_scale=vs_cache)
         new_cache = {"k": k_cache, "v": v_cache, "index": idx + lengths}
         if ks_cache is not None:
             new_cache.update(k_scale=ks_cache, v_scale=vs_cache)
     elif cache is None or s > 1:
         # training, or whole-prompt prefill (cache is filled afterwards)
-        if rope_on:
-            if positions is None:
-                positions = jnp.arange(s)[None, :]
-            q = R.apply_rope(q, positions, rotary_dim=rot,
-                             theta=cfg.rope_theta, interleaved=interleaved)
-            k = R.apply_rope(k, positions, rotary_dim=rot,
-                             theta=cfg.rope_theta, interleaved=interleaved)
-        out = blockwise_attention(
-            q, k, v, norm_kind=cfg.score_norm, norm_params=p["score_norm"],
-            causal=not cross, window=window, softcap=cfg.attn_softcap,
-            merged=merged, q_chunk=q_chunk, kv_chunk=kv_chunk)
+        q, k = rope(q, k, jnp.arange(s)[None, :] if positions is None
+                    else positions)
+        with jax.named_scope("attn"):
+            out = blockwise_attention(
+                q, k, v, norm_kind=cfg.score_norm,
+                norm_params=p["score_norm"], causal=not cross, window=window,
+                softcap=cfg.attn_softcap, merged=merged, q_chunk=q_chunk,
+                kv_chunk=kv_chunk)
         new_cache = None
         if cache is not None and not cross:                  # prefill write
-            if "k_scale" in cache:
-                # attention above ran on full-precision K/V; only the cache
-                # write pays the quantization round-trip
-                k, ksc = CL.quantize_kv(k, cache["k"].dtype)
-                v, vsc = CL.quantize_kv(v, cache["v"].dtype)
-            k_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cache["k"].dtype), 0, axis=1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cache["v"].dtype), 0, axis=1)
-            new_cache = {"k": k_cache, "v": v_cache,
-                         "index": jnp.full((b,), s, jnp.int32)}
-            if "k_scale" in cache:
-                new_cache["k_scale"] = jax.lax.dynamic_update_slice_in_dim(
-                    cache["k_scale"], ksc, 0, axis=1)
-                new_cache["v_scale"] = jax.lax.dynamic_update_slice_in_dim(
-                    cache["v_scale"], vsc, 0, axis=1)
+            with jax.named_scope("kv_cache"):
+                if "k_scale" in cache:
+                    # attention above ran on full-precision K/V; only the cache
+                    # write pays the quantization round-trip
+                    k, ksc = CL.quantize_kv(k, cache["k"].dtype)
+                    v, vsc = CL.quantize_kv(v, cache["v"].dtype)
+                k_cache = jax.lax.dynamic_update_slice_in_dim(
+                    cache["k"], k.astype(cache["k"].dtype), 0, axis=1)
+                v_cache = jax.lax.dynamic_update_slice_in_dim(
+                    cache["v"], v.astype(cache["v"].dtype), 0, axis=1)
+                new_cache = {"k": k_cache, "v": v_cache,
+                             "index": jnp.full((b,), s, jnp.int32)}
+                if "k_scale" in cache:
+                    new_cache["k_scale"] = jax.lax.dynamic_update_slice_in_dim(
+                        cache["k_scale"], ksc, 0, axis=1)
+                    new_cache["v_scale"] = jax.lax.dynamic_update_slice_in_dim(
+                        cache["v_scale"], vsc, 0, axis=1)
     else:
         # one-token decode: s == 1
         idx = cache["index"]                                 # (b,) int32
-        if rope_on:
-            pos = idx[:, None]
-            q = R.apply_rope(q, pos, rotary_dim=rot, theta=cfg.rope_theta,
-                             interleaved=interleaved)
-            k = R.apply_rope(k, pos, rotary_dim=rot, theta=cfg.rope_theta,
-                             interleaved=interleaved)
+        q, k = rope(q, k, idx[:, None])
         if cross:
             k_full, v_full = k, v                            # cond K/V, no cache
             kv_index = jnp.full((b,), k.shape[1] - 1, jnp.int32)
             new_cache = cache
-            out = decode_attention(q, k_full, v_full, kv_index,
-                                   norm_kind=cfg.score_norm,
-                                   norm_params=p["score_norm"], window=0,
-                                   softcap=cfg.attn_softcap, merged=merged)
+            with jax.named_scope("attn"):
+                out = decode_attention(q, k_full, v_full, kv_index,
+                                       norm_kind=cfg.score_norm,
+                                       norm_params=p["score_norm"], window=0,
+                                       softcap=cfg.attn_softcap,
+                                       merged=merged)
         else:
             def upd(c, new, i):
                 if decode_active is None:
@@ -696,40 +706,47 @@ def attention_apply(p, x, cfg: ModelConfig, *, kind: str = "global",
                         cb, jnp.where(ab, nb, old), ib, axis=0)
                 return jax.vmap(one)(c, new, i, decode_active)
             ks_cache = vs_cache = None
-            if "k_scale" in cache:
-                # quantize the one fresh row; ``upd`` is shape-generic so
-                # the (b, 1, hkv) scale row shares the same slot write
-                k, ksc = CL.quantize_kv(k, cache["k"].dtype)
-                v, vsc = CL.quantize_kv(v, cache["v"].dtype)
-                ks_cache = upd(cache["k_scale"], ksc, idx)
-                vs_cache = upd(cache["v_scale"], vsc, idx)
-            k_cache = upd(cache["k"], k.astype(cache["k"].dtype), idx)
-            v_cache = upd(cache["v"], v.astype(cache["v"].dtype), idx)
-            k_cache = shard(k_cache, "act_batch,act_kv_seq,act_kv_heads,")
-            v_cache = shard(v_cache, "act_batch,act_kv_seq,act_kv_heads,")
+            with jax.named_scope("kv_cache"):
+                if "k_scale" in cache:
+                    # quantize the one fresh row; ``upd`` is shape-generic
+                    # so the (b, 1, hkv) scale row shares the same slot
+                    # write
+                    k, ksc = CL.quantize_kv(k, cache["k"].dtype)
+                    v, vsc = CL.quantize_kv(v, cache["v"].dtype)
+                    ks_cache = upd(cache["k_scale"], ksc, idx)
+                    vs_cache = upd(cache["v_scale"], vsc, idx)
+                k_cache = upd(cache["k"], k.astype(cache["k"].dtype), idx)
+                v_cache = upd(cache["v"], v.astype(cache["v"].dtype), idx)
+                k_cache = shard(k_cache,
+                                "act_batch,act_kv_seq,act_kv_heads,")
+                v_cache = shard(v_cache,
+                                "act_batch,act_kv_seq,act_kv_heads,")
             if decode_kernel and cfg.score_norm == "consmax":
                 # split-KV Pallas kernel; q is already pre-scaled above and
                 # the cache is consumed in its stored layout/dtype (per-
                 # block casts inside the kernel, no full-cache copy)
                 from repro.kernels.consmax_decode.ops import consmax_decode_op
-                out = consmax_decode_op(
-                    q, k_cache, v_cache, idx,
-                    jnp.broadcast_to(p["score_norm"]["beta"], (H,)),
-                    jnp.broadcast_to(p["score_norm"]["gamma"], (H,)),
-                    window=window, softcap=cfg.attn_softcap, merged=merged,
-                    scale=1.0, bk=decode_kv_block, fill_bound=fill_bound,
-                    k_scale=ks_cache, v_scale=vs_cache)
+                with jax.named_scope("attn"):
+                    out = consmax_decode_op(
+                        q, k_cache, v_cache, idx,
+                        jnp.broadcast_to(p["score_norm"]["beta"], (H,)),
+                        jnp.broadcast_to(p["score_norm"]["gamma"], (H,)),
+                        window=window, softcap=cfg.attn_softcap,
+                        merged=merged, scale=1.0, bk=decode_kv_block,
+                        fill_bound=fill_bound, k_scale=ks_cache,
+                        v_scale=vs_cache)
             else:
-                dec_k = (k_cache if ks_cache is not None
-                         else k_cache.astype(cdt))
-                dec_v = (v_cache if vs_cache is not None
-                         else v_cache.astype(cdt))
-                out = decode_attention(q, dec_k, dec_v, idx,
-                                       norm_kind=cfg.score_norm,
-                                       norm_params=p["score_norm"],
-                                       window=window,
-                                       softcap=cfg.attn_softcap, merged=merged,
-                                       k_scale=ks_cache, v_scale=vs_cache)
+                with jax.named_scope("kv_cache"):
+                    dec_k = (k_cache if ks_cache is not None
+                             else k_cache.astype(cdt))
+                    dec_v = (v_cache if vs_cache is not None
+                             else v_cache.astype(cdt))
+                with jax.named_scope("attn"):
+                    out = decode_attention(
+                        q, dec_k, dec_v, idx, norm_kind=cfg.score_norm,
+                        norm_params=p["score_norm"], window=window,
+                        softcap=cfg.attn_softcap, merged=merged,
+                        k_scale=ks_cache, v_scale=vs_cache)
             step = (1 if decode_active is None
                     else decode_active.astype(idx.dtype))
             new_cache = {"k": k_cache, "v": v_cache, "index": idx + step}
@@ -752,6 +769,7 @@ def attention_apply(p, x, cfg: ModelConfig, *, kind: str = "global",
         # o-projection partials instead — the megatron-style combine —
         # reassociates the K contraction and is NOT bit-identical.)
         out = jax.lax.all_gather(out, model_axis, axis=-2, tiled=True)
-    out = L.heads_out(p["o"], out, dtype=cdt)
-    out = shard(out, "act_batch,act_seq,act_embed")
+    with jax.named_scope("attn_proj"):
+        out = L.heads_out(p["o"], out, dtype=cdt)
+        out = shard(out, "act_batch,act_seq,act_embed")
     return out, new_cache

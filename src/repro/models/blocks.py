@@ -131,7 +131,8 @@ def block_apply(p, x, cfg: ModelConfig, kind: str, *, positions=None,
 
     if _is_attn(kind):
         akind = kind if kind in ("local", "global") else "global"
-        h = L.norm_apply(p["attn_norm"], x, kind=cfg.norm)
+        with jax.named_scope("attn_proj"):
+            h = L.norm_apply(p["attn_norm"], x, kind=cfg.norm)
         attn_cache = cache.get("attn") if cache is not None else None
         h, attn_cache = ATT.attention_apply(
             p["attn"], h, cfg, kind=akind, positions=positions,
@@ -153,13 +154,14 @@ def block_apply(p, x, cfg: ModelConfig, kind: str, *, positions=None,
             h, _ = ATT.attention_apply(p["xattn"], h, cfg, cond=cond,
                                        cache=xc, merged=merged)
             x = x + h
-        h = L.norm_apply(p["mlp_norm"], x, kind=cfg.norm)
-        if kind == "attn_moe":
-            h, aux = _moe_apply(p["moe"], h, cfg)
-        else:
-            h = mlp_apply(p["mlp"], h, cfg)
-        if cfg.post_block_norm:
-            h = L.norm_apply(p["mlp_post_norm"], h, kind=cfg.norm)
+        with jax.named_scope("mlp"):
+            h = L.norm_apply(p["mlp_norm"], x, kind=cfg.norm)
+            if kind == "attn_moe":
+                h, aux = _moe_apply(p["moe"], h, cfg)
+            else:
+                h = mlp_apply(p["mlp"], h, cfg)
+            if cfg.post_block_norm:
+                h = L.norm_apply(p["mlp_post_norm"], h, kind=cfg.norm)
         x = x + h
         if cache is not None:
             new_cache = dict(cache, attn=attn_cache)

@@ -92,9 +92,14 @@ def lm_apply(p, cfg: ModelConfig, *, tokens=None, embeds=None, cond=None,
         positions = idx[:, None] + jnp.arange(s)[None, :]
     # decode: caller passes positions (= cache index) for rope/sinusoidal
 
-    x = F.frontend_apply(p, cfg, tokens=tokens, embeds=embeds,
-                         positions=positions)
-    x = shard(x, "act_batch,act_seq,act_embed")
+    # named scopes (``embed``, ``layers``, ``unembed_sample``, and inside
+    # the blocks ``attn_proj``, ``kv_cache``, ``attn``, ``mlp``) live only in
+    # the HLO's op_name metadata, where a device trace reads which part of
+    # the model each op belongs to
+    with jax.named_scope("embed"):
+        x = F.frontend_apply(p, cfg, tokens=tokens, embeds=embeds,
+                             positions=positions)
+        x = shard(x, "act_batch,act_seq,act_embed")
 
     def super_step(x, bp, cache_in):
         new_caches = {} if cache_in is not None else None
@@ -123,7 +128,8 @@ def lm_apply(p, cfg: ModelConfig, *, tokens=None, embeds=None, cond=None,
         elif remat == "dots":
             body = jax.checkpoint(
                 body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-        x, auxs = jax.lax.scan(body, x, p["blocks"])
+        with jax.named_scope("layers"):
+            x, auxs = jax.lax.scan(body, x, p["blocks"])
         new_caches = None
         aux = jnp.sum(auxs)
     else:
@@ -131,26 +137,29 @@ def lm_apply(p, cfg: ModelConfig, *, tokens=None, embeds=None, cond=None,
             bp, ci = xs
             y, co, aux = super_step(x, bp, ci)
             return y, (co, aux)
-        x, (new_caches, auxs) = jax.lax.scan(body, x, (p["blocks"], caches))
+        with jax.named_scope("layers"):
+            x, (new_caches, auxs) = jax.lax.scan(body, x,
+                                                 (p["blocks"], caches))
         aux = jnp.sum(auxs)
 
-    x = L.norm_apply(p["final_norm"], x, kind=cfg.norm)
-    if logits_index is not None:
-        li = jnp.asarray(logits_index)
-        if li.ndim == 0:
-            x = jax.lax.dynamic_slice_in_dim(x, li, 1, axis=1)
-        else:                                  # (b,) per-batch row gather
-            x = jnp.take_along_axis(x, li[:, None, None], axis=1)
-    elif logits_slice is not None:
-        x = x[:, logits_slice]
-    logits = L.unembed(p["embed"], x, dtype=cfg.cdtype())
-    if cfg.final_softcap > 0:
-        logits = (cfg.final_softcap
-                  * jnp.tanh(logits.astype(jnp.float32) / cfg.final_softcap))
-    logits = shard(logits, "act_batch,act_seq,act_vocab")
-    if logits_epilogue is not None:
-        return logits_epilogue(logits, new_caches), new_caches, aux
-    return logits, new_caches, aux
+    with jax.named_scope("unembed_sample"):
+        x = L.norm_apply(p["final_norm"], x, kind=cfg.norm)
+        if logits_index is not None:
+            li = jnp.asarray(logits_index)
+            if li.ndim == 0:
+                x = jax.lax.dynamic_slice_in_dim(x, li, 1, axis=1)
+            else:                              # (b,) per-batch row gather
+                x = jnp.take_along_axis(x, li[:, None, None], axis=1)
+        elif logits_slice is not None:
+            x = x[:, logits_slice]
+        logits = L.unembed(p["embed"], x, dtype=cfg.cdtype())
+        if cfg.final_softcap > 0:
+            logits = (cfg.final_softcap * jnp.tanh(
+                logits.astype(jnp.float32) / cfg.final_softcap))
+        logits = shard(logits, "act_batch,act_seq,act_vocab")
+        if logits_epilogue is not None:
+            return logits_epilogue(logits, new_caches), new_caches, aux
+        return logits, new_caches, aux
 
 
 # --------------------------------------------------------------- caches ----

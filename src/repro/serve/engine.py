@@ -49,6 +49,7 @@ else raises at construction).
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 
 import jax
@@ -62,6 +63,23 @@ from repro.models import transformer as T
 from repro.serve import sampling as S
 from repro.serve.sampling import SamplingParams
 from repro.serve.scheduler import PagePool, Scheduler
+
+Span = jax.profiler.TraceAnnotation
+_gc_open: list = []
+
+
+def _gc_span(phase: str, info: dict):
+    """``gc.callbacks`` hook: a ``gc`` span around every collection, on the
+    profiler's clock, so that a host stall can be shown to be one or not.
+    Registered once, when this module is imported."""
+    if phase == "start":
+        _gc_open.append(Span("gc", generation=info["generation"]))
+        _gc_open[-1].__enter__()
+    elif _gc_open:
+        _gc_open.pop().__exit__(None, None, None)
+
+
+gc.callbacks.append(_gc_span)
 
 
 def _has_attention(cfg: ModelConfig) -> bool:
@@ -424,9 +442,16 @@ class ContinuousBatchingEngine:
         self.results: dict[int, list[int]] = {}
         self.prefilled_tokens = 0          # chunk tokens actually computed —
                                            # warm admissions skip cached rows
-        self.ttft: dict[int, float] = {}   # uid -> seconds submit->1st token
-        self._t_submit: dict[int, float] = {}
-        self._steps = 0
+        # host telemetry, read by the benchmark: counts over the engine's
+        # life, and per-request perf_counter stamps (``submitted``,
+        # ``admitted``, ``first_token``, ``finished``) taken where each
+        # event happens; the spans of ``step`` carry the same boundaries
+        # on the profiler's clock
+        self.counters = dict.fromkeys(
+            ("steps", "decode_steps", "decode_active_slots",
+             "prefill_chunks", "cow_copies", "table_uploads",
+             "host_syncs"), 0)
+        self.request_times: dict[int, dict[str, float]] = {}
         self._submits = 0                  # drives default-policy seed + k
         self._chunk = scfg.prefill_chunk
         self._budget = scfg.prefill_budget or self._chunk
@@ -601,7 +626,7 @@ class ContinuousBatchingEngine:
         self._submits += 1
         uid = self.scheduler.submit(prompt, max_new_tokens, eos_id,
                                     sampling=sp)
-        self._t_submit[uid] = time.perf_counter()
+        self.request_times[uid] = {"submitted": time.perf_counter()}
         return uid
 
     def run(self, max_steps: int | None = None) -> dict[int, list[int]]:
@@ -621,12 +646,44 @@ class ContinuousBatchingEngine:
     def step(self):
         """One engine iteration: admit (writing sampling-bank rows),
         prefill up to the token budget, then one shared decode step for
-        the DECODING slots."""
+        the DECODING slots. Requests that complete are finished last, each
+        phase in a span of its own (``engine.*``) under ``engine.step``."""
+        c = self.counters
+        with jax.profiler.StepTraceAnnotation("engine.step",
+                                              step_num=c["steps"]):
+            with Span("engine.admit"):
+                self._admit()
+                plan = self.scheduler.prefill_plan(self._chunk, self._budget)
+            done = []
+            for slot, start, n in plan:
+                uid = self.scheduler.slots[slot].request.uid
+                with Span("engine.prefill", uid=uid, slot=slot):
+                    if self._prefill_one(slot, start, n):
+                        done.append(slot)
+            # a request done at its first token leaves before the decode
+            # step, which would otherwise serve it one more
+            for slot in done:
+                self._finish(slot)
+            decoding = self.scheduler.decoding()
+            if decoding:
+                with Span("engine.decode"):
+                    sampled = self._decode_once(decoding)
+                with Span("engine.record"):
+                    done = [slot for slot, _ in decoding
+                            if self.scheduler.record(slot, int(sampled[slot]))]
+                for slot in done:
+                    self._finish(slot)
+            elif not plan:
+                return  # nothing queued, nothing active
+        c["steps"] += 1
+
+    def _admit(self):
         while True:
             admitted = self.scheduler.admit()
             if admitted is None:
-                break
+                return
             slot, req = admitted
+            self.request_times[req.uid]["admitted"] = time.perf_counter()
             self.bank = S.bank_put(self.bank, slot, req.sampling)
             state = self.scheduler.slots[slot]
             if self.paged and state.filled:
@@ -637,14 +694,6 @@ class ContinuousBatchingEngine:
                 self.caches = self._set_index(
                     self.caches, jnp.asarray(slot, jnp.int32),
                     jnp.asarray(state.filled, jnp.int32))
-        plan = self.scheduler.prefill_plan(self._chunk, self._budget)
-        for slot, start, n in plan:
-            self._prefill_one(slot, start, n)
-        if self.scheduler.decoding():
-            self._decode_once()
-        elif not plan:
-            return  # nothing queued, nothing active
-        self._steps += 1
 
     def compile_step(self, name: str):
         """Compile the ``"prefill"`` or ``"decode"`` step ahead of any
@@ -701,13 +750,27 @@ class ContinuousBatchingEngine:
         mutations (the common case: one token, no new page) reuse the
         resident buffer instead of paying a host transfer per token."""
         if self._table_version != self.pool.version:
-            table = jnp.asarray(self.pool.table)
-            if self.plan is not None:
-                # GLOBAL table, replicated: each shard localizes it in-step
-                table = self.plan.put(table, self.plan.replicated)
+            with Span("engine.table_upload"):
+                table = jnp.asarray(self.pool.table)
+                if self.plan is not None:
+                    # GLOBAL table, replicated: each shard localizes it
+                    # in-step
+                    table = self.plan.put(table, self.plan.replicated)
             self._table_dev = table
             self._table_version = self.pool.version
+            self.counters["table_uploads"] += 1
         return self._table_dev
+
+    def _cow_copy(self, copies):
+        """Run the allocator's copy-on-write page copies (src, dst)."""
+        if not copies:
+            return
+        with Span("engine.cow_copy"):
+            for src, dst in copies:
+                self.caches = self._copy_page(
+                    self.caches, jnp.asarray(src, jnp.int32),
+                    jnp.asarray(dst, jnp.int32))
+        self.counters["cow_copies"] += len(copies)
 
     def _decode_inputs(self, tokens, active) -> dict:
         inputs = {"tokens": tokens, "active": jnp.asarray(active)}
@@ -715,8 +778,11 @@ class ContinuousBatchingEngine:
             inputs["page_table"] = self._device_table()
         return inputs
 
-    def _prefill_one(self, slot: int, start: int, n: int):
-        prompt = self.scheduler.slots[slot].request.prompt
+    def _prefill_one(self, slot: int, start: int, n: int) -> bool:
+        """One prompt chunk; True when the request is done (its first token
+        was its last) and must be finished."""
+        request = self.scheduler.slots[slot].request
+        prompt = request.prompt
         chunk = prompt[start:start + n] + [0] * (self._chunk - n)
         page_row = None
         if self.paged:
@@ -725,12 +791,10 @@ class ContinuousBatchingEngine:
             # re-score of a fully cached prompt lands in the shared last
             # page, so its COW copy must run before this chunk's K/V write
             _, copies = self.pool.ensure_writable(slot, start, start + n)
-            for src, dst in copies:
-                self.caches = self._copy_page(
-                    self.caches, jnp.asarray(src, jnp.int32),
-                    jnp.asarray(dst, jnp.int32))
+            self._cow_copy(copies)
             page_row = self._device_table()[slot:slot + 1]
         self.prefilled_tokens += n
+        self.counters["prefill_chunks"] += 1
         out, self.caches = self._prefill(
             self.params, self.caches, jnp.asarray(slot, jnp.int32),
             jnp.asarray(chunk, jnp.int32)[None, :],
@@ -742,25 +806,31 @@ class ContinuousBatchingEngine:
             # prefixes admit warm
             state = self.scheduler.slots[slot]
             self.pool.commit_prefix(slot, prompt, state.filled)
-        if done:
-            # prompt complete: the chunk's output is the first token of the
-            # request (sampled in-step when fused; from logits otherwise)
+        if not done:
+            return False
+        # prompt complete: the chunk's output is the first token of the
+        # request (sampled in-step when fused; from logits otherwise)
+        with Span("engine.first_token"):
             if self.fused:
                 tok = int(out[0])
-                self._last = self._last.at[slot].set(tok)
             else:
                 state = self.scheduler.slots[slot]
                 tok = int(S.sample_tokens(
                     out, S.bank_take(self.bank, slice(slot, slot + 1)),
                     jnp.asarray([state.filled], jnp.int32))[0])
-            uid = self.scheduler.slots[slot].request.uid
-            if uid in self._t_submit:
-                self.ttft[uid] = time.perf_counter() - self._t_submit.pop(uid)
-            if self.scheduler.record(slot, tok):
-                self._finish(slot)
+        self.counters["host_syncs"] += 1
+        self.request_times[request.uid]["first_token"] = time.perf_counter()
+        if self.fused:
+            self._last = self._last.at[slot].set(tok)
+        return self.scheduler.record(slot, tok)
 
-    def _decode_once(self):
-        decoding = self.scheduler.decoding()
+    def _decode_once(self, decoding) -> np.ndarray:
+        """One shared decode step over the DECODING slots; the
+        (max_slots,) tokens it sampled, on the host."""
+        c = self.counters
+        c["decode_steps"] += 1
+        c["decode_active_slots"] += len(decoding)
+        c["host_syncs"] += 1
         active = np.zeros((self.scfg.max_slots,), bool)
         for slot, state in decoding:
             active[slot] = True
@@ -772,17 +842,15 @@ class ContinuousBatchingEngine:
                 # the COW invariant is enforced here, not assumed)
                 rows = state.filled + len(state.generated)
                 _, copies = self.pool.ensure_writable(slot, rows - 1, rows)
-                for src, dst in copies:
-                    self.caches = self._copy_page(
-                        self.caches, jnp.asarray(src, jnp.int32),
-                        jnp.asarray(dst, jnp.int32))
+                self._cow_copy(copies)
         if self.fused:
             # device-side feedback: last tokens in, next tokens out — the
             # only host traffic is draining the (max_slots,) token vector
             self._last, self.caches = self._decode(
                 self.params, self.caches,
                 self._decode_inputs(self._last, active), self.bank)
-            sampled = np.asarray(self._last)
+            with Span("engine.decode_drain"):
+                sampled = np.asarray(self._last)
         else:
             # legacy A/B baseline: ship (max_slots, vocab) logits to the
             # host and sample there — through the SAME per-slot schedule
@@ -798,15 +866,18 @@ class ContinuousBatchingEngine:
             drawn = S.sample_tokens(logits[rows], S.bank_take(self.bank,
                                                               rows), pos)
             sampled = np.zeros((self.scfg.max_slots,), np.int32)
-            sampled[rows] = np.asarray(drawn)
-        for slot, _ in decoding:
-            if self.scheduler.record(slot, int(sampled[slot])):
-                self._finish(slot)
+            with Span("engine.decode_drain"):
+                sampled[rows] = np.asarray(drawn)
+        return sampled
 
     def _finish(self, slot: int):
-        uid, generated = self.scheduler.finish(slot)
-        self.results[uid] = generated
-        self.caches = self._reset(self.caches, jnp.asarray(slot, jnp.int32))
+        uid = self.scheduler.slots[slot].request.uid
+        with Span("engine.finish", uid=uid, slot=slot):
+            _, generated = self.scheduler.finish(slot)
+            self.results[uid] = generated
+            self.caches = self._reset(self.caches,
+                                      jnp.asarray(slot, jnp.int32))
+        self.request_times[uid]["finished"] = time.perf_counter()
 
 
 # --------------------------------------------------- dry-run entry point ----

@@ -113,7 +113,9 @@ def test_cow_fires_under_live_sharer_and_streams_stay_bit_identical():
     assert ceng.pool.cow_copies == 0
     assert weng.prefilled_tokens < ceng.prefilled_tokens
     assert weng.pool.free_pages == 16          # drained: all refs dropped
-    assert weng.ttft[1] >= 0.0                 # TTFT recorded per uid
+    t = weng.request_times[1]                  # stamped per uid, in order
+    assert t["submitted"] <= t["admitted"] <= t["first_token"] \
+        <= t["finished"]
 
 
 # ---------------------------------------------------------------- fork ----

@@ -90,3 +90,35 @@ def test_serving_kernel_compiles_for_v5e(one_chip, kernel, arch, kv_dtype):
     fn, args = _launch(kernel, cfg, kv_dtype, shape)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_engine_decode_step_keeps_its_names_for_v5e(one_chip, monkeypatch):
+    """Named scopes live in the ops' metadata only: the engine's paged
+    decode step, compiled for a v5e at qwen2-1.5b's attention widths (two
+    layers, a small vocabulary), keeps its module name and the kernel's
+    custom-call name, which a device trace is read by, and its ops carry
+    the ``kv_cache`` scope."""
+    from repro.configs.base import ServeConfig
+    from repro.kernels.consmax_decode import ops as decode_ops
+    from repro.models import transformer as T
+    from repro.nn import module as nn
+    from repro.serve.engine import ContinuousBatchingEngine
+    monkeypatch.setattr(decode_ops, "_on_cpu", lambda: False)
+    cfg = get_config("qwen2-1.5b", n_layers=2, vocab_size=1024,
+                     param_dtype="bfloat16")
+    scfg = ServeConfig(score_norm=cfg.score_norm, max_slots=SLOTS,
+                       max_seq=1024, paged_kv=True, page_size=PAGE,
+                       prefill_chunk=CHUNK, decode_kernel=True,
+                       prefill_kernel=True)
+    eng = ContinuousBatchingEngine(cfg, scfg, nn.abstract_init(T.lm_init,
+                                                                cfg))
+    place = functools.partial(jax.tree.map, lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip))
+    inputs = eng._decode_inputs(eng._last, [False] * SLOTS)
+    text = eng._decode.lower(place(eng.params), place(eng.caches),
+                             place(inputs), place(eng.bank)).compile(
+                             ).as_text()
+    assert text.startswith("HloModule jit_decode_step")
+    assert "%consmax_decode_paged_op" in text
+    assert "tpu_custom_call" in text
+    assert "/kv_cache/" in text
