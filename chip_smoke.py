@@ -1,7 +1,9 @@
 """Chip smoke test: ConSmax serving at qwen2-1.5b's published width on a TPU.
 
-    python3 chip_smoke.py             # one chip: phases A and B
-    python3 chip_smoke.py --chips 4   # a four-chip host: the sharded engine
+    python3 chip_smoke.py                  # one chip: phases A and B
+    python3 chip_smoke.py --chips 4        # a four-chip host: sharded engine
+    python3 chip_smoke.py --decode-timing  # one chip: the paged decode
+                                           # kernel alone, timed
 
 Phase A compiles each of the four ConSmax serving kernels (decode, paged
 decode, prefill, paged prefill) with Mosaic at qwen2-1.5b widths, with a
@@ -27,6 +29,14 @@ On a TPU v5e that identity does not hold today: the tensor-parallel axis
 changes the logits (see the README's "Sharded serving"), so this phase
 fails there. It serves through the host-sampling path, which returns the
 logits, so it is slow: about eight minutes on a four-chip v5e host.
+
+``--decode-timing`` times only the paged decode kernel at the shapes of
+the benchmark's ``qwen2-1.5b.decode-long`` cell: 24 slots, a 32-column
+table of 256-row pages in random pool order, contexts spread over
+2,100-7,400 rows, bf16 K/V at qwen2-1.5b widths, 28 calls (one per
+layer) in one compiled program. It prints milliseconds per call,
+microseconds per live page and the kernel's share of its roofline by
+``bench/costs.decode_kernel``'s count and ``bench/peaks.json``.
 
 Earlier lines are facts about the run: device, compile seconds, wall time,
 tokens, peak device memory. None of them is a speed claim. The last line is
@@ -178,6 +188,73 @@ def phase_kernels(cfg, seed):
                  f"by {ratio:.3f}x the tolerance")
 
 
+# ------------------------------------------------ the decode kernel alone ----
+DT_SLOTS, DT_COLUMNS, DT_CONTEXTS, DT_CALLS = 24, 32, (2100, 7400), 28
+
+
+def decode_timing(cfg, dev, seed):
+    """The paged decode kernel alone at decode-long's shapes: one line of
+    JSON with its time per call, per live page and its roofline share."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    sys.path.insert(0, str(ROOT))
+    from bench import costs
+    from repro.kernels.consmax_decode.kernel import consmax_decode_paged
+
+    H, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
+    pages = DT_SLOTS * DT_COLUMNS
+    rng = np.random.default_rng(seed)
+    lens = np.linspace(*DT_CONTEXTS, DT_SLOTS).round().astype(np.int32)
+    rng.shuffle(lens)
+    table = rng.permutation(pages).astype(np.int32).reshape(DT_SLOTS,
+                                                            DT_COLUMNS)
+    live = -(-lens // PAGE)
+    for i, n in enumerate(live):
+        table[i, n:] = -1                    # unmapped past the fill
+    key = jax.random.key(seed)
+
+    def rnd(i, shape):
+        return jax.random.normal(jax.random.fold_in(key, i), shape,
+                                 jnp.float32).astype(jnp.bfloat16)
+
+    args = (rnd(1, (DT_SLOTS, H, d)), rnd(2, (pages, PAGE, hkv, d)),
+            rnd(3, (pages, PAGE, hkv, d)), jnp.asarray(table),
+            jnp.asarray(lens), jnp.linspace(0.5, 2.5, H, dtype=jnp.float32),
+            jnp.full((H,), 2.0, jnp.float32))
+
+    def calls(q, *rest):
+        # each call's q depends on the last output, so none is hoisted
+        def body(_, acc):
+            qi = (q.astype(jnp.float32) + acc * 1e-30).astype(q.dtype)
+            return acc + consmax_decode_paged(
+                qi, *rest, scale=1.0, interpret=False).astype(jnp.float32)
+        return jax.lax.fori_loop(0, DT_CALLS, body,
+                                 jnp.zeros(q.shape, jnp.float32))
+
+    compiled = jax.jit(calls).lower(*args).compile()
+    compiled(*args).block_until_ready()
+    times = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        compiled(*args).block_until_ready()
+        times.append(time.perf_counter() - t0)
+    per_call = float(np.median(times)) / DT_CALLS
+    shape = types.SimpleNamespace(h=H, hkv=hkv, dk=d)
+    flops, nbytes = costs.decode_kernel(lens.tolist(), shape)
+    least, bound = costs.least_time(flops, nbytes,
+                                    costs.peaks(dev.device_kind))
+    say(json.dumps({
+        "decode_timing": {"slots": DT_SLOTS, "columns": DT_COLUMNS,
+                          "live_pages": int(live.sum()), "calls": DT_CALLS},
+        "ms_per_call": per_call * 1e3,
+        "us_per_live_page": per_call / int(live.sum()) * 1e6,
+        "roofline_pct": 100.0 * least / per_call, "bound": bound}))
+
+
 # ------------------------------------------------------- phase B: engine ----
 def engine_flags(seed, *extra):
     return ["--arch", ARCH, "--published", "--engine", "continuous",
@@ -271,6 +348,9 @@ def main(argv=None):
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4 = only the sharded engine on a 2x2 mesh, "
                          "compared with one device")
+    ap.add_argument("--decode-timing", action="store_true",
+                    help="time only the paged decode kernel at the "
+                         "decode-long cell's shapes")
     ap.add_argument("--seed", type=int, default=0,
                     help="seed of the weights, kernel inputs and requests")
     args = ap.parse_args(argv)
@@ -302,7 +382,9 @@ def main(argv=None):
         f"weights from seed {args.seed}")
 
     t_all = time.perf_counter()
-    if args.chips == 1:
+    if args.decode_timing:
+        decode_timing(cfg, dev, args.seed)
+    elif args.chips == 1:
         phase_kernels(cfg, args.seed)
         serve(engine_flags(args.seed), args.seed, "phase B")
     else:

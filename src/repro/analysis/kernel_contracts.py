@@ -14,7 +14,7 @@ Checks (the kernel half of the serving contract):
 * ``vmem-budget`` — per-program VMEM working-set estimate:
   ``2 x (input blocks + output blocks) + scratch`` (the factor 2 is
   Mosaic's double-buffered pipeline), each block counted in its tiled
-  layout (``tiled_bytes``), against a per-core budget, plus a
+  layout (``cache_layout.tiled_bytes``), against a per-core budget, plus a
   per-operand block cap — the class of bug the decode kernel's
   ``_fold_factor`` 2 MB K/V cap exists to prevent, caught at analysis time
   instead of as a Mosaic OOM on hardware.
@@ -85,7 +85,7 @@ class KernelLaunch:
     def vmem_working_set(self) -> int:
         """Double-buffered pipeline estimate: 2 x (in + out) + scratch."""
         blocks = [b for b in self.in_blocks + self.out_blocks
-                  if b.memory_space != "smem"]
+                  if b.memory_space == "vmem"]
         return 2 * sum(b.nbytes for b in blocks) + self.scratch_bytes
 
     def to_json(self) -> dict:
@@ -103,10 +103,12 @@ class KernelLaunch:
 
 
 def _mem_space(spec) -> str:
-    ms = getattr(spec, "memory_space", None)
-    if ms is None:
-        return "any"
-    return "smem" if "smem" in str(ms).lower() else "vmem"
+    """"smem", "vmem", or "any": an operand left where it lives (HBM) for
+    the kernel's own DMAs, which holds no VMEM block."""
+    ms = str(getattr(spec, "memory_space", None)).lower()
+    if "smem" in ms:
+        return "smem"
+    return "any" if "any" in ms or "hbm" in ms else "vmem"
 
 
 def _dim_semantics(compiler_params):
@@ -121,41 +123,23 @@ def _dim_semantics(compiler_params):
     return tuple(ds) if ds is not None else None
 
 
-def tiled_bytes(shape, dtype) -> int:
-    """Bytes a VMEM block of ``shape`` occupies in Mosaic's tiled layout:
-    the last dim pads to 128 lanes; the second-minor dim pads to the dtype's
-    sublane tile (8 rows of 32 bits: 8 fp32, 16 bf16, 32 int8), or, when it
-    is shorter than a tile, to the next power of two (Mosaic's small
-    tilings). So an all-heads scale block ``(rows, hkv)`` costs ``rows``
-    full lane rows, and a ``(…, hkv, dk)`` K/V block costs little more than
-    its data."""
-    itemsize = np.dtype(dtype).itemsize
-    dims = [int(d) for d in shape] or [1]
-    dims[-1] = -(-dims[-1] // 128) * 128
-    if len(dims) >= 2:
-        tile = 8 * max(1, 4 // itemsize)
-        r = dims[-2]
-        dims[-2] = (-(-r // tile) * tile if r >= tile
-                    else 1 << max(0, (r - 1).bit_length()))
-    return int(np.prod(dims, dtype=np.int64)) * itemsize
-
-
 def _block_info(spec, shape, dtype, index_map_default=None) -> BlockInfo:
     bshape = tuple(getattr(spec, "block_shape", None) or shape)
     bshape = tuple(int(d) for d in bshape if d is not None)
     space = _mem_space(spec)
     nbytes = (int(np.prod(bshape, dtype=np.int64)) * np.dtype(dtype).itemsize
-              if space == "smem" else tiled_bytes(bshape, dtype))
+              if space == "smem" else CL.tiled_bytes(bshape, dtype))
     return BlockInfo(bshape, str(np.dtype(dtype)), nbytes, space,
                      getattr(spec, "index_map", index_map_default))
 
 
 def _scratch_bytes(scratch_shapes) -> int:
+    """VMEM the scratch buffers take (semaphores and SMEM take none)."""
     total = 0
     for s in jax.tree_util.tree_leaves(scratch_shapes):
         dt = getattr(s, "dtype", None)
-        if dt is not None:
-            total += tiled_bytes(tuple(getattr(s, "shape", ())), dt)
+        if dt is not None and "vmem" in str(getattr(s, "memory_space", "")):
+            total += CL.tiled_bytes(tuple(getattr(s, "shape", ())), dt)
     return total
 
 
@@ -290,7 +274,7 @@ def check_vmem(launch: KernelLaunch, *,
     for kind, blocks in (("input", launch.in_blocks),
                          ("output", launch.out_blocks)):
         for i, b in enumerate(blocks):
-            if b.memory_space != "smem" and b.nbytes > block_cap_bytes:
+            if b.memory_space == "vmem" and b.nbytes > block_cap_bytes:
                 findings.append(Finding(
                     "vmem-budget", launch.name,
                     f"{kind} block {i} {b.block_shape} {b.dtype} is "

@@ -1,15 +1,20 @@
 """Shared layout / masking helpers for the serving-path ConSmax kernels.
 
 The decode (``consmax_decode``) and prefill (``consmax_prefill``) kernels
-block the model's KV-cache layout ``(b, L, hkv, dk)`` (or the page pool's
-``(P, ps, hkv, dk)``) *directly*: one grid program covers a block of rows
-of **all** hkv heads — K/V blocks ``(…, rows, hkv, dk)``, scale blocks
+read the model's KV-cache layout ``(b, L, hkv, dk)`` (or the page pool's
+``(P, ps, hkv, dk)``) *directly*. The contiguous kernels and the paged
+prefill kernel block it: one grid program covers a block of rows of **all**
+hkv heads — K/V blocks ``(…, rows, hkv, dk)``, scale blocks
 ``(…, rows, hkv)`` — and loops over the heads in its body. The last two
 block dims are then the full ``(hkv, dk)`` / ``(rows, hkv)`` extents, which
 is what Mosaic's tiling rule accepts on the TPU (a unit hkv block in the
-second-minor dim is refused whenever 1 < hkv). No per-step ``swapaxes``
-copy of the cache is ever traced. Everything both kernel families agree
-on lives here:
+second-minor dim is refused whenever 1 < hkv). The paged decode kernel
+walks a run of page-table columns per program: where a bfloat16 pool keeps
+two heads a 32-bit word it fetches each live page with its own DMAs and
+reads it once for all heads (``packs_head_pairs`` / ``unpack_head_pair``);
+other pools arrive one page of all heads per program, as above. No
+per-step ``swapaxes`` copy of the cache is ever traced. Everything the
+kernel families agree on lives here:
 
 * ``divisor_block`` — pick a block size that tiles the cache axis exactly,
   so blocking never needs a full-cache ``jnp.pad`` copy either.
@@ -22,6 +27,11 @@ on lives here:
 * ``kv_head`` / ``consmax_partial`` — one KV head's (dequantized) tile out
   of an all-heads block, and one block's ``p @ v`` partial: the body every
   serving kernel runs once per head.
+* ``packs_head_pairs`` / ``unpack_head_pair`` — when a bfloat16 page keeps
+  two heads in each 32-bit word, and how one pass over the words yields
+  both heads' tiles exactly.
+* ``tiled_bytes`` — the VMEM a block takes in Mosaic's tiled layout: what
+  sizes a paged-decode program's run of pages.
 * ``kv_mask`` — the one causal/length/window mask formula shared by the
   kernels and the jnp walks (``core.attention._kv_walk``): a query at
   absolute position ``qpos`` sees cache row ``kpos`` iff ``kpos < kv_len``,
@@ -54,6 +64,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def divisor_block(n: int, bk: int) -> int:
@@ -69,6 +80,25 @@ def divisor_block(n: int, bk: int) -> int:
     while n % bk:
         bk -= 1
     return bk
+
+
+def tiled_bytes(shape, dtype) -> int:
+    """Bytes a VMEM block of ``shape`` occupies in Mosaic's tiled layout:
+    the last dim pads to 128 lanes; the second-minor dim pads to the dtype's
+    sublane tile (8 rows of 32 bits: 8 fp32, 16 bf16, 32 int8), or, when it
+    is shorter than a tile, to the next power of two (Mosaic's small
+    tilings). So an all-heads scale block ``(rows, hkv)`` costs ``rows``
+    full lane rows, and a ``(…, hkv, dk)`` K/V block costs little more than
+    its data."""
+    itemsize = np.dtype(dtype).itemsize
+    dims = [int(d) for d in shape] or [1]
+    dims[-1] = -(-dims[-1] // 128) * 128
+    if len(dims) >= 2:
+        tile = 8 * max(1, 4 // itemsize)
+        r = dims[-2]
+        dims[-2] = (-(-r // tile) * tile if r >= tile
+                    else 1 << max(0, (r - 1).bit_length()))
+    return int(np.prod(dims, dtype=np.int64)) * itemsize
 
 
 def block_cache_rows(k, v, bk: int):
@@ -134,6 +164,28 @@ def kv_head(x_ref, s_ref, i: int, h: int, dtype):
     if s_ref is None:
         return x_ref[i, :, h].astype(dtype)
     return dequant_block(x_ref[i, :, h], s_ref[i, :, h:h + 1], dtype)
+
+
+def packs_head_pairs(dtype, hkv: int, dk: int) -> bool:
+    """True iff a ``(rows, hkv, dk)`` page stores each row's KV heads two
+    to a 32-bit word: bfloat16, an even head count and 128-lane head rows.
+    XLA's TPU layout then keeps the pool's ``(…, hkv, dk)`` tiles with
+    heads ``2j`` and ``2j + 1`` in the low and high halves of one word, so
+    the page viewed as ``(rows * hkv, dk)`` is the same bytes."""
+    return (jnp.dtype(dtype) == jnp.dtype(jnp.bfloat16) and hkv % 2 == 0
+            and dk == 128)
+
+
+def unpack_head_pair(words, dtype):
+    """``words``: a uint32 tile whose words each hold two bf16 values,
+    the even head's in the low half. Returns the two heads' tiles in
+    ``dtype``: each half moved to the top of an fp32 word is exactly that
+    bf16 value, so the cast loses nothing and the tiles equal the stored
+    values."""
+    lo = jax.lax.bitcast_convert_type(words << 16, jnp.float32)
+    hi = jax.lax.bitcast_convert_type(words & jnp.uint32(0xFFFF0000),
+                                      jnp.float32)
+    return lo.astype(dtype), hi.astype(dtype)
 
 
 def consmax_partial(q, k, v, mask, beta, gamma, *, scale: float,

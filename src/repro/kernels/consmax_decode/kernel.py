@@ -12,17 +12,32 @@ fp32 addition outside the kernel — no rescale pass, no (m, l) exchange, no
 cross-shard ordering. This is the decode-time analogue of flash-decoding's
 split-KV, but without the log-sum-exp combine step softmax forces.
 
-Both variants block the model's cache layout **directly** — contiguous
+Both variants read the model's cache layout **directly** — contiguous
 ``(b, L, hkv, dk)`` rows or the shared ``(P, ps, hkv, dk)`` page pool — so a
-decode step never traces a transposed (or padded) copy of the cache. One
-grid program covers a KV shard (or page) of **all** hkv heads: K/V blocks
-``(…, bk, hkv, dk)``, scale blocks ``(…, bk, hkv)``, per-head beta/gamma
-read from SMEM, and a static loop over the heads in the body. The last two
-block dims are therefore full extents, which Mosaic's TPU tiling rule
-accepts at every width. The block size is chosen by
-``cache_layout.divisor_block`` to tile L exactly. Layout folding, the mask
-formula, the per-head body and the ConSmax weights are shared with the
-prefill kernel via ``kernels/cache_layout.py``.
+decode step never traces a transposed (or padded) copy of the cache.
+
+The contiguous kernel's grid program covers a KV shard of **all** hkv
+heads: K/V blocks ``(…, bk, hkv, dk)``, scale blocks ``(…, bk, hkv)``,
+per-head beta/gamma read from SMEM, and a static loop over the heads in the
+body. The last two block dims are therefore full extents, which Mosaic's
+TPU tiling rule accepts at every width. The block size is chosen by
+``cache_layout.divisor_block`` to tile L exactly.
+
+The paged kernel's program walks a **run** of ``ppb`` columns of one
+slot's page table. Where a bfloat16 pool has an even head count and
+128-lane head rows (qwen2-1.5b), the pools stay in HBM and the program
+fetches each live page of its run with its own DMAs, all in flight before
+the first page is computed, so pages stream in while earlier ones compute;
+``ppb`` is the most pages whose K and V fit ``PAGE_VMEM_BYTES`` of VMEM
+(the whole table at qwen2-1.5b's 32 columns). Each such page is read
+**once** for all heads: it stores a row's heads two to a 32-bit word, so
+the page is loaded as words and every pair of heads comes out of one word
+by a shift and a mask (``cache_layout.unpack_head_pair``), exact and with
+no strided gather per head. Other pools (8 KV heads of dk 64, int8, fp8)
+keep ``ppb = 1``: the page arrives through the BlockSpec index map and is
+sliced per head. Layout folding, the mask formula, the per-head body and
+the ConSmax weights are shared with the prefill kernel via
+``kernels/cache_layout.py``.
 
 Per (slot, kv-head, kv-shard) — the head a static loop inside a program:
 
@@ -51,15 +66,17 @@ full-operand grid sweep in interpret mode) is amortized ``bf``-fold. Each
 folded slot still runs its own 2-D dots, so the result is bit-identical to
 the capacity sweep (``bf = 1``). The ``pl.when`` skip fires per
 (slot-group, shard); per-slot raggedness inside a live group is handled by
-the length mask. The paged variant keeps the per-slot grid: its page-table
-gather is a per-(slot, page) index map that a folded block cannot express.
+the length mask. The paged variant keeps one slot per program: its pages
+are gathered through that slot's table row.
 
 GQA is folded into the q rows: the g = n_heads/n_kv_heads query heads that
 share one KV head form the (g, d) left operand, so the score tile is (g, bk).
 
-VMEM per program: K/V blocks ``bf·bk·hkv·d`` each plus a (bf, hkv, g, d)
-q/out block; ``_fold_factor`` caps the fold so each K/V block stays under
-2 MB counted at fp32 — comfortably double-bufferable.
+VMEM per program: contiguous, K/V blocks ``bf·bk·hkv·d`` each plus a
+(bf, hkv, g, d) q/out block; ``_fold_factor`` caps the fold so each K/V
+block stays under 2 MB counted at fp32 — comfortably double-bufferable.
+Paged, ``ppb`` pages of K and V in scratch (or one pipelined page of K,
+V and scales) plus a (1, hkv, ppb, g, d) partials block.
 """
 from __future__ import annotations
 
@@ -74,15 +91,14 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import cache_layout as CL
 
 
-def _decode_blocks(n_of, slots: int, start, rows, refs, o_ref, *, live_gate,
+def _decode_blocks(n_of, slots: int, start, rows, refs, o_ref, *,
                    bounded: bool, g: int, window: int, **partial_kw):
-    """The body every decode program runs: block slot ``i < slots`` has
-    ``n_of(i)`` valid rows (its decode row sits at ``n_of(i) - 1``), the
-    block holds cache rows ``[start, start + rows)`` of every KV head, and
-    each (slot, head) writes its own fp32 partial ``o_ref[i, h, 0]``.
-    ``live_gate`` is an extra liveness condition (the paged table entry is
-    mapped). Slots run in a loop, not unrolled: Mosaic's compile time grows
-    much faster than linearly in unrolled bodies."""
+    """The body every contiguous decode program runs: block slot
+    ``i < slots`` has ``n_of(i)`` valid rows (its decode row sits at
+    ``n_of(i) - 1``), the block holds cache rows ``[start, start + rows)``
+    of every KV head, and each (slot, head) writes its own fp32 partial
+    ``o_ref[i, h, 0]``. Slots run in a loop, not unrolled: Mosaic's compile
+    time grows much faster than linearly in unrolled bodies."""
     beta_ref, gamma_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref = refs
     hkv = q_ref.shape[1]
 
@@ -105,13 +121,12 @@ def _decode_blocks(n_of, slots: int, start, rows, refs, o_ref, *, live_gate,
     if not bounded:
         compute()
         return
-    # fill bounding: a block past every slot's fill, entirely behind every
-    # window, or an unmapped page would compute only masked-to-zero weights
-    # — write the zeros directly
+    # fill bounding: a block past every slot's fill or entirely behind
+    # every window would compute only masked-to-zero weights — write the
+    # zeros directly
     live = functools.reduce(jnp.logical_or, [
         CL.shard_live(start, rows, n_of(i), qpos_lo=n_of(i) - 1,
                       window=window) for i in range(slots)])
-    live = jnp.logical_and(live, live_gate)
     pl.when(live)(compute)
 
     @pl.when(jnp.logical_not(live))
@@ -127,7 +142,7 @@ def _kernel(len_ref, beta_ref, gamma_ref, q_ref, k_ref, v_ref, *rest,
     _decode_blocks(lambda i: len_ref[ig * bf + i], bf, ik * bk, bk,
                    (beta_ref, gamma_ref, q_ref, k_ref, v_ref,
                     *(scale_refs or (None, None))),
-                   o_ref, live_gate=True, **kw)
+                   o_ref, **kw)
 
 
 def _fold_factor(b: int, bk: int, hkv: int, d: int,
@@ -218,17 +233,109 @@ def consmax_decode(q, k, v, lengths, beta, gamma, *, window: int = 0,
 
 
 # ------------------------------------------------------------- paged KV ----
-def _paged_kernel(tab_ref, len_ref, beta_ref, gamma_ref, q_ref, k_ref, v_ref,
-                  *rest, ps: int, **kw):
-    """Paged pool: program (slot, table entry) — one page of every head.
-    An unmapped entry clamps to page 0; its rows sit at kpos >= n and are
-    masked (or, bounded, the program is skipped)."""
-    *scale_refs, o_ref = rest                        # quantized KV: (ks, vs)
-    ib, ij = pl.program_id(0), pl.program_id(1)
-    _decode_blocks(lambda i: len_ref[ib], 1, ij * ps, ps,
-                   (beta_ref, gamma_ref, q_ref, k_ref, v_ref,
-                    *(scale_refs or (None, None))),
-                   o_ref, live_gate=tab_ref[ib, ij] >= 0, **kw)
+PAGE_VMEM_BYTES = 8 << 20     # fetched pages one paged-decode program holds
+
+
+def _pages_per_program(npg: int, page_bytes: int) -> int:
+    """Table columns one paged-decode program walks when it fetches its own
+    pages: as many as fit ``PAGE_VMEM_BYTES`` (``page_bytes`` counts a
+    page's K and V in VMEM's tiled layout), at least 1, at most the table
+    width."""
+    return max(1, min(npg, PAGE_VMEM_BYTES // page_bytes))
+
+
+def _paged_kernel(tab_ref, len_ref, beta_ref, gamma_ref, q_ref, *refs,
+                  ps: int, ppb: int, npg: int, n_pools: int, packed: bool,
+                  bounded: bool, g: int, window: int, **partial_kw):
+    """Program (slot, run): table columns ``[jb * ppb, jb * ppb + ppb)`` of
+    slot ``ib``; each column writes its own fp32 partial ``o_ref[0, h, i]``
+    per head. Bounded, a column is computed only if it is in the table,
+    mapped and holds a live row (by length and window); the others write
+    exact zeros. Unbounded, every column in the table is computed, unmapped
+    ones from page 0, whose rows sit at kpos >= n.
+
+    ``packed``: the pools are HBM refs of ``(ps * hkv, d)`` word-packed
+    pages, and the program DMAs each column it computes into VMEM scratch
+    itself, all started before the first is waited on, so later pages
+    stream in while earlier ones compute; dead columns are never fetched.
+    Otherwise ``ppb`` is 1 and the refs are the one page (and its scales)
+    the pipeline fetched through the BlockSpec index map."""
+    pools, (o_ref, *scratch) = refs[:n_pools], refs[n_pools:]
+    ib, jb = pl.program_id(0), pl.program_id(1)
+    n = len_ref[ib]
+    hkv, d = q_ref.shape[1], q_ref.shape[-1]
+    dtype = q_ref.dtype
+
+    def column(i):
+        j = jb * ppb + i
+        entry = tab_ref[ib, jnp.minimum(j, npg - 1)]
+        live = j < npg
+        if bounded:
+            live &= (entry >= 0) & CL.shard_live(j * ps, ps, n, qpos_lo=n - 1,
+                                                 window=window)
+        return j, jnp.maximum(entry, 0), live
+
+    if packed:
+        bufs, sem = scratch[:n_pools], scratch[n_pools]
+
+        def copies(i, page):
+            return [pltpu.make_async_copy(src.at[page], dst.at[i],
+                                          sem.at[c, i])
+                    for c, (src, dst) in enumerate(zip(pools, bufs))]
+
+        @pl.loop(0, ppb)
+        def _fetch(i):
+            _, page, live = column(i)
+
+            @pl.when(live)
+            def _():
+                for c in copies(i, page):
+                    c.start()
+    else:
+        bufs = pools
+
+    qs = [q_ref[0, h] for h in range(hkv)]                   # (g, d) each
+    params = [(CL.head_rows(beta_ref, h, g, g),
+               CL.head_rows(gamma_ref, h, g, g)) for h in range(hkv)]
+
+    def heads(i):
+        """Each head's (ps, d) K and V tiles of buffered page ``i``."""
+        if not packed:
+            ks, vs = bufs[2:] or (None, None)
+            return [(CL.kv_head(bufs[0], ks, i, h, dtype),
+                     CL.kv_head(bufs[1], vs, i, h, dtype))
+                    for h in range(hkv)]
+        # one pass over the page's 32-bit words: a row's heads 2p and 2p + 1
+        # share a word, row p of every hkv/2 in the word view
+        out = []
+        for buf in bufs:
+            words = buf.bitcast(jnp.uint32)
+            tiles = []
+            for p in range(hkv // 2):
+                rows = (pl.ds(p, ps, stride=hkv // 2) if hkv > 2
+                        else slice(None))
+                tiles += CL.unpack_head_pair(words[i, rows, :], dtype)
+            out.append(tiles)
+        return zip(*out)
+
+    @pl.loop(0, ppb)
+    def _compute(i):
+        j, page, live = column(i)
+
+        @pl.when(live)
+        def _():
+            if packed:
+                for c in copies(i, page):
+                    c.wait()
+            kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (g, ps), 1)
+            mask = CL.kv_mask(n - 1, kpos, n, window)
+            for h, (k, v) in enumerate(heads(i)):
+                o_ref[0, h, i] = CL.consmax_partial(qs[h], k, v, mask,
+                                                    *params[h], **partial_kw)
+
+        @pl.when(jnp.logical_not(live))
+        def _():
+            o_ref[0, :, i] = jnp.zeros((hkv, g, d), jnp.float32)
 
 
 def consmax_decode_paged(q, kp, vp, page_table, lengths, beta, gamma, *,
@@ -241,21 +348,33 @@ def consmax_decode_paged(q, kp, vp, page_table, lengths, beta, gamma, *,
     lengths: (b,) valid logical rows; beta/gamma: (nh,) fp32.
     ``k_scale``/``v_scale``: (P, ps, nkv) fp32 per-row-per-head quant scale
     pools living beside the page table for a quantized (int8/fp8) KV pool —
-    gathered through the same page index map and upcast per-page in VMEM.
+    fetched with their pages and upcast per page in VMEM.
 
-    The KV grid axis iterates *page-table entries*: the table rides in as a
-    scalar-prefetch operand, so program (ib, ij) DMAs pool page
-    ``page_table[ib, ij]`` (all heads) straight from HBM — the gather lives
-    in the BlockSpec index map, no materialized per-slot contiguous cache.
+    Grid (b, runs): program (ib, jb) walks ``ppb`` consecutive columns of
+    slot ib's table row, which rides in as a scalar-prefetch operand — no
+    materialized per-slot contiguous cache. A bfloat16 pool with an even
+    head count and 128-lane head rows (``CL.packs_head_pairs``, qwen2-1.5b)
+    is read as ``(P, ps * nkv, d)``, the same bytes (a bitcast in XLA),
+    left in HBM (``memory_space=ANY``): each program DMAs the live pages
+    of a run of ``ppb`` columns, the most whose K and V fit
+    ``PAGE_VMEM_BYTES``, and each page yields every head's ``(ps, d)``
+    tile from one pass over its 32-bit words. Any other pool (8 KV heads
+    of dk 64, int8, fp8) keeps ``ppb = 1``: its page, all heads, arrives
+    through the BlockSpec index map and is sliced per head. A manual DMA
+    of such a page would slice dims that are not tile-aligned, which
+    Mosaic refuses, and an aligned view costs XLA a whole-pool relayout
+    per call.
+
     Every grid dim stays ``parallel``: page partials are independent (no
-    running max, no denominator) and combine by the same caller-side fp32
-    addition as the contiguous kernel. With ``fill_bound`` (default) the
-    page axis is clamped to the traced batch-max live page count and
-    per-slot dead pages (unmapped entries, pages past the fill, pages behind
-    the window) are ``pl.when``-skipped, so the table's capacity-sized tail
-    stops costing a program per entry; ``fill_bound=False`` sweeps every
-    table column (the pre-fill-bounding baseline). Unmapped entries clamp
-    to page 0 and contribute exact zeros either way.
+    running max, no denominator), one fp32 partial per (slot, head,
+    column) in a ``(b, nkv, npg, g, d)`` buffer, and they combine by the
+    same caller-side fp32 addition as the contiguous kernel. With
+    ``fill_bound`` (default) the run axis covers the traced batch-max live
+    page count, and per-slot dead columns (unmapped entries, pages past
+    the fill, pages behind the window) are not computed and, when the
+    program fetches its own pages, not fetched; ``fill_bound=False``
+    computes every table column (the pre-fill-bounding baseline). Unmapped
+    entries contribute exact zeros either way.
     """
     b, nh, d = q.shape
     P, ps, nkv = kp.shape[0], kp.shape[1], kp.shape[2]
@@ -268,36 +387,40 @@ def consmax_decode_paged(q, kp, vp, page_table, lengths, beta, gamma, *,
     len1 = lengths.astype(jnp.int32)
     npg_live = (CL.live_blocks(jnp.max(len1), ps, npg) if fill_bound
                 else npg)
+    pools = [kp, vp] + ([k_scale, v_scale] if k_scale is not None else [])
+    packed = len(pools) == 2 and CL.packs_head_pairs(kp.dtype, nkv, d)
+    if packed:
+        pools = [x.reshape(P, ps * nkv, d) for x in pools]
+        ppb = _pages_per_program(npg, 2 * CL.tiled_bytes((ps * nkv, d),
+                                                         kp.dtype))
+        pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        scratch = ([pltpu.VMEM((ppb, ps * nkv, d), kp.dtype)] * 2
+                   + [pltpu.SemaphoreType.DMA((2, ppb))])
+    else:
+        ppb, scratch = 1, []
+
+        def page_map(ib, jb, tab_ref, len_ref):
+            return (jnp.maximum(tab_ref[ib, jb], 0), 0, 0, 0)
+
+        pool_specs = [pl.BlockSpec((1, *x.shape[1:]),
+                                   lambda *a, n=x.ndim: page_map(*a)[:n])
+                      for x in pools]
 
     kernel = functools.partial(_paged_kernel, scale=scale, window=window,
-                               softcap=softcap, ps=ps, g=g, merged=merged,
-                               bounded=fill_bound)
-
-    def page_map(ib, ij, tab_ref, len_ref):
-        return (jnp.maximum(tab_ref[ib, ij], 0), 0, 0, 0)
-
-    def scale_page_map(ib, ij, tab_ref, len_ref):
-        return (jnp.maximum(tab_ref[ib, ij], 0), 0, 0)
-
+                               softcap=softcap, ps=ps, ppb=ppb, npg=npg,
+                               n_pools=len(pools), packed=packed, g=g,
+                               merged=merged, bounded=fill_bound)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    in_specs = [
-        smem, smem,                                             # beta, gamma
-        pl.BlockSpec((1, nkv, g, d), lambda ib, ij, *_: (ib, 0, 0, 0)),
-        pl.BlockSpec((1, ps, nkv, d), page_map),                # k page
-        pl.BlockSpec((1, ps, nkv, d), page_map),                # v page
-    ]
-    operands = [beta.astype(jnp.float32), gamma.astype(jnp.float32),
-                q.reshape(b, nkv, g, d), kp, vp]
-    if k_scale is not None:
-        in_specs += [pl.BlockSpec((1, ps, nkv), scale_page_map)] * 2
-        operands += [k_scale, v_scale]
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                       # page table + lengths
-        grid=(b, npg_live),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, nkv, 1, g, d),
-                               lambda ib, ij, *_: (ib, 0, ij, 0, 0)),
+        grid=(b, (npg_live + ppb - 1) // ppb),
+        in_specs=[smem, smem,                                   # beta, gamma
+                  pl.BlockSpec((1, nkv, g, d),
+                               lambda ib, jb, *_: (ib, 0, 0, 0)),
+                  *pool_specs],
+        out_specs=pl.BlockSpec((1, nkv, ppb, g, d),
+                               lambda ib, jb, *_: (ib, 0, jb, 0, 0)),
+        scratch_shapes=scratch,
     )
     partials = pl.pallas_call(
         kernel,
@@ -306,7 +429,8 @@ def consmax_decode_paged(q, kp, vp, page_table, lengths, beta, gamma, *,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-    )(tab, len1, *operands)
+    )(tab, len1, beta.astype(jnp.float32), gamma.astype(jnp.float32),
+      q.reshape(b, nkv, g, d), *pools)
 
     out = CL.fill_bounded_sum(partials, npg_live)    # the sync-free combine
     return out.reshape(b, nh, d).astype(q.dtype)

@@ -2,11 +2,14 @@
 
 Both kernels consume the model's cache layout — q (b, 1, H, dk), cache k/v
 (b, L, hkv, dk) (or the (P, ps, hkv, dk) page pools), per-slot cache
-``index`` (b,) — directly: the hkv axis is blocked inside the kernel grid,
-so a decode step never pays a full-cache ``swapaxes`` (or pad) copy. The
-valid-kv count per slot is ``index + 1`` (the current token's k/v is written
-into the cache before attention). On CPU (this container) the kernel body
-executes in interpret mode; on a real TPU backend it compiles through Mosaic.
+``index`` (b,) — directly: the contiguous kernel blocks the hkv axis inside
+its grid, and the paged kernel walks runs of table columns, fetching whole
+pages of every head (by its own DMAs where a bf16 pool packs two heads a
+word, then one pass over each page), so a decode step never pays a
+full-cache ``swapaxes`` (or pad) copy. The valid-kv count
+per slot is ``index + 1`` (the current token's k/v is written into the
+cache before attention). On a CPU backend the kernel body executes in
+interpret mode; on a TPU it compiles through Mosaic.
 """
 from __future__ import annotations
 
@@ -54,15 +57,15 @@ def consmax_decode_paged_op(q, kp, vp, page_table, lengths, beta, gamma, *,
                             fill_bound=True, interpret=None, k_scale=None,
                             v_scale=None):
     """Paged-pool variant. q: (b, 1, H, dk); kp, vp: shared page pools
-    (P, ps, hkv, dk) in the model's cache layout (no transpose — the kernel
-    blocks the hkv axis directly, so the pool is never copied per step);
-    page_table: (b, max_pages) int32; lengths: (b,) valid logical rows
-    (index + active, already counting the token written this step).
+    (P, ps, hkv, dk) in the model's cache layout, each program walking a
+    run of table columns (the kernel says how it fetches and reads a
+    page); page_table: (b, max_pages) int32; lengths: (b,) valid logical
+    rows (index + active, already counting the token written this step).
 
     Returns (b, 1, H, dk) in q.dtype. ``fill_bound`` bounds the page-table
     walk by the traced batch-max fill instead of the table's capacity.
     ``k_scale``/``v_scale``: (P, ps, hkv) fp32 scale pools for a quantized
-    KV pool, gathered through the same page-table index map.
+    KV pool, fetched with their pages through the table.
     """
     interp = _on_cpu() if interpret is None else interpret
     out = consmax_decode_paged(q[:, 0], kp, vp, page_table, lengths, beta,

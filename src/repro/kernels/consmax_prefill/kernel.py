@@ -37,9 +37,9 @@ parallel split costs ``ns`` output-sized fp32 partial buffers in HBM; pick
 ``bk`` (ServeConfig.prefill_kv_block) so ns = L/bk stays small on long
 caches.
 
-The paged variant walks *page-table entries* via a scalar-prefetch operand
-(mirroring ``consmax_decode_paged``): program (ib, iq, ij) DMAs pool
-page ``page_table[ib, ij]`` (all heads) straight from HBM. Its page axis
+The paged variant walks *page-table entries* via a scalar-prefetch
+operand: program (ib, iq, ij) DMAs pool page ``page_table[ib, ij]`` (all
+heads) straight from HBM through its BlockSpec index map. Its page axis
 accumulates into VMEM scratch ('arbitrary' trailing dim) instead of
 per-page partial buffers: a chunk's partials are (c*g, d)-sized, so per-page slots would
 cost max_pages_per_slot × chunk-output HBM — at 500k context that is

@@ -449,8 +449,8 @@ class ContinuousBatchingEngine:
         # on the profiler's clock
         self.counters = dict.fromkeys(
             ("steps", "decode_steps", "decode_active_slots",
-             "prefill_chunks", "cow_copies", "table_uploads",
-             "host_syncs"), 0)
+             "decode_kv_pages", "decode_kv_grid_pages", "prefill_chunks",
+             "cow_copies", "table_uploads", "host_syncs"), 0)
         self.request_times: dict[int, dict[str, float]] = {}
         self._submits = 0                  # drives default-policy seed + k
         self._chunk = scfg.prefill_chunk
@@ -832,6 +832,7 @@ class ContinuousBatchingEngine:
         c["decode_active_slots"] += len(decoding)
         c["host_syncs"] += 1
         active = np.zeros((self.scfg.max_slots,), bool)
+        live = []                          # each slot's pages by its length
         for slot, state in decoding:
             active[slot] = True
             if self.paged:
@@ -843,6 +844,12 @@ class ContinuousBatchingEngine:
                 rows = state.filled + len(state.generated)
                 _, copies = self.pool.ensure_writable(slot, rows - 1, rows)
                 self._cow_copy(copies)
+                live.append(-(-rows // self.scfg.page_size))
+        if live:
+            # the pages the decode kernel fetches, against its grid of
+            # max_slots rows of the batch-max live page count
+            c["decode_kv_pages"] += sum(live)
+            c["decode_kv_grid_pages"] += self.scfg.max_slots * max(live)
         if self.fused:
             # device-side feedback: last tokens in, next tokens out — the
             # only host traffic is draining the (max_slots,) token vector

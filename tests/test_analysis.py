@@ -279,6 +279,24 @@ def test_real_serving_kernel_launches_pass_all_contracts(paged):
         assert launches[f"prefill_{kind}"].scratch_bytes > 0
 
 
+def test_paged_decode_page_runs_pass_contracts_at_published_width():
+    """At qwen2-1.5b's widths (two bf16 KV heads a 32-bit word) the paged
+    decode kernel leaves both pools in HBM and DMAs runs of pages into VMEM
+    scratch itself: the launch records the pools as "any" blocks, which
+    hold no VMEM, and its scratch (pages and DMA semaphores) fits the
+    budget with the partials block."""
+    cfg = get_config("qwen2-1.5b")
+    scfg = ServeConfig(max_seq=2048, prefill_chunk=512, max_slots=4,
+                       decode_kernel=True, prefill_kernel=True,
+                       paged_kv=True, page_size=256, score_norm="consmax")
+    launch = serving_launches(cfg, scfg)["decode_paged"]
+    assert not check_launch(launch)
+    assert [b.memory_space for b in launch.in_blocks[-2:]] == ["any", "any"]
+    pages = launch.in_blocks[-1].block_shape[0]
+    assert pages == scfg.num_pages
+    assert 0 < launch.scratch_bytes < launch.vmem_working_set() < 16 << 20
+
+
 def test_capture_launches_restores_pallas_call():
     from jax.experimental import pallas as pl
     real = pl.pallas_call

@@ -51,6 +51,32 @@ def test_counters_agree_with_the_scheduler():
     assert c["cow_copies"] == eng.pool.cow_copies == 0
 
 
+def test_decode_kv_page_counters_follow_slot_lengths():
+    """``decode_kv_pages`` sums the live pages of the slots each decode
+    step serves; ``decode_kv_grid_pages`` is max_slots times the most any
+    of them holds — the decode kernel's fetched pages against its grid."""
+    eng = _engine()
+    ps, want = eng.scfg.page_size, {"pages": 0, "grid": 0}
+    decode_once = eng._decode_once
+
+    def counted(decoding):
+        live = [-(-(st.filled + len(st.generated)) // ps)
+                for _, st in decoding]
+        want["pages"] += sum(live)
+        want["grid"] += eng.scfg.max_slots * max(live)
+        return decode_once(decoding)
+
+    eng._decode_once = counted
+    for i, (n, m) in enumerate([(13, 6), (3, 9), (20, 4)]):
+        eng.submit(_prompt(eng, n, i), m)
+    eng.run()
+    c = eng.counters
+    assert c["decode_kv_pages"] == want["pages"] > 0
+    assert c["decode_kv_grid_pages"] == want["grid"] >= c["decode_kv_pages"]
+    assert c["decode_kv_grid_pages"] <= (c["decode_steps"] * eng.scfg.max_slots
+                                         * eng.scfg.max_pages_per_slot)
+
+
 def test_table_uploads_follow_pool_versions():
     eng = _engine()
     eng.submit(_prompt(eng, 6, 1), 12)

@@ -26,6 +26,8 @@ from jax import random
 
 from repro.configs.base import ServeConfig
 from repro.configs.registry import get_config
+from repro.kernels import cache_layout as CL
+from repro.kernels.consmax_decode import kernel as decode_kernel
 from repro.kernels.consmax_decode.kernel import (consmax_decode,
                                                  consmax_decode_paged)
 from repro.kernels.consmax_decode.ref import consmax_decode_ref
@@ -62,7 +64,7 @@ def _setup(seed=0):
 def _paged(k, v, kv_lens):
     """Scatter the first kv_lens[b] contiguous rows onto a page pool."""
     npg = L // PS
-    kp = jnp.zeros((B * npg + 1, PS, NKV, D), k.dtype)
+    kp = jnp.zeros((B * npg + 1, PS, *k.shape[2:]), k.dtype)
     vp = jnp.zeros_like(kp)
     tab = -jnp.ones((B, npg), jnp.int32)
     pid = 1
@@ -94,17 +96,77 @@ def test_decode_fill_sweep_bit_parity_and_oracle(fill, variant):
                                atol=1e-5)
 
 
+# page walks of the paged decode kernel beyond the plain table, each at
+# the "mid" fill beside a 1-row slot and a full one. All but the quantized
+# walks read bf16 pools of two heads a 32-bit word and 128-lane rows, which
+# the kernel fetches by its own DMAs, a run of columns a program
+PAGED_WALKS = ("bf16_pairs", "fp8", "hole", "int8", "localized",
+               "ragged_runs")
+
+
+def _paged_walk(walk, q, k, v, lens, monkeypatch):
+    """(q, kp, vp, table, scales, the pool as the kernel reads it) for one
+    walk; page 0 of every pool the kernel sees is zeros, as in ``_paged``,
+    so unmapped columns read zeros on the capacity sweep too."""
+    scales = {}
+    if walk in ("int8", "fp8"):
+        kp, vp, tab = _paged(k, v, lens)
+        dt = CL.kv_cache_dtype("fp8_e4m3" if walk == "fp8" else walk)
+        (kp, ksc), (vp, vsc) = CL.quantize_kv(kp, dt), CL.quantize_kv(vp, dt)
+        scales = dict(k_scale=ksc, v_scale=vsc)
+        return (q, kp, vp, tab, scales,
+                [CL.dequantize_kv(kp, ksc), CL.dequantize_kv(vp, vsc)])
+    assert CL.packs_head_pairs(jnp.bfloat16, NKV, 128)
+    ks = random.split(random.key(7), 3)
+    q = random.normal(ks[0], (B, NH, 128))
+    k, v = (random.normal(kk, (B, L, NKV, 128)).astype(jnp.bfloat16)
+            for kk in ks[1:])
+    kp, vp, tab = _paged(k, v, lens)
+    if walk == "hole":            # slot 0's second page unmapped mid-table
+        tab = tab.at[0, 1].set(-1)
+    elif walk == "localized":     # shard 1 of 2 over a block position map
+        pps, npg = B * (L // PS) // 2 + 1, L // PS
+        pages = [iter(range(1, pps)), iter(range(pps + 1, 2 * pps))]
+        glob = np.full((B, npg), -1, np.int32)
+        kp = jnp.zeros((2 * pps, PS, *k.shape[2:]), k.dtype)
+        vp = jnp.zeros_like(kp)
+        for ib in range(B):
+            for j in range(-(-int(lens[ib]) // PS)):
+                glob[ib, j] = pid = next(pages[j // (npg // 2)])
+                kp = kp.at[pid].set(k[ib, j * PS:(j + 1) * PS])
+                vp = vp.at[pid].set(v[ib, j * PS:(j + 1) * PS])
+        tab = CL.localize_page_table(jnp.asarray(glob), 1, pps)
+        kp, vp = kp[pps:], vp[pps:]
+        assert (tab == -1).any() and (tab >= 0).any()
+    elif walk == "ragged_runs":   # 3 columns a program over a 4-column table
+        page = 2 * CL.tiled_bytes((PS * NKV, 128), kp.dtype)
+        monkeypatch.setattr(decode_kernel, "PAGE_VMEM_BYTES", 3 * page)
+        assert decode_kernel._pages_per_program(tab.shape[1], page) == 3
+    return q, kp, vp, tab, scales, [kp, vp]
+
+
 @pytest.mark.parametrize("variant", sorted(VARIANTS))
-@pytest.mark.parametrize("fill", sorted(FILLS))
-def test_decode_paged_fill_sweep_bit_parity_and_oracle(fill, variant):
+@pytest.mark.parametrize("fill", sorted(FILLS) + list(PAGED_WALKS))
+def test_decode_paged_fill_sweep_bit_parity_and_oracle(fill, variant,
+                                                       monkeypatch):
     q, k, v, beta, gamma = _setup(seed=1)
     kw = VARIANTS[variant]
-    lens = jnp.asarray([FILLS[fill], 1, L], jnp.int32)[:B]
-    kp, vp, tab = _paged(k, v, lens)
+    lens = jnp.asarray([FILLS.get(fill, FILLS["mid"]), 1, L], jnp.int32)[:B]
+    if fill in FILLS:
+        kp, vp, tab = _paged(k, v, lens)
+        scales = {}
+    else:
+        q, kp, vp, tab, scales, (kseen, vseen) = _paged_walk(
+            fill, q, k, v, lens, monkeypatch)
+        # the oracle reads what the table maps: unmapped columns page 0
+        k, v = (x[jnp.maximum(tab, 0)].reshape(B, -1, *x.shape[2:])
+                for x in (kseen, vseen))
     bounded = consmax_decode_paged(q, kp, vp, tab, lens, beta, gamma,
-                                   fill_bound=True, interpret=True, **kw)
+                                   fill_bound=True, interpret=True, **kw,
+                                   **scales)
     capacity = consmax_decode_paged(q, kp, vp, tab, lens, beta, gamma,
-                                    fill_bound=False, interpret=True, **kw)
+                                    fill_bound=False, interpret=True, **kw,
+                                    **scales)
     np.testing.assert_array_equal(np.asarray(bounded), np.asarray(capacity))
     ref = consmax_decode_ref(q, k.swapaxes(1, 2), v.swapaxes(1, 2), lens,
                              beta, gamma, **kw)

@@ -6,7 +6,9 @@ for a *described* v5e chip — nothing runs, so this says nothing about
 results or speed — at the widths of qwen2-1.5b (H=12, hkv=2, dk=128) and
 gemma2-2b (H=8, hkv=4, dk=256, window 4096, softcap 50), with a bf16 and an
 int8 KV cache, at the serving shapes the chip smoke test uses: 8 slots of
-4096 rows, 256-row pages, 512-token prefill chunks.
+4096 rows, 256-row pages, 512-token prefill chunks. The paged decode kernel
+also compiles at the benchmark's decode-long table (24 slots x 32 pages) at
+qwen2-1.5b and granite-3-2b widths.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and every pytest-xdist worker
@@ -14,6 +16,7 @@ imports every test file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -90,6 +93,66 @@ def test_serving_kernel_compiles_for_v5e(one_chip, kernel, arch, kv_dtype):
     fn, args = _launch(kernel, cfg, kv_dtype, shape)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+CELL_SLOTS, CELL_COLUMNS = 24, 32      # qwen2-1.5b.decode-long's table
+CELL_WIDTHS = {"qwen2-1.5b": (12, 2, 128), "granite-3-2b": (32, 8, 64)}
+
+
+def _sources(hlo: str, name: str) -> list[str]:
+    """Opcodes from operand ``%name`` of a compiled module back through
+    bitcasts (free reinterpretations) to what made it."""
+    defs = {}
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*)", line)
+        if m:
+            defs[m.group(1)] = m.group(2)
+    chain = []
+    while True:
+        rest = defs[name]
+        op = re.search(r"\s([\w\-]+)\(", rest).group(1)
+        chain.append(op)
+        if op != "bitcast":
+            return chain
+        name = re.search(r"bitcast\(%([\w.\-]+)\)", rest).group(1)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("arch", sorted(CELL_WIDTHS))
+def test_paged_decode_compiles_at_the_cell_shape_for_v5e(one_chip, arch,
+                                                         kv_dtype):
+    """The paged decode kernel at the decode-long cell's table (24 slots x
+    32 pages of 256 rows). At qwen2-1.5b widths in bf16 the pools reach
+    the kernel as bitcasts of the arguments: the (P, ps * hkv, dk) word
+    view is free and no copy of a pool feeds the custom call. (An int8
+    pool and granite's 8 heads of dk 64 are relaid out by XLA first.)"""
+    H, hkv, dk = CELL_WIDTHS[arch]
+    dt = CL.kv_cache_dtype(kv_dtype)
+    quant = CL.kv_quantized(dt)
+    pages = CELL_SLOTS * CELL_COLUMNS
+    shape = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    args = [shape((CELL_SLOTS, H, dk), jnp.bfloat16),
+            *[shape((pages, PAGE, hkv, dk), dt)] * 2,
+            shape((CELL_SLOTS, CELL_COLUMNS), jnp.int32),
+            shape((CELL_SLOTS,), jnp.int32), *[shape((H,), jnp.float32)] * 2]
+    scales = [shape((pages, PAGE, hkv), jnp.float32)] * 2 if quant else []
+
+    def call(*a):
+        ks, vs = a[7:] if quant else (None, None)
+        return consmax_decode_paged(*a[:7], k_scale=ks, v_scale=vs,
+                                    interpret=False)
+
+    text = jax.jit(call).lower(*args, *scales).compile().as_text()
+    kernel = next(line for line in text.splitlines()
+                  if "tpu_custom_call" in line)
+    operands = re.findall(r"%([\w.\-]+)", kernel.split("custom-call(", 1)[1]
+                          .split("custom_call_target", 1)[0])[1:]
+    pools = [o for o in operands
+             if f"[{pages}," in text.split(f"%{o} = ", 1)[1].split("\n")[0]]
+    assert len(pools) == (4 if quant else 2)
+    if arch == "qwen2-1.5b" and not quant:
+        assert all(_sources(text, o)[-1] == "parameter" for o in pools), \
+            [_sources(text, o) for o in pools]
 
 
 def test_engine_decode_step_keeps_its_names_for_v5e(one_chip, monkeypatch):
