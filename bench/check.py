@@ -2,8 +2,10 @@
 
 Once the window has closed and the engine's state is freed, a sample of the
 requests the engine finished, drawn from the seed and always holding the
-one with the most served tokens, is run through the reference (teacher
-forced on each prompt and its served tokens). A greedy token served where
+one with the most served tokens, is run through the model module's
+reference (teacher forced on each prompt and its served tokens): ``gaps``
+is its ``logit_gaps`` with the run's weights and sizes bound,
+``gaps(prompt, served, prec) -> (gap, argmax)``. A greedy token served where
 the reference's best logit lies far above it is wrong; rounding only lets
 a served token sit a little below the best. The numbers compared:
 
@@ -22,7 +24,6 @@ from __future__ import annotations
 import numpy as np
 
 import loadgen
-import reference
 
 SAMPLE_TOKENS = 512      # sample at least this many served tokens ...
 SAMPLE_MAX = 4           # ... from at most this many requests
@@ -53,26 +54,26 @@ def sample(finished: dict, req_of: dict, seed: int) -> list[int]:
     return chosen
 
 
-def compare(params, sizes: reference.Sizes, finished: dict, req_of: dict,
-            seed: int, vocab: int, limits: dict) -> tuple[bool, dict, dict]:
+def compare(gaps, finished: dict, req_of: dict, seed: int, vocab: int,
+            limits: dict) -> tuple[bool, dict, dict]:
     """(correct, numbers with limits, facts about the sample)."""
     mism = sum(1 for u, toks in finished.items()
                if len(toks) != req_of[u].output_len)
     oov = sum(1 for toks in finished.values()
               for t in toks if not 0 <= t < vocab)
     chosen = sample(finished, req_of, seed)
-    gaps = []
+    got = []
     for u in chosen:
         req = req_of[u]
         prompt = loadgen.prompt_tokens(seed, req.index, req.prompt_len,
                                        vocab)
         served = np.clip(np.asarray(finished[u]), 0, vocab - 1)
-        gaps.append(reference.logit_gaps(params, sizes, prompt, served)[0])
-    gaps = np.concatenate(gaps) if gaps else np.zeros(1)
+        got.append(gaps(prompt, served)[0])
+    got = np.concatenate(got) if got else np.zeros(1)
     numbers = {
-        "max_logit_gap": {"value": float(gaps.max()),
+        "max_logit_gap": {"value": float(got.max()),
                           "limit": limits["max_logit_gap"]},
-        "mean_logit_gap": {"value": float(gaps.mean()),
+        "mean_logit_gap": {"value": float(got.mean()),
                            "limit": limits["mean_logit_gap"]},
         "length_mismatches": {"value": mism, "limit": 0},
         "out_of_vocab": {"value": oov, "limit": 0},
@@ -80,13 +81,13 @@ def compare(params, sizes: reference.Sizes, finished: dict, req_of: dict,
     ok = bool(chosen) and all(v["value"] <= v["limit"]
                               for v in numbers.values())
     facts = {"finished": len(finished), "sampled_requests": len(chosen),
-             "sampled_tokens": int(gaps.size),
-             "share_below_best": float(np.mean(gaps > 0))}
+             "sampled_tokens": int(got.size),
+             "share_below_best": float(np.mean(got > 0))}
     return ok, numbers, facts
 
 
-def control_tokens(params, sizes: reference.Sizes, finished: dict,
-                   req_of: dict, seed: int, vocab: int) -> dict:
+def control_tokens(gaps, finished: dict, req_of: dict, seed: int,
+                   vocab: int) -> dict:
     """The control in the program's place: at every position the check
     reads, the float8 reference's first choice (teacher-forced on the same
     prompt and served tokens), as the tokens ``compare`` then judges."""
@@ -96,7 +97,6 @@ def control_tokens(params, sizes: reference.Sizes, finished: dict,
         prompt = loadgen.prompt_tokens(seed, req.index, req.prompt_len,
                                        vocab)
         served = np.clip(np.asarray(finished[u]), 0, vocab - 1)
-        _, low_top = reference.logit_gaps(params, sizes, prompt, served,
-                                          prec="fp8")
+        _, low_top = gaps(prompt, served, prec="fp8")
         out[u] = [int(t) for t in low_top]
     return out

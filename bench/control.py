@@ -37,12 +37,11 @@ def main(argv=None):
     for seed in args.seeds:
         got = {}
 
-        def control(params, sizes, finished, req_of, seed=seed, got=got):
-            tokens = check.control_tokens(params, sizes, finished, req_of,
-                                          seed, sizes.vocab)
+        def control(gaps, finished, req_of, vocab, seed=seed, got=got):
+            tokens = check.control_tokens(gaps, finished, req_of, seed,
+                                          vocab)
             got["correct"], got["numbers"], got["facts"] = check.compare(
-                params, sizes, tokens, req_of, seed, sizes.vocab,
-                cell.cell["limits"])
+                gaps, tokens, req_of, seed, vocab, cell.cell["limits"])
 
         res = run.run_cell(cell, seed, args.seconds, False,
                            t_start=time.perf_counter(), after_check=control)

@@ -1,5 +1,9 @@
 """Operations and bytes that the algorithm needs, from shapes and filled
-rows alone.
+rows alone: the chip's peaks, the least time, and the paged ConSmax
+attention kernels' counts from the widths they are called at (an object
+with ``h``, ``hkv`` and ``dk``). What a step holds, and so how many calls
+of which kernel at which widths and lengths, is a model module's to count
+(``bench/models/<name>.py``).
 
 Counted: only the rows actually filled (never capacity, page rounding,
 padding or inactive slots); K and V at their storage dtype; weights at
@@ -35,11 +39,13 @@ def least_time(flops: float, nbytes: float, pk: dict) -> tuple[float, str]:
 
 # ---------------------------------------------------------------- kernels ----
 def decode_kernel(ctx: list[int], s, kv_bytes: int = 2,
-                  act_bytes: int = 2) -> tuple[float, float]:
-    """One layer's paged decode attention over active slots with contexts
-    ``ctx`` (rows attended, the new token's included): (FLOPs, bytes).
-    Reads each slot's K and V rows once, q in and the output out."""
-    rows = sum(ctx)
+                  act_bytes: int = 2, window: int = 0) -> tuple[float, float]:
+    """One call of the paged decode attention over active slots with
+    contexts ``ctx`` (rows filled, the new token's included): (FLOPs,
+    bytes). Reads each slot's attended K and V rows once, q in and the
+    output out. ``window`` > 0 (a sliding-window layer) attends only the
+    last ``window`` rows."""
+    rows = sum(min(c, window) for c in ctx) if window else sum(ctx)
     flops = 4.0 * s.h * s.dk * rows                   # q.k and p.v
     nbytes = (2.0 * rows * s.hkv * s.dk * kv_bytes
               + 2.0 * len(ctx) * s.h * s.dk * act_bytes)
@@ -47,40 +53,28 @@ def decode_kernel(ctx: list[int], s, kv_bytes: int = 2,
 
 
 def prefill_kernel(start: int, n: int, s, kv_bytes: int = 2,
-                   act_bytes: int = 2) -> tuple[float, float]:
-    """One layer's chunked prefill attention of ``n`` real tokens at rows
-    [start, start + n), each attending causally to the rows up to its own:
-    (FLOPs, bytes). K and V of the start + n rows are read once."""
-    pairs = n * start + n * (n + 1) / 2                # (query, key) pairs
+                   act_bytes: int = 2, window: int = 0) -> tuple[float, float]:
+    """One call of the chunked prefill attention of ``n`` real tokens at
+    rows [start, start + n), each attending causally to the rows up to its
+    own: (FLOPs, bytes). K and V of the rows attended are read once.
+    ``window`` > 0 (a sliding-window layer): the row at position r attends
+    only rows r - window < k <= r."""
+    if window:
+        pairs = _sum_min(start + 1, start + n, window)
+        rows = start + n - max(0, start - window + 1)
+    else:
+        pairs = n * start + n * (n + 1) / 2            # (query, key) pairs
+        rows = start + n
     flops = 4.0 * s.h * s.dk * pairs
-    nbytes = (2.0 * (start + n) * s.hkv * s.dk * kv_bytes
+    nbytes = (2.0 * rows * s.hkv * s.dk * kv_bytes
               + 2.0 * n * s.h * s.dk * act_bytes)
     return flops, nbytes
 
 
-# ------------------------------------------------------------ model steps ----
-def linear_flops_per_token(s) -> float:
-    """q, k, v, o and the gated MLP of every layer, per token."""
-    per_layer = (s.d * s.h * s.dk + 2 * s.d * s.hkv * s.dk
-                 + s.h * s.dk * s.d + 3 * s.d * s.ff)
-    return 2.0 * per_layer * s.layers
-
-
-def unembed_flops(s) -> float:
-    return 2.0 * s.d * s.vocab
-
-
-def decode_step_flops(ctx: list[int], s) -> float:
-    """One decode step: every active slot's token through the model, its
-    attention over its own context, and its logits row."""
-    attn = s.layers * decode_kernel(ctx, s)[0]
-    return len(ctx) * (linear_flops_per_token(s) + unembed_flops(s)) + attn
-
-
-def prefill_step_flops(start: int, n: int, final: bool, s) -> float:
-    """One prefill chunk: ``n`` real tokens through the model, their causal
-    attention, and a logits row only where the prompt ends (the row that
-    seeds the first token)."""
-    attn = s.layers * prefill_kernel(start, n, s)[0]
-    return (n * linear_flops_per_token(s) + attn
-            + (unembed_flops(s) if final else 0.0))
+def _sum_min(a: int, b: int, w: int) -> float:
+    """sum of min(x, w) for x = a .. b."""
+    if b <= w:
+        return (a + b) * (b - a + 1) / 2
+    if a >= w:
+        return float(w * (b - a + 1))
+    return (a + w) * (w - a + 1) / 2 + w * (b - w)

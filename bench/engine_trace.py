@@ -11,15 +11,17 @@ What the program records (``repro.serve.engine``, ``repro.models``):
   ``engine.table_upload``, ``engine.decode_drain``), ``engine.record``,
   ``engine.finish`` (``uid``, ``slot``); and ``gc`` around every Python
   collection;
-* ``engine.counters`` and ``engine.request_times`` (perf_counter stamps);
+* ``engine.counters`` and ``engine.request_times`` (perf_counter stamps),
+  which ``run.py``'s traced run keeps around its window;
 * ``jax.named_scope`` names in each HLO op's ``op_name`` metadata:
   ``embed``, ``layers``, ``attn_proj``, ``kv_cache``, ``attn``, ``mlp``,
   ``unembed_sample``.
 
 A device op's ``op_name`` is read from the trace event's stats where one
 holds it (``OP_NAME_STATS``), else from the compiled program's HLO text
-(``hlo_op_names``). An op that carries none is ``unscoped`` (XLA's own
-copies); one whose ``op_name`` names no scope is ``other``.
+(``hlo_op_names``, ``fill_op_names``). An op that carries none is
+``unscoped`` (XLA's own copies); one whose ``op_name`` names no scope is
+``other``.
 
 ``trace_reduce`` is used as it is: this module adds the engine's events
 beside what it reads, and the reductions below.
@@ -112,6 +114,21 @@ def read(path: str, span_names=()) -> dict:
                                         if not k.startswith("_")}))
     ev["engine"] = engine
     return ev
+
+
+def fill_op_names(ev: dict, hlo: dict):
+    """Fill each device's ``op_names`` from the compiled steps' HLO text
+    (``hlo``: step -> text) where the trace's stats hold none; where they
+    were read goes to ``ev["op_name_source"]``."""
+    source = "trace_stats"
+    for dev in ev["devices"].values():
+        if any(dev["op_names"].values()):
+            continue
+        source = "hlo_text"
+        for text in hlo.values():
+            mod = re.search(r"HloModule (\w+)", text).group(1)
+            dev["op_names"][mod] = hlo_op_names(text)
+    ev["op_name_source"] = source
 
 
 # ------------------------------------------------------------ reductions ----

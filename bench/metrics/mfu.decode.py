@@ -1,5 +1,5 @@
-"""Whole decode step: model FLOPs of the active slots' tokens
-(``costs.decode_step_flops``) over the traced device time of the decode
+"""Whole decode step: model FLOPs of the active slots' tokens (the model
+module's ``decode_step_flops``) over the traced device time of the decode
 program at the chip's bf16 peak."""
 
 

@@ -1,6 +1,6 @@
 """Whole prefill step: model FLOPs of the real prompt tokens of each chunk
-(``costs.prefill_step_flops``) over the traced device time of the prefill
-program at the chip's bf16 peak."""
+(the model module's ``prefill_step_flops``) over the traced device time of
+the prefill program at the chip's bf16 peak."""
 
 
 def read(run):

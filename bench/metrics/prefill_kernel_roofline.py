@@ -1,9 +1,11 @@
 """Kernels: the paged ConSmax prefill kernel's share of its roofline.
 
-Least time per chunk from ``costs.prefill_kernel`` (filled rows only) over
-the traced device time of the kernel, per program run; one kernel call per
-layer."""
+Least time per chunk, summed over the chunk's kernel calls (the model
+module's ``prefill_kernel_calls``: filled rows only), over the traced
+device time of the ``consmax_prefill_paged_op`` ops, per program run."""
 
 
 def read(run):
-    return run.kernel_roofline("jit_prefill_chunk_step", run.prefill_costs())
+    return run.kernel_roofline("jit_prefill_chunk_step",
+                               "consmax_prefill_paged_op",
+                               run.prefill_calls())

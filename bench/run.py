@@ -9,11 +9,13 @@ every program the window drives compiled (persistent compilation cache in
 the checkout), then the cell's own warm traffic: a closed loop's first wave
 prefilled, or an open loop's warm phase. The window then runs ``--seconds``
 of host clock. ``--trace 0`` reports the cell's end-to-end metrics;
-``--trace 1`` traces the window on the device and reports its per-layer
-metrics. After the window the engine's state is freed and the served tokens
-are compared with the float32 reference (``check.py``); each number
-compared is printed beside its limit, last on stderr and last in the
-result line.
+``--trace 1`` traces the window on the device, keeps the engine's own
+telemetry around it (its counters read on both sides of the window, its
+requests' times, the HLO text of the steps set-up compiled), and reports
+the per-layer metrics. After the window the engine's state is freed and the
+served tokens are compared with the configuration's float32 reference (its
+model module's ``logit_gaps``, by ``check.py``); each number compared is
+printed beside its limit, last on stderr and last in the result line.
 
 A run that finds no TPU, or fewer chips than the cell asks for, exits
 non-zero without a result.
@@ -25,6 +27,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse       # noqa: E402
+import functools      # noqa: E402
 import gc             # noqa: E402
 import json           # noqa: E402
 import os             # noqa: E402
@@ -37,13 +40,12 @@ import jax            # noqa: E402
 
 import check          # noqa: E402
 import costs          # noqa: E402
+import engine_trace   # noqa: E402
 import loadgen        # noqa: E402
 import readers        # noqa: E402
-import reference      # noqa: E402
 import serve_loop     # noqa: E402
 import spec           # noqa: E402
 import trace_reduce   # noqa: E402
-import weights        # noqa: E402
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -101,16 +103,19 @@ class CompileCounter:
             self.names.append(kw.get("fun_name", "?"))
 
 
-def build(cell: spec.Cell, seed: int, engine_hook=None):
-    """Weights from the seed and a warmed engine (set-up, less traffic)."""
+def build(cell: spec.Cell, seed: int, engine_hook=None,
+          hlo: dict | None = None):
+    """Weights from the seed and a warmed engine (set-up, less traffic);
+    ``hlo``, where given, receives each compiled step's HLO text."""
     t0 = time.perf_counter()
-    params = weights.make_params(cell.config, seed_word(seed))
+    params = cell.model.make_params(cell.config, seed_word(seed))
     jax.block_until_ready(params)
     log(f"setup: weights {time.perf_counter() - t0:.3f} s")
-    engine = serve_loop.build_engine(cell.config, params)
+    engine = serve_loop.build_engine(cell.config, cell.model.PUBLISHED,
+                                     params)
     if engine_hook is not None:
         engine_hook(engine)
-    compile_s = serve_loop.warm_engine(engine)
+    compile_s = serve_loop.warm_engine(engine, hlo)
     log("setup: compile " + ", ".join(f"{k} {v:.3f} s"
                                       for k, v in compile_s.items()))
     return params, engine
@@ -119,11 +124,12 @@ def build(cell: spec.Cell, seed: int, engine_hook=None):
 def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
              require_tpu: bool = True, keep_trace: str | None = None,
              t_start: float | None = None, engine_hook=None,
-             after_check=None) -> dict:
+             after_check=None, on_trace=None) -> dict:
     """One run; returns the result line as a dict. ``engine_hook(engine)``
-    (tests only) may break the timed path underneath; ``after_check(params,
-    sizes, finished, req_of)`` (the control's script) reads the same sample
-    after the check."""
+    (tests only) may break the timed path underneath; ``after_check(gaps,
+    finished, req_of, vocab)`` (the control's script) reads the same sample
+    after the check; ``on_trace(run, ev)`` (``engine_report.py``) gets a
+    traced run's ``EngineRunData`` and trace events."""
     t_start = T_START if t_start is None else t_start
     devs = check_device(cell.chips) if require_tpu else jax.devices()
     dev = devs[0]
@@ -135,7 +141,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
 
     config, mix = cell.config, cell.traffic
     counter = CompileCounter()
-    params, engine = build(cell, seed, engine_hook)
+    hlo = {} if trace else None
+    params, engine = build(cell, seed, engine_hook, hlo)
     runner = serve_loop.Runner(engine, mix, cell.cell, seed)
     runner.warm(float(mix.get("warm_seconds", 0)))
     # what set-up made lives to the end of the run: keep the collector's
@@ -152,11 +159,14 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         opts.python_tracer_level = 0
         opts.enable_hlo_proto = False
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        before = dict(engine.counters)
     counter.on = True
     runner.window(seconds)
     counter.on = False
     if trace:
         jax.profiler.stop_trace()
+        counters = {k: v - before[k] for k, v in engine.counters.items()}
+        times = {u: dict(t) for u, t in engine.request_times.items()}
     stats = serve_loop.window_stats(runner)
     sizes_ok = (engine.prefill_cache_size, engine.decode_cache_size)
     if sizes_ok != (1, 1):
@@ -183,20 +193,26 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
 
     summary = {}
     if trace:
-        xplane = trace_reduce.find_xplane(trace_dir)
-        summary = trace_reduce.summarize(trace_reduce.read(
-            xplane, serve_loop.SPAN_NAMES))
+        t0 = time.perf_counter()
+        ev = engine_trace.read(trace_reduce.find_xplane(trace_dir),
+                               serve_loop.SPAN_NAMES)
+        engine_trace.fill_op_names(ev, hlo)
+        summary = trace_reduce.summarize(ev)
+        eng = engine_trace.summarize(ev, ev["spans"])
+        log(f"trace: read in {time.perf_counter() - t0:.3f} s, op names "
+            f"from {ev['op_name_source']}")
         if keep_trace:
             shutil.copytree(trace_dir, keep_trace, dirs_exist_ok=True)
         shutil.rmtree(trace_dir, ignore_errors=True)
 
-    sz = reference.Sizes(**weights.sizes(config))
+    sz = cell.model.sizes(config)
+    gaps = functools.partial(cell.model.logit_gaps, params, sz)
     t0 = time.perf_counter()
     correct, numbers, facts = check.compare(
-        params, sz, finished, req_of, seed, vocab, cell.cell["limits"])
+        gaps, finished, req_of, seed, vocab, cell.cell["limits"])
     log(f"check: {facts}, {time.perf_counter() - t0:.3f} s")
     if after_check is not None:
-        after_check(params, sz, finished, req_of)
+        after_check(gaps, finished, req_of, vocab)
 
     metrics = {}
     if not trace:
@@ -209,12 +225,17 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         # a CPU run (the tests) has no device trace, so no reader that
         # needs the chip's peaks finds anything to read
         peaks = costs.peaks(dev.device_kind) if dev.platform == "tpu" else {}
-        run = readers.RunData(stats=stats, rec=rec, trace=summary,
-                              sizes=sz, peaks=peaks, max_slots=max_slots)
+        run = engine_trace.EngineRunData(
+            stats=stats, rec=rec, trace=summary, sizes=sz, peaks=peaks,
+            max_slots=max_slots, model=cell.model, counters=counters,
+            request_times=times, window=(rec.t_w0, max(rec.t_end, rec.t_w1)),
+            engine=eng)
         for m in cell.per_layer:
-            v = readers.load(m["name"])(run)
+            v = readers.load(m["name"], cell.bench)(run)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+        if on_trace is not None:
+            on_trace(run, ev)
     for k in ("ttft_p50_ms", "ttft_p95_ms", "ttft_mean_ms", "itl_p50_ms",
               "itl_p95_ms", "itl_p99_ms", "itl_mean_ms", "itl_max_ms",
               "output_tok_s", "requests_due", "requests_first_token",

@@ -27,39 +27,46 @@ SPAN_NAMES = ("engine_step", "submit", "arrival_wait", "harness")
 
 
 # ----------------------------------------------------------------- build ----
-def model_config(config: dict):
+def model_config(config: dict, published: dict):
+    """The program's config: its arch with the file's overrides (a JSON
+    list is a tuple, such as a ``block_pattern``), checked against the
+    published sizes field by field (``published``: program field ->
+    config key, the model module's ``PUBLISHED``)."""
     from repro.configs.registry import get_config
+
+    def tup(v):
+        return tuple(v) if isinstance(v, list) else v
+
     prog = config["program"]
-    cfg = get_config(prog["arch"], **prog.get("overrides", {}))
-    published = {"n_layers": "num_hidden_layers", "d_model": "hidden_size",
-                 "n_heads": "num_attention_heads",
-                 "n_kv_heads": "num_key_value_heads",
-                 "d_ff": "intermediate_size", "vocab_size": "vocab_size",
-                 "rope_theta": "rope_theta"}
+    cfg = get_config(prog["arch"], **{
+        k: tup(v) for k, v in prog.get("overrides", {}).items()})
     for field, key in published.items():
-        if getattr(cfg, field) != config[key]:
+        if getattr(cfg, field) != tup(config[key]):
             raise SystemExit(f"{config['name']}: the program's {field} "
                              f"{getattr(cfg, field)} is not the published "
                              f"{key} {config[key]}")
     return cfg
 
 
-def build_engine(config: dict, params):
+def build_engine(config: dict, published: dict, params):
     from repro.configs.base import ServeConfig
     from repro.serve.engine import ContinuousBatchingEngine
-    cfg = model_config(config)
+    cfg = model_config(config, published)
     scfg = ServeConfig(score_norm=cfg.score_norm,
                        max_slots=config["max_slots"], **config["engine"])
     return ContinuousBatchingEngine(cfg, scfg, params)
 
 
-def warm_engine(engine) -> dict:
-    """Compile every program the window drives; seconds per step."""
+def warm_engine(engine, hlo: dict | None = None) -> dict:
+    """Compile every program the window drives; seconds per step. Where
+    ``hlo`` is given, each step's optimized HLO text is kept in it."""
     import jax.numpy as jnp
     secs = {}
     for name in ("prefill", "decode"):
         t0 = time.perf_counter()
-        engine.compile_step(name)
+        compiled = engine.compile_step(name)
+        if hlo is not None:
+            hlo[name] = compiled.as_text()
         secs[name] = time.perf_counter() - t0
     t0 = time.perf_counter()
     zero = jnp.asarray(0, jnp.int32)

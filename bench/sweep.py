@@ -43,7 +43,8 @@ def main(argv=None):
     warm = float(cell.traffic["warm_seconds"])
     for rate in args.rates:
         if engine is None:
-            engine = serve_loop.build_engine(cell.config, params)
+            engine = serve_loop.build_engine(
+                cell.config, cell.model.PUBLISHED, params)
             serve_loop.warm_engine(engine)
         r = serve_loop.Runner(engine, cell.traffic, cell.cell, args.seed,
                               rate_per_s=rate)

@@ -7,15 +7,15 @@ the control's at this size (bench/tests, 4 seeds x 2 mixes): program
 max_logit_gap <= 0.030 and mean_logit_gap <= 0.00048; the float8 control
 max_logit_gap >= 0.30 and mean_logit_gap >= 0.0125.
 """
+import functools
+
 import jax
 import pytest
 
 import check
 import loadgen
-import reference
 import serve_loop
 import tiny
-import weights
 
 LIMITS = (0.12, 0.003)
 CELLS = ["qwen2-1.5b.decode-long", "granite-3-2b.chat-bursty"]
@@ -75,13 +75,14 @@ def test_control_is_not_correct(name):
         r.warm(float(c.traffic.get("warm_seconds", 0)))
         r.window(3.0)
         fin = {u: list(t) for u, t in eng.results.items()}
-        s = reference.Sizes(**weights.sizes(c.config))
+        gaps = functools.partial(c.model.logit_gaps, params,
+                                 c.model.sizes(c.config))
         v = eng.cfg.vocab_size
-        ok, numbers, _ = check.compare(params, s, fin, r.req_of, seed, v,
+        ok, numbers, _ = check.compare(gaps, fin, r.req_of, seed, v,
                                        c.cell["limits"])
         assert ok is True, numbers
-        ctl = check.control_tokens(params, s, fin, r.req_of, seed, v)
-        ok, numbers, _ = check.compare(params, s, ctl, r.req_of, seed, v,
+        ctl = check.control_tokens(gaps, fin, r.req_of, seed, v)
+        ok, numbers, _ = check.compare(gaps, ctl, r.req_of, seed, v,
                                        c.cell["limits"])
         assert ok is False, numbers
         eng.caches = None

@@ -11,6 +11,8 @@ import gzip
 import json
 from pathlib import Path
 
+import pytest
+
 import engine_report
 import engine_trace as E
 import readers
@@ -141,3 +143,18 @@ def test_report_reads_the_engine_around_the_harness_run():
     # no device plane on the CPU: nothing for the trace's readers
     assert m["kv_cache_ms.chat"] is None and m["engine_host_ms.chat"] is None
     assert set(out["span_cost_us"]) == {"off", "on"}
+
+
+@pytest.mark.parametrize("name,metric", [
+    ("qwen2-1.5b.decode-long", "decode_active_share"),
+    ("granite-3-2b.chat-bursty", "admit_wait_p50_ms")])
+def test_traced_run_reports_the_engine_metrics(name, metric):
+    """``run.py``'s own traced run keeps the engine's counters and request
+    times around its window: the cell's engine metrics that need no device
+    trace read on the CPU too; those of the trace give None there and are
+    left out of the line."""
+    r = tiny.run(tiny.cell(name, (0.12, 0.003)), 2147483951, trace=True)
+    assert r["correct"], r["check"]
+    assert r["metrics"][metric]["value"] >= 0
+    assert not {"engine_host_ms.chat", "engine_host_ms.decode",
+                "kv_cache_ms.chat", "kv_cache_ms.decode"} & set(r["metrics"])

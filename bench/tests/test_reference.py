@@ -1,10 +1,9 @@
 """The reference reads the params pytree the way the program does.
 
 At smoke size on the CPU, the program's full forward (``lm_apply`` with no
-cache, float32 compute) and the reference agree on every logit, on weights
-that ``weights.py`` made, for both configurations' layouts (qkv bias and
-rope theta included)."""
-import copy
+cache, float32 compute) and the dense model module's reference agree on
+every logit, on weights that the module made, for both configurations'
+layouts (qkv bias and rope theta included)."""
 import json
 
 import jax
@@ -14,17 +13,15 @@ import pytest
 
 import reference
 import spec
-import weights
+import tiny
+
+DENSE = tiny.model("dense")
 
 
-def tiny(name):
+def small(name):
     cfg = json.loads((spec.BENCH / "configs" / f"{name}.json").read_text())
-    cfg = copy.deepcopy(cfg)
-    cfg.update(hidden_size=64, intermediate_size=96, num_hidden_layers=2,
-               num_attention_heads=4, num_key_value_heads=2, vocab_size=300)
-    cfg["program"]["overrides"].update(
-        n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=96,
-        vocab_size=300, head_dim=0, compute_dtype="float32")
+    cfg = DENSE.tiny(cfg)
+    cfg["program"]["overrides"]["compute_dtype"] = "float32"
     return cfg
 
 
@@ -32,18 +29,18 @@ def tiny(name):
 def test_reference_matches_lm_apply(name):
     import serve_loop
     from repro.models import transformer as T
-    cfg = tiny(name)
-    params = weights.make_params(cfg, 123)
-    mcfg = serve_loop.model_config(cfg)
+    cfg = small(name)
+    params = DENSE.make_params(cfg, 123)
+    mcfg = serve_loop.model_config(cfg, DENSE.PUBLISHED)
     shapes = jax.tree.map(lambda a: (a.shape, a.dtype), params)
     want = jax.tree.map(lambda a: (a.shape, a.dtype), T.lm_abstract(mcfg))
     assert shapes == want
+    s = DENSE.sizes(cfg)
     t = 512
-    toks = np.random.default_rng(0).integers(0, 300, size=t)
+    toks = np.random.default_rng(0).integers(0, s.vocab, size=t)
     prog, _, _ = T.lm_apply(params, mcfg, tokens=jnp.asarray(toks)[None],
                             merged=True)
-    s = reference.Sizes(**weights.sizes(cfg))
-    h = reference.hidden(params, s, jnp.asarray(toks))
+    h = DENSE.hidden(params, s, jnp.asarray(toks))
     ref = jnp.einsum("td,vd->tv", h,
                      params["embed"]["table"].astype(jnp.float32),
                      precision=reference.HI)
@@ -53,21 +50,21 @@ def test_reference_matches_lm_apply(name):
 
 
 def test_gaps_read_served_tokens():
-    cfg = tiny("qwen2-1.5b")
-    params = weights.make_params(cfg, 7)
-    s = reference.Sizes(**weights.sizes(cfg))
+    cfg = small("qwen2-1.5b")
+    params = DENSE.make_params(cfg, 7)
+    s = DENSE.sizes(cfg)
     prompt = list(range(40))
     # greedy continuation under the reference itself: every gap is 0
     served = []
     for _ in range(5):
         seq = np.asarray(prompt + served)
-        h = reference.hidden(params, s, jnp.asarray(
+        h = DENSE.hidden(params, s, jnp.asarray(
             np.pad(seq, (0, 512 - len(seq)))))
         lg = h[len(seq) - 1] @ params["embed"]["table"].astype(
             jnp.float32).T
         served.append(int(jnp.argmax(lg)))
-    gap, top = reference.logit_gaps(params, s, prompt, served)
+    gap, top = DENSE.logit_gaps(params, s, prompt, served)
     assert np.all(gap < 1e-5) and list(top) == served
-    wrong = [(x + 1) % 300 for x in served]
-    gap, _ = reference.logit_gaps(params, s, prompt, wrong)
+    wrong = [(x + 1) % s.vocab for x in served]
+    gap, _ = DENSE.logit_gaps(params, s, prompt, wrong)
     assert np.all(gap > 0)

@@ -1,6 +1,6 @@
 """A cell at smoke size for the CPU tests: the real configuration and mix
-files with every width shrunk, the kernels in interpret mode."""
-import copy
+files, every width shrunk by the configuration's model module
+(``tiny``), the engine's shape shrunk here, the kernels in interpret mode."""
 import time
 
 import spec
@@ -8,12 +8,7 @@ import spec
 
 def cell(name: str, limit: float) -> spec.Cell:
     c = spec.load_cell(name)
-    cfg = copy.deepcopy(c.config)
-    cfg.update(hidden_size=128, intermediate_size=256, num_hidden_layers=2,
-               num_attention_heads=4, num_key_value_heads=2, vocab_size=512)
-    cfg["program"]["overrides"].update(
-        n_layers=2, d_model=128, n_heads=4, n_kv_heads=2, d_ff=256,
-        vocab_size=512, head_dim=0)
+    cfg = c.model.tiny(c.config)
     cfg["max_slots"] = 4
     cfg["engine"].update(max_seq=256, page_size=16, prefill_chunk=32)
     c.config = cfg
@@ -27,7 +22,13 @@ def cell(name: str, limit: float) -> spec.Cell:
     return c
 
 
-def run(c: spec.Cell, seed: int, seconds: float = 3.0, hook=None) -> dict:
+def run(c: spec.Cell, seed: int, seconds: float = 3.0, hook=None,
+        trace: bool = False) -> dict:
     import run as bench_run
-    return bench_run.run_cell(c, seed, seconds, False, require_tpu=False,
+    return bench_run.run_cell(c, seed, seconds, trace, require_tpu=False,
                               t_start=time.perf_counter(), engine_hook=hook)
+
+
+def model(name: str):
+    """A model module of the benchmark, by name."""
+    return spec.load_module(spec.BENCH / "models" / f"{name}.py")

@@ -1,14 +1,17 @@
 """Random weights from the seed, made on the device in one jitted call.
 
-The pytree has the layout the program's decoder reads (``embed``, a
-``blocks`` stack with a leading layer axis, ``final_norm``), built here from
-the configuration's sizes alone, so the reference and the program read the
-same arrays and neither made them. Matrices are bfloat16 (both published
-checkpoints store bfloat16); ConSmax's per-head beta and gamma stay float32.
+A model module (``bench/models/<name>.py``) gives the pytree's layout as a
+tree of shapes, the layout the program reads, built from the configuration's
+sizes alone, so the reference and the program read the same arrays and
+neither made them. Each leaf is drawn from its own key, the seed's key
+folded with the crc32 of the leaf's path. Matrices are bfloat16 (the
+published checkpoints store bfloat16); ConSmax's per-head beta and gamma
+stay float32.
 
-Distributions: embedding N(0, 1/d) (tied logits of order 1), projections
-N(0, 1/fan_in), biases N(0, 0.05^2), RMSNorm gains 1 + N(0, 0.1^2) stored
-zero-centred, beta ~ U[0.5, 2.5] and gamma = 100 (the paper's init).
+Distributions: an embedding ``table`` N(0, 1/d) (tied logits of order 1),
+projections N(0, 1/fan_in), biases N(0, 0.05^2), RMSNorm gains 1 + N(0,
+0.1^2) stored zero-centred, beta ~ U[0.5, 2.5] and gamma = 100 (the paper's
+init).
 """
 from __future__ import annotations
 
@@ -16,36 +19,6 @@ import zlib
 
 import jax
 import jax.numpy as jnp
-
-
-def sizes(config: dict) -> dict:
-    d = config["hidden_size"]
-    h = config["num_attention_heads"]
-    return dict(d=d, h=h, hkv=config["num_key_value_heads"], dk=d // h,
-                ff=config["intermediate_size"], vocab=config["vocab_size"],
-                layers=config["num_hidden_layers"],
-                qkv_bias=bool(config.get("qkv_bias",
-                                         config.get("attention_bias", False))),
-                theta=float(config["rope_theta"]),
-                eps=float(config["rms_norm_eps"]))
-
-
-def _shapes(s: dict) -> dict:
-    n, d, h, hkv, dk, ff = (s["layers"], s["d"], s["h"], s["hkv"], s["dk"],
-                            s["ff"])
-    attn = {"q": {"w": (n, d, h, dk)}, "k": {"w": (n, d, hkv, dk)},
-            "v": {"w": (n, d, hkv, dk)}, "o": {"w": (n, h, dk, d)},
-            "score_norm": {"beta": (n, h), "gamma": (n, h)}}
-    if s["qkv_bias"]:
-        attn["q"]["b"] = (n, h, dk)
-        attn["k"]["b"] = (n, hkv, dk)
-        attn["v"]["b"] = (n, hkv, dk)
-    block = {"attn_norm": {"scale": (n, d)}, "attn": attn,
-             "mlp_norm": {"scale": (n, d)},
-             "mlp": {"gate": {"w": (n, d, ff)}, "up": {"w": (n, d, ff)},
-                     "down": {"w": (n, ff, d)}}}
-    return {"embed": {"table": (s["vocab"], d)}, "blocks": {"b0": block},
-            "final_norm": {"scale": (d,)}}
 
 
 def _leaf(key, path: str, shape: tuple):
@@ -76,11 +49,10 @@ def _build(key, shapes, prefix=""):
             for k, v in shapes.items()}
 
 
-def make_params(config: dict, seed_word: int):
-    """The whole pytree from one key, in one jitted call on the default
-    device. The key is the hardware generator's (``rbg``): the same seed
-    gives the same weights on the same backend, without threefry's cost
-    over billions of elements."""
-    shapes = _shapes(sizes(config))
+def make(shapes: dict, seed_word: int):
+    """The whole pytree of ``shapes`` from one key, in one jitted call on
+    the default device. The key is the hardware generator's (``rbg``): the
+    same seed gives the same weights on the same backend, without
+    threefry's cost over billions of elements."""
     fn = jax.jit(lambda k: _build(k, shapes))
     return fn(jax.random.key(seed_word, impl="rbg"))
