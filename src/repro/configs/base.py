@@ -29,10 +29,31 @@ class MoEConfig:
     n_experts: int = 8
     top_k: int = 2
     d_ff_expert: int = 0             # expert hidden size
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # 0 = dropless: every token reaches
+                                     # all of its top-k experts (grouped
+                                     # matmuls over the sorted rows)
     layer_period: int = 1            # MoE every k-th layer (jamba: 2)
     aux_loss_weight: float = 0.01
     router_norm: str = "softmax"     # "softmax" | "consmax" (extension)
+
+    @property
+    def dropless(self) -> bool:
+        """Every token reaches all of its top-k experts (``capacity_factor``
+        0); the one test of that setting, for every reader."""
+        return self.capacity_factor == 0
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (transformers' ``_compute_yarn_parameters``,
+    ``truncate`` true): frequencies below the correction range are
+    interpolated by ``factor``, those above it kept, a linear ramp between;
+    cos and sin are scaled by ``attention_factor``."""
+    factor: float = 16.0
+    original_max_position_embeddings: int = 8192
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -71,6 +92,8 @@ class ModelConfig:
     rope_style: str = "half"         # "half" | "interleaved" (glm 2d) | "none"
     rope_fraction: float = 1.0       # chatglm: 0.5
     rope_theta: float = 10000.0
+    rope_yarn: Optional[YarnConfig] = None  # YaRN on full-attention layers
+                                     # only; "local" kinds keep plain RoPE
     attn_softcap: float = 0.0        # gemma2: 50.0 ; grok: 30.0 ; 0 = off
     final_softcap: float = 0.0       # gemma2: 30.0
     window: int = 0                  # sliding-window size for "local" layers

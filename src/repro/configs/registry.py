@@ -10,11 +10,12 @@ consmax-style stabilizer extension behind cfg.xlstm.stabilizer.
 from __future__ import annotations
 
 from repro.configs.base import (ConSmaxConfig, MambaConfig, ModelConfig,
-                                MoEConfig, XLSTMConfig)
+                                MoEConfig, XLSTMConfig, YarnConfig)
 
 _JAMBA_PATTERN = ("mamba", "mamba_moe", "mamba", "mamba_moe",
                   "attn", "mamba_moe", "mamba", "mamba_moe")
 _XLSTM_PATTERN = ("mlstm",) * 7 + ("slstm",)
+_MELLUM2_PATTERN = ("local_moe",) * 3 + ("global_moe",)
 
 
 def _full():
@@ -57,6 +58,21 @@ def _full():
             mlp="gelu_glu", attn_softcap=30.0, final_softcap=30.0,
             embed_scale=True, block_pattern=("attn_moe",),
             moe=MoEConfig(n_experts=8, top_k=2, d_ff_expert=32768)),
+        # [moe] 28L 2304 32H kv4 head_dim 128 v98304 untied; every MLP a
+        # dropless 64-expert top-8 layer of ff 896 (d_ff), no shared expert;
+        # 3 sliding (w=1024) : 1 full layers, rope theta 5e5, YaRN x16 on
+        # full layers only [hf JetBrains/Mellum2-12B-A2.5B-Instruct]
+        "mellum2-12b-a2.5b": ModelConfig(
+            arch_id="mellum2-12b-a2.5b", family="moe", n_layers=28,
+            d_model=2304, n_heads=32, n_kv_heads=4, d_ff=896,
+            vocab_size=98304, head_dim=128, rope_theta=500000.0,
+            rope_yarn=YarnConfig(
+                factor=16.0, original_max_position_embeddings=8192,
+                beta_fast=32.0, beta_slow=1.0,
+                attention_factor=1.2772588722239782),
+            window=1024, block_pattern=_MELLUM2_PATTERN,
+            tie_embeddings=False,
+            moe=MoEConfig(n_experts=64, top_k=8, capacity_factor=0.0)),
         # [vlm] 32L 3072 32H kv32 ff8192 v32064 — phi3-mini backbone + CLIP
         # frontend (stub: precomputed patch embeddings)
         "phi-3-vision-4.2b": ModelConfig(
@@ -111,6 +127,7 @@ def _smoke(full: ModelConfig) -> ModelConfig:
     if full.family in ("moe", "hybrid"):
         kw["moe"] = MoEConfig(
             n_experts=4, top_k=2, d_ff_expert=256,
+            capacity_factor=full.moe.capacity_factor,
             layer_period=full.moe.layer_period,
             router_norm=full.moe.router_norm)
     if full.mamba is not None:

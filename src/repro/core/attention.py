@@ -501,6 +501,7 @@ def attention_apply(p, x, cfg: ModelConfig, *, kind: str = "global",
     cdt = cfg.cdtype()
     cross = cond is not None
     window = cfg.window if kind == "local" else 0
+    yarn = None if kind == "local" else cfg.rope_yarn
 
     # named scopes: ``attn_proj`` (projections, rope), ``kv_cache`` (every
     # write of new K/V into the cache or pool, and conversions of the
@@ -526,7 +527,7 @@ def attention_apply(p, x, cfg: ModelConfig, *, kind: str = "global",
         with jax.named_scope("attn_proj"):
             return tuple(R.apply_rope(a, pos, rotary_dim=rot,
                                       theta=cfg.rope_theta,
-                                      interleaved=interleaved)
+                                      interleaved=interleaved, yarn=yarn)
                          for a in (q, k))
 
     if cache is not None and page_table is not None and not cross:

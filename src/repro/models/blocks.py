@@ -2,7 +2,7 @@
 sandwich post-norms). Block kinds:
 
   attn / global / local  : attention + dense MLP
-  attn_moe               : attention + MoE
+  attn_moe / global_moe / local_moe : attention of that kind + MoE
   mamba / mamba_moe      : Mamba SSM block (+ MoE instead of the implicit MLP)
   mlstm / slstm          : xLSTM cells (self-contained, no separate MLP)
   any kind with cfg.cross_attn: adds a cross-attention sub-block (musicgen)
@@ -22,13 +22,18 @@ from repro.models import xlstm as XL
 from repro.nn import layers as L
 
 
-def _moe_apply(p, h, cfg):
+ATTN_KINDS = ("attn", "attn_moe", "global", "local", "global_moe",
+              "local_moe")
+ATTN_MOE_KINDS = ("attn_moe", "global_moe", "local_moe")
+
+
+def _moe_apply(p, h, cfg, **kw):
     """Dispatch to explicit expert-parallel all-to-all MoE when the sharding
     context requests it (and the expert count divides the axis)."""
     mesh, axis, n = ep_info()
     if mesh is not None and n and cfg.moe.n_experts % n == 0:
         return MOE_EP.moe_apply_ep(p, h, cfg, mesh, axis)
-    return MOE.moe_apply(p, h, cfg)
+    return MOE.moe_apply(p, h, cfg, **kw)
 
 
 # -------------------------------------------------------------------- mlp ----
@@ -67,7 +72,7 @@ def mlp_apply(p, x, cfg: ModelConfig):
 
 # ------------------------------------------------------------------ block ----
 def _is_attn(kind):
-    return kind in ("attn", "attn_moe", "global", "local")
+    return kind in ATTN_KINDS
 
 
 def block_init(ctx, name, cfg: ModelConfig, kind: str):
@@ -88,7 +93,7 @@ def block_init(ctx, name, cfg: ModelConfig, kind: str):
                 p["xattn"] = ATT.attention_init(ctx, "xattn", cfg, cross=True)
             p["mlp_norm"] = L.norm_init(ctx, "mlp_norm", d, kind=cfg.norm,
                                         dtype=pdt)
-            if kind == "attn_moe":
+            if kind in ATTN_MOE_KINDS:
                 p["moe"] = MOE.moe_init(ctx, "moe", cfg)
             else:
                 p["mlp"] = mlp_init(ctx, "mlp", cfg)
@@ -124,13 +129,17 @@ def block_apply(p, x, cfg: ModelConfig, kind: str, *, positions=None,
                 kv_chunk=1024, decode_kernel=False, decode_kv_block=256,
                 prefill_kernel=False, prefill_kv_block=512, fill_bound=True,
                 prefill_append=None, decode_active=None, page_table=None,
-                psum_axes=()):
-    """Returns (x, new_cache, aux_losses)."""
+                psum_axes=(), moe_counts=False):
+    """Returns (x, new_cache, aux_losses, counts): ``counts`` is the
+    dropless expert layer's (rows routed, experts hit), (2,) int32, with
+    ``moe_counts`` on an expert layer, else None. An expert layer routes
+    only the real rows of a prefill chunk and the active decode slots."""
     aux = jnp.zeros((), jnp.float32)
+    counts = None
     new_cache = cache
 
     if _is_attn(kind):
-        akind = kind if kind in ("local", "global") else "global"
+        akind = "local" if kind in ("local", "local_moe") else "global"
         with jax.named_scope("attn_proj"):
             h = L.norm_apply(p["attn_norm"], x, kind=cfg.norm)
         attn_cache = cache.get("attn") if cache is not None else None
@@ -156,8 +165,17 @@ def block_apply(p, x, cfg: ModelConfig, kind: str, *, positions=None,
             x = x + h
         with jax.named_scope("mlp"):
             h = L.norm_apply(p["mlp_norm"], x, kind=cfg.norm)
-            if kind == "attn_moe":
-                h, aux = _moe_apply(p["moe"], h, cfg)
+            if kind in ATTN_MOE_KINDS:
+                rows = None
+                if prefill_append is not None:
+                    rows = (jnp.arange(x.shape[1])[None, :]
+                            < prefill_append[:, None])
+                elif decode_active is not None:
+                    rows = decode_active[:, None]
+                with jax.named_scope("moe"):
+                    h, aux, *c = _moe_apply(p["moe"], h, cfg, row_mask=rows,
+                                            counts=moe_counts)
+                counts = c[0] if c else None
             else:
                 h = mlp_apply(p["mlp"], h, cfg)
             if cfg.post_block_norm:
@@ -172,7 +190,8 @@ def block_apply(p, x, cfg: ModelConfig, kind: str, *, positions=None,
         x = x + h
         if kind == "mamba_moe":
             h = L.norm_apply(p["moe_norm"], x, kind=cfg.norm)
-            h, aux = _moe_apply(p["moe"], h, cfg)
+            with jax.named_scope("moe"):
+                h, aux = _moe_apply(p["moe"], h, cfg)
             x = x + h
         if cache is not None:
             new_cache = dict(cache, mamba=mcache)
@@ -193,4 +212,4 @@ def block_apply(p, x, cfg: ModelConfig, kind: str, *, positions=None,
         if cache is not None:
             new_cache = dict(cache, slstm=sc)
     x = shard(x, "act_batch,act_seq,act_embed")
-    return x, new_cache, aux
+    return x, new_cache, aux, counts

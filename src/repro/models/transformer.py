@@ -33,13 +33,18 @@ def lm_init(ctx: nn.Ctx, cfg: ModelConfig):
     else:
         blocks = nn.vmap_init(_super_init, cfg.n_super_layers,
                               ctx.fold("blocks"), cfg)
-    return {
+    p = {
         "embed": L.embedding_init(ctx, "embed", cfg.vocab_size, cfg.d_model,
                                   dtype=pdt),
         "blocks": blocks,
         "final_norm": L.norm_init(ctx, "final_norm", cfg.d_model,
                                   kind=cfg.norm, dtype=pdt),
     }
+    if not cfg.tie_embeddings:
+        # untied LM head: its own (vocab, d) table, read by ``unembed``
+        p["lm_head"] = L.embedding_init(ctx, "lm_head", cfg.vocab_size,
+                                        cfg.d_model, dtype=pdt)
+    return p
 
 
 def lm_apply(p, cfg: ModelConfig, *, tokens=None, embeds=None, cond=None,
@@ -48,7 +53,7 @@ def lm_apply(p, cfg: ModelConfig, *, tokens=None, embeds=None, cond=None,
              logits_index=None, decode_kernel=False, decode_kv_block=256,
              prefill_kernel=False, prefill_kv_block=512, fill_bound=True,
              prefill_append=None, decode_active=None, page_table=None,
-             logits_epilogue=None, psum_axes=()):
+             logits_epilogue=None, psum_axes=(), moe_counts=False):
     """Forward pass.
 
     tokens: (b, s) int ids (token frontend) | embeds: (b, s, d) stub frontends.
@@ -82,7 +87,11 @@ def lm_apply(p, cfg: ModelConfig, *, tokens=None, embeds=None, cond=None,
     steps into token emitters, so no (b, vocab) array ever crosses to the
     host. ``new_caches`` is passed so the epilogue can read the post-step
     per-slot cache index (its per-slot sample positions).
-    Returns (logits | epilogue out, new_caches, aux_loss).
+    moe_counts: also return the dropless expert layers' counts, (2,) int32
+    summed over layers: rows routed to experts and (layer, expert) pairs
+    with at least one row.
+    Returns (logits | epilogue out, new_caches, aux_loss), and the counts
+    last with ``moe_counts``.
     """
     b, s = (tokens.shape if tokens is not None else embeds.shape[:2])
     if positions is None and caches is None:
@@ -93,9 +102,9 @@ def lm_apply(p, cfg: ModelConfig, *, tokens=None, embeds=None, cond=None,
     # decode: caller passes positions (= cache index) for rope/sinusoidal
 
     # named scopes (``embed``, ``layers``, ``unembed_sample``, and inside
-    # the blocks ``attn_proj``, ``kv_cache``, ``attn``, ``mlp``) live only in
-    # the HLO's op_name metadata, where a device trace reads which part of
-    # the model each op belongs to
+    # the blocks ``attn_proj``, ``kv_cache``, ``attn``, ``mlp``, ``moe``,
+    # ``moe_experts``) live only in the HLO's op_name metadata, where a
+    # device trace reads which part of the model each op belongs to
     with jax.named_scope("embed"):
         x = F.frontend_apply(p, cfg, tokens=tokens, embeds=embeds,
                              positions=positions)
@@ -104,43 +113,47 @@ def lm_apply(p, cfg: ModelConfig, *, tokens=None, embeds=None, cond=None,
     def super_step(x, bp, cache_in):
         new_caches = {} if cache_in is not None else None
         aux = jnp.zeros((), jnp.float32)
+        counts = jnp.zeros((2,), jnp.int32) if moe_counts else None
         for i, kind in enumerate(cfg.block_pattern):
             ci = cache_in[f"b{i}"] if cache_in is not None else None
-            x, co, a = B.block_apply(
+            x, co, a, n = B.block_apply(
                 bp[f"b{i}"], x, cfg, kind, positions=positions, cache=ci,
                 cond=cond, merged=merged, q_chunk=q_chunk, kv_chunk=kv_chunk,
                 decode_kernel=decode_kernel, decode_kv_block=decode_kv_block,
                 prefill_kernel=prefill_kernel,
                 prefill_kv_block=prefill_kv_block, fill_bound=fill_bound,
                 prefill_append=prefill_append, decode_active=decode_active,
-                page_table=page_table, psum_axes=psum_axes)
+                page_table=page_table, psum_axes=psum_axes,
+                moe_counts=moe_counts)
             aux = aux + a
+            if n is not None:
+                counts = counts + n
             if cache_in is not None:
                 new_caches[f"b{i}"] = co
-        return x, new_caches, aux
+        return x, new_caches, (aux, counts)
 
     if caches is None:
         def body(x, bp):
-            y, _, aux = super_step(x, bp, None)
-            return y, aux
+            y, _, stats = super_step(x, bp, None)
+            return y, stats
         if remat == "full":
             body = jax.checkpoint(body)
         elif remat == "dots":
             body = jax.checkpoint(
                 body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
         with jax.named_scope("layers"):
-            x, auxs = jax.lax.scan(body, x, p["blocks"])
+            x, (auxs, counts) = jax.lax.scan(body, x, p["blocks"])
         new_caches = None
-        aux = jnp.sum(auxs)
     else:
         def body(x, xs):
             bp, ci = xs
-            y, co, aux = super_step(x, bp, ci)
-            return y, (co, aux)
+            y, co, stats = super_step(x, bp, ci)
+            return y, (co, stats)
         with jax.named_scope("layers"):
-            x, (new_caches, auxs) = jax.lax.scan(body, x,
-                                                 (p["blocks"], caches))
-        aux = jnp.sum(auxs)
+            x, (new_caches, (auxs, counts)) = jax.lax.scan(
+                body, x, (p["blocks"], caches))
+    aux = jnp.sum(auxs)
+    more = (jnp.sum(counts, axis=0),) if moe_counts else ()
 
     with jax.named_scope("unembed_sample"):
         x = L.norm_apply(p["final_norm"], x, kind=cfg.norm)
@@ -152,14 +165,15 @@ def lm_apply(p, cfg: ModelConfig, *, tokens=None, embeds=None, cond=None,
                 x = jnp.take_along_axis(x, li[:, None, None], axis=1)
         elif logits_slice is not None:
             x = x[:, logits_slice]
-        logits = L.unembed(p["embed"], x, dtype=cfg.cdtype())
+        logits = L.unembed(p.get("lm_head", p["embed"]), x,
+                           dtype=cfg.cdtype())
         if cfg.final_softcap > 0:
             logits = (cfg.final_softcap * jnp.tanh(
                 logits.astype(jnp.float32) / cfg.final_softcap))
         logits = shard(logits, "act_batch,act_seq,act_vocab")
         if logits_epilogue is not None:
-            return logits_epilogue(logits, new_caches), new_caches, aux
-        return logits, new_caches, aux
+            logits = logits_epilogue(logits, new_caches)
+        return (logits, new_caches, aux) + more
 
 
 # --------------------------------------------------------------- caches ----
@@ -179,7 +193,7 @@ def init_caches(cfg: ModelConfig, batch: int, max_seq: int,
     def one_super():
         c = {}
         for i, kind in enumerate(cfg.block_pattern):
-            if kind in ("attn", "attn_moe", "global", "local"):
+            if kind in B.ATTN_KINDS:
                 hkv, dk = cfg.n_kv_heads, cfg.head_dim_
                 attn = {
                     "k": jnp.zeros((batch, max_seq, hkv, dk), kv_dtype),
@@ -226,7 +240,7 @@ def init_paged_caches(cfg: ModelConfig, batch: int, num_pages: int,
     def one_super():
         c = {}
         for i, kind in enumerate(cfg.block_pattern):
-            if kind in ("attn", "attn_moe", "global", "local"):
+            if kind in B.ATTN_KINDS:
                 hkv, dk = cfg.n_kv_heads, cfg.head_dim_
                 attn = {
                     "k": jnp.zeros((num_pages, page_size, hkv, dk), kv_dtype),
@@ -368,7 +382,7 @@ def cache_axes(cfg: ModelConfig, *, quantized: bool = False,
     def one_super():
         c = {}
         for i, kind in enumerate(cfg.block_pattern):
-            if kind in ("attn", "attn_moe", "global", "local"):
+            if kind in B.ATTN_KINDS:
                 if paged:
                     attn = {
                         "k": "layers,act_kv_pages,,act_kv_heads,",

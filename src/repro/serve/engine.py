@@ -59,6 +59,7 @@ import numpy as np
 from repro.configs.base import ModelConfig, ServeConfig
 from repro.distributed import serve_mesh as SM
 from repro.kernels import cache_layout as CL
+from repro.models import blocks as B
 from repro.models import transformer as T
 from repro.serve import sampling as S
 from repro.serve.sampling import SamplingParams
@@ -83,13 +84,18 @@ gc.callbacks.append(_gc_span)
 
 
 def _has_attention(cfg: ModelConfig) -> bool:
-    return any(k in ("attn", "attn_moe", "global", "local")
-               for k in cfg.block_pattern)
+    return any(k in B.ATTN_KINDS for k in cfg.block_pattern)
 
 
 def _attention_only(cfg: ModelConfig) -> bool:
-    return all(k in ("attn", "attn_moe", "global", "local")
-               for k in cfg.block_pattern)
+    return all(k in B.ATTN_KINDS for k in cfg.block_pattern)
+
+
+def _counts_experts(cfg: ModelConfig) -> bool:
+    """Dropless expert layers: the fused decode step also returns their
+    counts (``lm_apply``'s ``moe_counts``)."""
+    return (cfg.moe is not None and cfg.moe.dropless
+            and any(k in B.ATTN_MOE_KINDS for k in cfg.block_pattern))
 
 
 def make_serve_fns(cfg: ModelConfig, scfg: ServeConfig, *, psum_axes=()):
@@ -110,7 +116,10 @@ def make_serve_fns(cfg: ModelConfig, scfg: ServeConfig, *, psum_axes=()):
     decode step takes ``batch_inputs["tokens"]`` as the ``(b,)`` last-token
     vector (it reshapes internally) plus optional ``active`` (b,) bool —
     rows where False return their input token unchanged — and optional
-    ``page_table``.
+    ``page_table``. Where the model has dropless expert layers
+    (``_counts_experts``) the fused decode step returns ``((tokens,
+    counts), caches)``, counts the (2,) int32 of ``lm_apply``'s
+    ``moe_counts`` over the active slots.
 
     With ``fused_sampling=False`` the legacy logits-returning signatures
     are preserved exactly (decode tokens ``(b, 1)``; returns
@@ -196,7 +205,7 @@ def make_serve_fns(cfg: ModelConfig, scfg: ServeConfig, *, psum_axes=()):
         kw = _model_inputs(cfg, batch_inputs)
         index = T.cache_index(caches)
         positions = index[:, None] if index is not None else None
-        out, caches, _ = T.lm_apply(
+        out, caches, _, *counts = T.lm_apply(
             params, cfg, caches=caches, merged=True, positions=positions,
             decode_kernel=scfg.decode_kernel,
             decode_kv_block=scfg.decode_kv_block,
@@ -204,13 +213,14 @@ def make_serve_fns(cfg: ModelConfig, scfg: ServeConfig, *, psum_axes=()):
             decode_active=batch_inputs.get("active"),
             page_table=batch_inputs.get("page_table"),
             logits_epilogue=_epilogue(sampling) if fused else None,
-            psum_axes=psum_axes, **kw)
+            psum_axes=psum_axes, moe_counts=fused and _counts_experts(cfg),
+            **kw)
         if not fused:
             return out[:, -1], caches
         active = batch_inputs.get("active")
         if active is not None:
             out = jnp.where(active, out, toks)
-        return out, caches
+        return ((out, *counts) if counts else out), caches
 
     return init_caches, prefill_step, decode_step, prefill_ragged
 
@@ -446,11 +456,15 @@ class ContinuousBatchingEngine:
         # life, and per-request perf_counter stamps (``submitted``,
         # ``admitted``, ``first_token``, ``finished``) taken where each
         # event happens; the spans of ``step`` carry the same boundaries
-        # on the profiler's clock
+        # on the profiler's clock. With dropless expert layers the decode
+        # steps' routed rows and experts hit, summed over layers, count
+        # too (``moe_rows``, ``moe_experts_hit``)
+        self._moe = self.fused and _counts_experts(cfg)
         self.counters = dict.fromkeys(
             ("steps", "decode_steps", "decode_active_slots",
              "decode_kv_pages", "decode_kv_grid_pages", "prefill_chunks",
-             "cow_copies", "table_uploads", "host_syncs"), 0)
+             "cow_copies", "table_uploads", "host_syncs")
+            + (("moe_rows", "moe_experts_hit") if self._moe else ()), 0)
         self.request_times: dict[int, dict[str, float]] = {}
         self._submits = 0                  # drives default-policy seed + k
         self._chunk = scfg.prefill_chunk
@@ -853,11 +867,21 @@ class ContinuousBatchingEngine:
         if self.fused:
             # device-side feedback: last tokens in, next tokens out — the
             # only host traffic is draining the (max_slots,) token vector
-            self._last, self.caches = self._decode(
+            out, self.caches = self._decode(
                 self.params, self.caches,
                 self._decode_inputs(self._last, active), self.bank)
-            with Span("engine.decode_drain"):
-                sampled = np.asarray(self._last)
+            if not self._moe:
+                self._last = out
+                with Span("engine.decode_drain"):
+                    sampled = np.asarray(self._last)
+            else:
+                # the expert counts come back in the same drain as the
+                # tokens
+                self._last = out[0]
+                with Span("engine.decode_drain"):
+                    sampled, counts = jax.device_get(out)
+                c["moe_rows"] += int(counts[0])
+                c["moe_experts_hit"] += int(counts[1])
         else:
             # legacy A/B baseline: ship (max_slots, vocab) logits to the
             # host and sample there — through the SAME per-slot schedule

@@ -90,3 +90,60 @@ def test_aux_loss_balanced_vs_skewed():
                   jnp.eye(cfg.d_model, cfg.moe.n_experts) * 50)
     _, aux_s = M.moe_apply(p_skew, x, cfg)
     assert float(aux_s) > float(aux_u)
+
+
+# ------------------------------------------------------------- dropless ----
+def _dropless():
+    cfg = get_config("mellum2-12b-a2.5b", smoke=True).replace(
+        d_ff=64, moe=MoEConfig(n_experts=16, top_k=8, capacity_factor=0.0))
+    p = M.moe_init(Ctx(random.key(0)), "moe", cfg)
+    x = random.normal(random.key(1), (2, 24, cfg.d_model)).astype(
+        jnp.bfloat16)
+    return cfg, p, x
+
+
+def test_dropless_equals_the_per_token_sum_over_its_top_k_experts():
+    """Every token reaches all k of its experts: the layer is the dense
+    weighted mixture, at float32 products on the bfloat16 operands."""
+    cfg, p, x = _dropless()
+    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), p["router"])
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits, -1), cfg.moe.top_k)
+    w = w / w.sum(-1, keepdims=True)
+    f32 = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+
+    def token(t, idxs, ws):
+        out = 0.0
+        for j in range(cfg.moe.top_k):
+            e = idxs[j]
+            h = jax.nn.silu(t @ f32(p["gate"][e])) * (t @ f32(p["up"][e]))
+            out = out + ws[j] * (f32(h) @ f32(p["down"][e]))
+        return out
+
+    dense = jax.vmap(jax.vmap(token))(f32(x), idx, w)
+    y, _, counts = M.moe_apply(p, x, cfg, counts=True)
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               np.asarray(dense), atol=2e-2, rtol=2e-2)
+    assert int(counts[0]) == 2 * 24 * cfg.moe.top_k
+    assert int(counts[1]) == len(np.unique(np.asarray(idx)))
+
+
+def test_dropless_row_does_not_depend_on_the_rest_of_its_chunk():
+    """A row's output is the same, bit for bit, whether its chunk is whole,
+    split in two, permuted, or has rows masked out (pad rows and idle
+    decode slots route nowhere and are not counted)."""
+    cfg, p, x = _dropless()
+    whole, _ = M.moe_apply(p, x, cfg)
+    halves = jnp.concatenate([M.moe_apply(p, x[:, :10], cfg)[0],
+                              M.moe_apply(p, x[:, 10:], cfg)[0]], axis=1)
+    np.testing.assert_array_equal(np.asarray(halves), np.asarray(whole))
+    perm = np.random.default_rng(0).permutation(24)
+    shuffled, _ = M.moe_apply(p, x[:, perm], cfg)
+    np.testing.assert_array_equal(np.asarray(shuffled),
+                                  np.asarray(whole[:, perm]))
+    mask = jnp.arange(24)[None, :] < jnp.asarray([[7], [19]])
+    masked, _, counts = M.moe_apply(p, x, cfg, row_mask=mask, counts=True)
+    keep = np.asarray(mask)
+    np.testing.assert_array_equal(np.asarray(masked)[keep],
+                                  np.asarray(whole)[keep])
+    assert not np.asarray(masked, np.float32)[~keep].any()
+    assert int(counts[0]) == (7 + 19) * cfg.moe.top_k

@@ -8,7 +8,7 @@ gemma2-2b (H=8, hkv=4, dk=256, window 4096, softcap 50), with a bf16 and an
 int8 KV cache, at the serving shapes the chip smoke test uses: 8 slots of
 4096 rows, 256-row pages, 512-token prefill chunks. The paged decode kernel
 also compiles at the benchmark's decode-long table (24 slots x 32 pages) at
-qwen2-1.5b and granite-3-2b widths.
+qwen2-1.5b, granite-3-2b and mellum2-12b-a2.5b widths.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and every pytest-xdist worker
@@ -95,8 +95,9 @@ def test_serving_kernel_compiles_for_v5e(one_chip, kernel, arch, kv_dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-CELL_SLOTS, CELL_COLUMNS = 24, 32      # qwen2-1.5b.decode-long's table
-CELL_WIDTHS = {"qwen2-1.5b": (12, 2, 128), "granite-3-2b": (32, 8, 64)}
+CELL_SLOTS, CELL_COLUMNS = 24, 32      # the decode-long cells' table
+CELL_WIDTHS = {"qwen2-1.5b": (12, 2, 128, 0), "granite-3-2b": (32, 8, 64, 0),
+               "mellum2-12b-a2.5b": (32, 4, 128, 1024)}   # H, hkv, dk, window
 
 
 def _sources(hlo: str, name: str) -> list[str]:
@@ -121,12 +122,13 @@ def _sources(hlo: str, name: str) -> list[str]:
 @pytest.mark.parametrize("arch", sorted(CELL_WIDTHS))
 def test_paged_decode_compiles_at_the_cell_shape_for_v5e(one_chip, arch,
                                                          kv_dtype):
-    """The paged decode kernel at the decode-long cell's table (24 slots x
-    32 pages of 256 rows). At qwen2-1.5b widths in bf16 the pools reach
-    the kernel as bitcasts of the arguments: the (P, ps * hkv, dk) word
-    view is free and no copy of a pool feeds the custom call. (An int8
-    pool and granite's 8 heads of dk 64 are relaid out by XLA first.)"""
-    H, hkv, dk = CELL_WIDTHS[arch]
+    """The paged decode kernel at the decode-long cells' table (24 slots x
+    32 pages of 256 rows). At qwen2-1.5b's widths, and at mellum2's 4 heads
+    of dk 128 with its 1024-row window, in bf16 the pools reach the kernel
+    as bitcasts of the arguments: the (P, ps * hkv, dk) word view is free
+    and no copy of a pool feeds the custom call. (An int8 pool and
+    granite's 8 heads of dk 64 are relaid out by XLA first.)"""
+    H, hkv, dk, window = CELL_WIDTHS[arch]
     dt = CL.kv_cache_dtype(kv_dtype)
     quant = CL.kv_quantized(dt)
     pages = CELL_SLOTS * CELL_COLUMNS
@@ -140,7 +142,7 @@ def test_paged_decode_compiles_at_the_cell_shape_for_v5e(one_chip, arch,
     def call(*a):
         ks, vs = a[7:] if quant else (None, None)
         return consmax_decode_paged(*a[:7], k_scale=ks, v_scale=vs,
-                                    interpret=False)
+                                    window=window, interpret=False)
 
     text = jax.jit(call).lower(*args, *scales).compile().as_text()
     kernel = next(line for line in text.splitlines()
@@ -150,7 +152,7 @@ def test_paged_decode_compiles_at_the_cell_shape_for_v5e(one_chip, arch,
     pools = [o for o in operands
              if f"[{pages}," in text.split(f"%{o} = ", 1)[1].split("\n")[0]]
     assert len(pools) == (4 if quant else 2)
-    if arch == "qwen2-1.5b" and not quant:
+    if arch != "granite-3-2b" and not quant:
         assert all(_sources(text, o)[-1] == "parameter" for o in pools), \
             [_sources(text, o) for o in pools]
 
@@ -185,3 +187,41 @@ def test_engine_decode_step_keeps_its_names_for_v5e(one_chip, monkeypatch):
     assert "%consmax_decode_paged_op" in text
     assert "tpu_custom_call" in text
     assert "/kv_cache/" in text
+
+
+def test_mellum2_decode_step_keeps_its_names_for_v5e(one_chip, monkeypatch):
+    """Mellum2's paged decode step (one period of four layers at published
+    widths, a small vocabulary), compiled for a v5e: the grouped expert
+    matmuls are three ``gmm`` kernels a layer under the ``moe_experts``
+    scope, inside ``moe``; the decode kernel keeps its name; the step's
+    outputs are the tokens and the expert counts."""
+    from repro.configs.base import ServeConfig
+    from repro.kernels.consmax_decode import ops as decode_ops
+    from repro.models import moe
+    from repro.models import transformer as T
+    from repro.nn import module as nn
+    from repro.serve.engine import ContinuousBatchingEngine
+    monkeypatch.setattr(decode_ops, "_on_cpu", lambda: False)
+    monkeypatch.setattr(moe, "_on_cpu", lambda: False)
+    cfg = get_config("mellum2-12b-a2.5b", n_layers=4, vocab_size=1024,
+                     param_dtype="bfloat16")
+    scfg = ServeConfig(score_norm=cfg.score_norm, max_slots=SLOTS,
+                       max_seq=1024, paged_kv=True, page_size=PAGE,
+                       prefill_chunk=CHUNK, decode_kernel=True,
+                       prefill_kernel=True)
+    eng = ContinuousBatchingEngine(cfg, scfg, nn.abstract_init(T.lm_init,
+                                                                cfg))
+    place = functools.partial(jax.tree.map, lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip))
+    inputs = eng._decode_inputs(eng._last, [False] * SLOTS)
+    lowered = eng._decode.lower(place(eng.params), place(eng.caches),
+                                place(inputs), place(eng.bank))
+    tokens, counts = lowered.out_info[0]
+    assert tokens.shape == (SLOTS,) and counts.shape == (2,)
+    text = lowered.compile().as_text()
+    assert text.startswith("HloModule jit_decode_step")
+    gmm = [line for line in text.splitlines() if re.match(r"\s*%gmm", line)]
+    assert len(gmm) == 3 * cfg.n_layers
+    assert all("tpu_custom_call" in g for g in gmm)
+    assert all("/moe/moe_experts/" in g for g in gmm)
+    assert "%consmax_decode_paged_op" in text
