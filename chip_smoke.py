@@ -129,7 +129,9 @@ def _kernel_cases(cfg, seed):
         kp, kps, kpref = cache(5, (pages, PAGE, hkv, d))
         vp, vps, vpref = cache(6, (pages, PAGE, hkv, d))
         scales = dict(k_scale=ks, v_scale=vs)
-        pscales = dict(k_scale=kps, v_scale=vps)
+        # the paged kernels read stacked pools at a layer: here one layer
+        pscales = dict(k_scale=None if kps is None else kps[None],
+                       v_scale=None if vps is None else vps[None])
 
         def dref(kc, vc):
             return lambda vv: consmax_decode_ref(
@@ -146,7 +148,7 @@ def _kernel_cases(cfg, seed):
             (f"decode/{kv_dtype}", consmax_decode,
              (qd, k, v, lens, beta, gamma), scales, dref(kref, vref)),
             (f"decode_paged/{kv_dtype}", consmax_decode_paged,
-             (qd, kp, vp, table, lens, beta, gamma), pscales,
+             (qd, kp[None], vp[None], 0, table, lens, beta, gamma), pscales,
              dref(gather(kpref), gather(vpref))),
             (f"prefill/{kv_dtype}", consmax_prefill,
              (qp, k[two], v[two], index, chunk_lens, beta, gamma),
@@ -154,7 +156,8 @@ def _kernel_cases(cfg, seed):
                   v_scale=None if vs is None else vs[two]),
              pref(kref, vref, two)),
             (f"prefill_paged/{kv_dtype}", consmax_prefill_paged,
-             (qp, kp, vp, table[two], index, chunk_lens, beta, gamma),
+             (qp, kp[None], vp[None], 0, table[two], index, chunk_lens, beta,
+              gamma),
              pscales, pref(gather(kpref), gather(vpref), two)),
         ]
     return [(name, functools.partial(fn, interpret=False, **kw, **sc), args,
@@ -221,8 +224,9 @@ def decode_timing(cfg, dev, seed):
         return jax.random.normal(jax.random.fold_in(key, i), shape,
                                  jnp.float32).astype(jnp.bfloat16)
 
-    args = (rnd(1, (DT_SLOTS, H, d)), rnd(2, (pages, PAGE, hkv, d)),
-            rnd(3, (pages, PAGE, hkv, d)), jnp.asarray(table),
+    args = (rnd(1, (DT_SLOTS, H, d)), rnd(2, (1, pages, PAGE, hkv, d)),
+            rnd(3, (1, pages, PAGE, hkv, d)), jnp.asarray(0, jnp.int32),
+            jnp.asarray(table),
             jnp.asarray(lens), jnp.linspace(0.5, 2.5, H, dtype=jnp.float32),
             jnp.full((H,), 2.0, jnp.float32))
 

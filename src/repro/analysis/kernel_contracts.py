@@ -30,9 +30,9 @@ Checks (the kernel half of the serving contract):
   ``dimension_semantics`` for its grid; an undeclared grid silently
   serializes (and hides races from this checker).
 * ``scalar-prefetch`` — the paged kernels' scalar-prefetch operands (page
-  table, index, kv_len) must match the declared arity and be int32: SMEM
-  scalars drive BlockSpec index maps, and a float or wide-int table is a
-  mis-wired launch that Mosaic reports only at compile time on hardware.
+  table, index, kv_len, layer) must match the declared arity and be int32:
+  SMEM scalars drive BlockSpec index maps, and a float or wide-int table is
+  a mis-wired launch that Mosaic reports only at compile time on hardware.
 """
 from __future__ import annotations
 
@@ -343,7 +343,8 @@ def serving_launches(cfg, scfg) -> dict[str, KernelLaunch]:
     its real shapes (full fill — the capacity grid, the worst case for VMEM
     and races), without running them. Contiguous or paged follows
     ``scfg.paged_kv``; block sizes follow the config's kv-block knobs,
-    mirroring exactly what ``make_serve_fns`` would launch."""
+    mirroring exactly what ``make_serve_fns`` would launch: the paged
+    kernels get every layer's pools, stacked, and read the last layer."""
     from repro.kernels.consmax_decode.kernel import (consmax_decode,
                                                      consmax_decode_paged)
     from repro.kernels.consmax_prefill.kernel import (consmax_prefill,
@@ -367,19 +368,21 @@ def serving_launches(cfg, scfg) -> dict[str, KernelLaunch]:
     if scfg.paged_kv:
         ps, P = scfg.page_size, scfg.num_pages
         npg = scfg.max_pages_per_slot
-        pool = jnp.zeros((P, ps, hkv, d), kv_dtype)
-        spool = jnp.ones((P, ps, hkv), jnp.float32) if quant else None
+        n = cfg.n_super_layers
+        layer = jnp.asarray(n - 1, jnp.int32)
+        pool = jnp.zeros((n, P, ps, hkv, d), kv_dtype)
+        spool = jnp.ones((n, P, ps, hkv), jnp.float32) if quant else None
         table = (jnp.arange(b * npg, dtype=jnp.int32) % P).reshape(b, npg)
         with capture_launches() as caught:
             consmax_decode_paged(
-                jnp.zeros((b, H, d)), pool, pool, table,
+                jnp.zeros((b, H, d)), pool, pool, layer, table,
                 jnp.full((b,), L, jnp.int32), beta, gamma, window=window,
                 softcap=softcap, fill_bound=scfg.fill_bound,
                 k_scale=spool, v_scale=spool)
         grab("decode_paged", caught)
         with capture_launches() as caught:
             consmax_prefill_paged(
-                jnp.zeros((1, c, H, d)), pool, pool, table[:1],
+                jnp.zeros((1, c, H, d)), pool, pool, layer, table[:1],
                 jnp.full((1,), L - c, jnp.int32),
                 jnp.full((1,), c, jnp.int32), beta, gamma, window=window,
                 softcap=softcap, fill_bound=scfg.fill_bound,

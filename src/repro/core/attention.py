@@ -28,7 +28,8 @@ Two execution paths:
 
 * ``paged_attention`` — append/decode against a *shared page pool* instead
   of per-slot contiguous rows: a (num_pages, page_size, hkv, dk) K/V buffer
-  plus a per-slot page table. The KV walk iterates page-table entries; for
+  per layer, every layer's stacked and read at a layer index, plus a
+  per-slot page table. The KV walk iterates page-table entries; for
   consmax each page's partial is final (pure-addition combine — the same
   sync-free property, now doing memory-management work), softmax/softermax
   keep their online (m, l) fallback across pages.
@@ -335,17 +336,26 @@ def append_attention(q, k, v, index, lengths, *, norm_kind, norm_params,
 
 
 # ------------------------------------------------------ paged KV cache ----
-def _paged_cache_write(pool, new, index, lengths, page_table):
-    """Scatter ``new``: (b, c, hkv, dk) into the shared page ``pool``:
-    (P, ps, hkv, dk) at per-slot logical rows [index, index + lengths).
+def _paged_cache_write(pool, new, layer, index, lengths, page_table):
+    """Scatter ``new``: (b, c, hkv, dk) into layer ``layer`` of the stacked
+    shared page pools ``pool``: (n_layers, P, ps, hkv, dk) at per-slot
+    logical rows [index, index + lengths) — in place, nothing sliced. (A
+    scale pool: ``new`` (b, c, hkv), ``pool`` (n_layers, P, ps, hkv).)
 
     Logical row t of slot b lands in pool page ``page_table[b, t // ps]``,
     page row ``t % ps``. Pad rows (>= lengths) and rows whose page-table
     entry is unmapped are routed out of bounds and dropped by the scatter —
     no pad-token K/V ever reaches a page, mirroring the contiguous append
     path. Slots own disjoint pages (the PagePool invariant), so the scatter
-    indices never collide."""
-    P, ps = pool.shape[0], pool.shape[1]
+    indices never collide.
+
+    A pool that XLA does not store row-major (``CL.page_view``) is written
+    a whole page at a time instead (``_paged_page_write``): a row scatter
+    would make XLA relay the carried pool out around the layer loop."""
+    if CL.page_view(pool.dtype, pool.shape[2:]) != CL.ROW_MAJOR:
+        return _paged_page_write(pool, new, layer, index, lengths,
+                                 page_table)
+    P, ps = pool.shape[1], pool.shape[2]
     b, c = new.shape[:2]
     pos = index[:, None] + jnp.arange(c)[None, :]            # (b, c) logical
     valid = jnp.arange(c)[None, :] < lengths[:, None]
@@ -356,53 +366,82 @@ def _paged_cache_write(pool, new, index, lengths, page_table):
     oob = ~valid | (logical_page >= page_table.shape[1]) | (pid < 0)
     pid = jnp.where(oob, P, pid)                             # dropped below
     row = pos % ps
-    return pool.at[pid.reshape(-1), row.reshape(-1)].set(
+    return pool.at[layer, pid.reshape(-1), row.reshape(-1)].set(
         new.reshape((b * c,) + new.shape[2:]).astype(pool.dtype),
         mode="drop")
 
 
-def paged_attention(q, kp, vp, page_table, index, lengths, *, norm_kind,
-                    norm_params, window=0, softcap=0.0, merged=True,
-                    k_scale=None, v_scale=None):
+def _paged_page_write(pool, new, layer, index, lengths, page_table):
+    """``_paged_cache_write`` a whole page at a time: each page a slot's
+    chunk lands on is gathered, its fresh rows merged in, and scattered
+    back whole — a scatter whose window is the page's every dim, which XLA
+    does in place in any layout, where a row's window ``(hkv, dk)`` would
+    have it keep those dims minor-most. Pages that take no fresh row (past
+    ``lengths``, unmapped) are dropped, so the pool ends bit-identical to
+    the row scatter's."""
+    P, ps = pool.shape[1], pool.shape[2]
+    b, c = new.shape[:2]
+    npg = page_table.shape[1]
+    m = (c + ps - 2) // ps + 1                   # pages a chunk can touch
+    lp = index[:, None] // ps + jnp.arange(m)[None, :]       # (b, m)
+    pid = jnp.take_along_axis(page_table, jnp.clip(lp, 0, npg - 1), axis=1)
+    off = (lp[..., None] * ps + jnp.arange(ps)) - index[:, None, None]
+    fresh = (off >= 0) & (off < lengths[:, None, None])      # (b, m, ps)
+    pid = jnp.where(fresh.any(-1) & (lp < npg) & (pid >= 0), pid, P)
+    old = pool[layer, jnp.minimum(pid, P - 1)]     # (b, m, ps, hkv[, dk])
+    tail = (1,) * (new.ndim - 2)
+    upd = jnp.take_along_axis(
+        new, jnp.clip(off, 0, c - 1).reshape(b, m * ps, *tail), axis=1)
+    pages = jnp.where(fresh.reshape(fresh.shape + tail),
+                      upd.reshape(old.shape).astype(pool.dtype), old)
+    return pool.at[layer, pid.reshape(-1)].set(
+        pages.reshape((b * m,) + pages.shape[2:]), mode="drop")
+
+
+def paged_attention(q, kp, vp, layer, page_table, index, lengths, *,
+                    norm_kind, norm_params, window=0, softcap=0.0,
+                    merged=True, k_scale=None, v_scale=None):
     """Attention of a (b, c, H, dk) chunk against page-pool KV.
 
-    kp, vp: (P, ps, hkv, dk) shared pools; page_table: (b, max_pages) int32
+    kp, vp: (n_layers, P, ps, hkv, dk) stacked shared pools, read at
+    ``layer`` (int32 scalar); page_table: (b, max_pages) int32
     (-1 = unmapped); index: (b,) chunk start positions; lengths: (b,) real
     tokens in the chunk. Covers both chunked append prefill (c > 1) and
     one-token decode (c == 1, lengths = active mask — an inactive slot gets
     kv_len = index, i.e. a fully masked row whose output is discarded).
 
     The KV walk iterates *page-table entries*: iteration j gathers one page
-    per slot (``kp[page_table[:, j]]``, a batched one-page gather) holding
-    logical rows [j*ps, (j+1)*ps), bounded by the highest filled page across
-    the batch — cost tracks fill level, not pool capacity. For consmax the
-    carry is the output accumulator alone: each page's ``exp(s-beta)/gamma
-    @ v`` partial is final (the paper's sync-free property is what makes
-    paging this cheap). softmax/softermax keep their online (m, l) rescale
-    fallback across pages. Unmapped entries are clamped to page 0; every
-    position they could contribute sits at kpos >= kv_len and is masked.
+    per slot (``kp[layer, page_table[:, j]]``, a batched one-page gather)
+    holding logical rows [j*ps, (j+1)*ps), bounded by the highest filled
+    page across the batch — cost tracks fill level, not pool capacity. For
+    consmax the carry is the output accumulator alone: each page's
+    ``exp(s-beta)/gamma @ v`` partial is final (the paper's sync-free
+    property is what makes paging this cheap). softmax/softermax keep their
+    online (m, l) rescale fallback across pages. Unmapped entries are
+    clamped to page 0; every position they could contribute sits at
+    kpos >= kv_len and is masked.
 
-    ``k_scale``/``v_scale``: (P, ps, hkv) fp32 scale pools for quantized
-    page pools — each gathered page is dequantized page-at-a-time (the
-    round-trip the Pallas kernel performs in VMEM).
+    ``k_scale``/``v_scale``: (n_layers, P, ps, hkv) fp32 scale pools for
+    quantized page pools — each gathered page is dequantized page-at-a-time
+    (the round-trip the Pallas kernel performs in VMEM).
 
     Unmapped entries (-1) are clamped to page 0 by the gather but their
     whole block is masked via ``block_valid`` — under sequence sharding a
     shard's localized table holds -1 for every page another shard owns
     *mid-fill*, where the kv_len bound alone would not exclude page 0's
     (foreign) rows."""
-    ps = kp.shape[1]
+    ps = kp.shape[2]
     hi = jnp.max(-(-(index + lengths) // ps))                # dynamic bound
 
     def gather(j):
         pid = jnp.maximum(page_table[:, j], 0)               # (b,)
-        k_blk, v_blk = kp[pid], vp[pid]                      # (b, ps, hkv, dk)
+        k_blk, v_blk = kp[layer, pid], vp[layer, pid]        # (b, ps, hkv, dk)
         if k_scale is not None:
-            k_blk = CL.dequantize_kv(k_blk, k_scale[pid], q.dtype)
-            v_blk = CL.dequantize_kv(v_blk, v_scale[pid], q.dtype)
+            k_blk = CL.dequantize_kv(k_blk, k_scale[layer, pid], q.dtype)
+            v_blk = CL.dequantize_kv(v_blk, v_scale[layer, pid], q.dtype)
         return k_blk, v_blk
 
-    return _kv_walk(q, index, lengths, gather, hi, ps, kp.shape[2],
+    return _kv_walk(q, index, lengths, gather, hi, ps, kp.shape[3],
                     norm_kind=norm_kind, norm_params=norm_params,
                     window=window, softcap=softcap, merged=merged,
                     block_valid=lambda j: page_table[:, j] >= 0)
@@ -452,7 +491,8 @@ def attention_apply(p, x, cfg: ModelConfig, *, kind: str = "global",
                     decode_kernel: bool = False, decode_kv_block: int = 256,
                     prefill_kernel: bool = False, prefill_kv_block: int = 512,
                     fill_bound: bool = True, prefill_append=None,
-                    decode_active=None, page_table=None, psum_axes=()):
+                    decode_active=None, page_table=None, layer=None,
+                    psum_axes=()):
     """Self- or cross-attention over x: (b, s, d).
 
     cache: None (train/prefill) or dict(k, v, index) for one-token decode.
@@ -479,10 +519,13 @@ def attention_apply(p, x, cfg: ModelConfig, *, kind: str = "global",
     K/V rows are quantized per-row-per-head at write time and the kernels
     (or jnp fallbacks) dequantize block-at-a-time at read time; the cache
     is never materialized in a wide dtype.
-    page_table: (b, max_pages) int32 — paged KV: the cache's k/v leaves are
-    shared (num_pages, page_size, hkv, dk) pools and each slot's logical
-    rows live on the pages its table row maps (-1 = unmapped). Applies to
-    the chunked-prefill and one-token decode cache paths only.
+    page_table: (b, max_pages) int32 — paged KV: the cache's leaves are
+    every layer's, stacked — k/v the shared (n_layers, num_pages,
+    page_size, hkv, dk) pools, ``index`` (n_layers, b) — and this block
+    writes and reads layer ``layer`` (int32 scalar) of them in place; each
+    slot's logical rows live on the pages its table row maps (-1 =
+    unmapped). Applies to the chunked-prefill and one-token decode cache
+    paths only.
     psum_axes: ("model", "seq") mesh axis pair for sharded serving under
     shard_map; empty = single-device, no collective. The combine runs on
     the per-head outputs BEFORE the o-projection: KV shards ("seq", pages
@@ -531,17 +574,18 @@ def attention_apply(p, x, cfg: ModelConfig, *, kind: str = "global",
                          for a in (q, k))
 
     if cache is not None and page_table is not None and not cross:
-        # paged KV: cache k/v leaves are shared (P, ps, hkv, dk) page pools.
-        # One code path covers chunked append prefill (s = chunk) and
-        # one-token decode (s == 1, where the active mask doubles as the
-        # chunk length: an inactive slot writes nothing and reads a fully
-        # masked row).
+        # paged KV: cache leaves are every layer's, stacked; k/v are shared
+        # (n_layers, P, ps, hkv, dk) page pools, written and read at
+        # ``layer`` in place. One code path covers chunked append prefill
+        # (s = chunk) and one-token decode (s == 1, where the active mask
+        # doubles as the chunk length: an inactive slot writes nothing and
+        # reads a fully masked row).
         if prefill_append is None and s > 1:
             raise NotImplementedError(
                 "paged KV caches serve chunked prefill (prefill_append) "
                 "and one-token decode only — whole-prompt prefill writes "
                 "contiguous rows")
-        idx = cache["index"]                                 # (b,) int32
+        idx = cache["index"][layer]                          # (b,) int32
         if prefill_append is not None:
             lengths = prefill_append.astype(jnp.int32)
         else:
@@ -557,12 +601,14 @@ def attention_apply(p, x, cfg: ModelConfig, *, kind: str = "global",
                 # the data
                 k, ksc = CL.quantize_kv(k, cache["k"].dtype)
                 v, vsc = CL.quantize_kv(v, cache["v"].dtype)
-                ksp = _paged_cache_write(cache["k_scale"], ksc, idx, lengths,
-                                         page_table)
-                vsp = _paged_cache_write(cache["v_scale"], vsc, idx, lengths,
-                                         page_table)
-            kp = _paged_cache_write(cache["k"], k, idx, lengths, page_table)
-            vp = _paged_cache_write(cache["v"], v, idx, lengths, page_table)
+                ksp = _paged_cache_write(cache["k_scale"], ksc, layer, idx,
+                                         lengths, page_table)
+                vsp = _paged_cache_write(cache["v_scale"], vsc, layer, idx,
+                                         lengths, page_table)
+            kp = _paged_cache_write(cache["k"], k, layer, idx, lengths,
+                                    page_table)
+            vp = _paged_cache_write(cache["v"], v, layer, idx, lengths,
+                                    page_table)
         with jax.named_scope("attn"):
             if (prefill_append is not None and prefill_kernel
                     and cfg.score_norm == "consmax"):
@@ -572,7 +618,7 @@ def attention_apply(p, x, cfg: ModelConfig, *, kind: str = "global",
                 from repro.kernels.consmax_prefill.ops import (
                     consmax_prefill_paged_op)
                 out = consmax_prefill_paged_op(
-                    q, kp, vp, page_table, idx, lengths,
+                    q, kp, vp, layer, page_table, idx, lengths,
                     jnp.broadcast_to(p["score_norm"]["beta"], (H,)),
                     jnp.broadcast_to(p["score_norm"]["gamma"], (H,)),
                     window=window, softcap=cfg.attn_softcap, merged=merged,
@@ -582,18 +628,19 @@ def attention_apply(p, x, cfg: ModelConfig, *, kind: str = "global",
                 from repro.kernels.consmax_decode.ops import (
                     consmax_decode_paged_op)
                 out = consmax_decode_paged_op(
-                    q, kp, vp, page_table, idx + lengths,
+                    q, kp, vp, layer, page_table, idx + lengths,
                     jnp.broadcast_to(p["score_norm"]["beta"], (H,)),
                     jnp.broadcast_to(p["score_norm"]["gamma"], (H,)),
                     window=window, softcap=cfg.attn_softcap, merged=merged,
                     scale=1.0, fill_bound=fill_bound, k_scale=ksp, v_scale=vsp)
             else:
                 out = paged_attention(
-                    q, kp, vp, page_table, idx, lengths,
+                    q, kp, vp, layer, page_table, idx, lengths,
                     norm_kind=cfg.score_norm, norm_params=p["score_norm"],
                     window=window, softcap=cfg.attn_softcap, merged=merged,
                     k_scale=ksp, v_scale=vsp)
-        new_cache = {"k": kp, "v": vp, "index": idx + lengths}
+        new_cache = {"k": kp, "v": vp,
+                     "index": cache["index"].at[layer].set(idx + lengths)}
         if ksp is not None:
             new_cache.update(k_scale=ksp, v_scale=vsp)
     elif cache is not None and prefill_append is not None and not cross:

@@ -1,8 +1,9 @@
 """Shared layout / masking helpers for the serving-path ConSmax kernels.
 
 The decode (``consmax_decode``) and prefill (``consmax_prefill``) kernels
-read the model's KV-cache layout ``(b, L, hkv, dk)`` (or the page pool's
-``(P, ps, hkv, dk)``) *directly*. The contiguous kernels and the paged
+read the model's KV-cache layout ``(b, L, hkv, dk)`` (or every layer's
+page pools, stacked ``(n_layers, P, ps, hkv, dk)``, at a layer index)
+*directly*. The contiguous kernels and the paged
 prefill kernel block it: one grid program covers a block of rows of **all**
 hkv heads — K/V blocks ``(…, rows, hkv, dk)``, scale blocks
 ``(…, rows, hkv)`` — and loops over the heads in its body. The last two
@@ -26,7 +27,10 @@ kernel families agree on lives here:
   ``(H,)`` SMEM parameter vector inside a kernel body.
 * ``kv_head`` / ``consmax_partial`` — one KV head's (dequantized) tile out
   of an all-heads block, and one block's ``p @ v`` partial: the body every
-  serving kernel runs once per head.
+  serving kernel runs once per head; ``page_view`` / ``stored_view`` /
+  ``page_head`` — the order XLA's TPU layout stores a stacked page pool
+  in, the view of the stack the paged kernels read in it (a bitcast
+  there), and one head's tile of a page read that way.
 * ``packs_head_pairs`` / ``unpack_head_pair`` — when a bfloat16 page keeps
   two heads in each 32-bit word, and how one pass over the words yields
   both heads' tiles exactly.
@@ -166,6 +170,75 @@ def kv_head(x_ref, s_ref, i: int, h: int, dtype):
     return dequant_block(x_ref[i, :, h], s_ref[i, :, h:h + 1], dtype)
 
 
+# The orders XLA's TPU layout stores a stacked paged pool's pages in, and
+# the view of the stack the paged kernels read in each: a K/V page
+# (ps, hkv, dk) or a scale page (ps, hkv) kept as it is, with each head's
+# rows above them (hkv, ps[, dk]), or with the page's rows on the lanes
+# (hkv, dk, ps).
+ROW_MAJOR, HEAD_MAJOR, ROWS_MINOR = "row_major", "head_major", "rows_minor"
+
+
+def page_view(dtype, page) -> str:
+    """The order XLA's TPU layout stores a stacked pool of ``page``-shaped
+    pages in, ``(ps, hkv, dk)`` K/V or ``(ps, hkv)`` fp32 scales, as the
+    paged kernels read it (``stored_view``). XLA pads a tiled layout's two
+    minor dims to whole tiles, and orders the dims to avoid padding: 128
+    lanes, and 8 sublanes or a small tile of 1, 2 or 4 32-bit words. So,
+    compiled for a v5e, it stores
+
+    * K/V pages whose head rows are not whole lanes rows-minor, where the
+      page's rows are (``ps % 128 == 0``: granite-3-2b's 8 heads of dk 64);
+    * K/V pages with whole head rows head-major where ``hkv`` heads would
+      pad the sublanes (qwen2-1.5b's int8 pool: 2 heads at 4 a word), and
+      row-major where they would not (its bfloat16 pool);
+    * scale pages rows-minor where the page's rows are whole lanes and
+      ``hkv`` fits the sublanes.
+
+    ``tests/test_tpu_compile.py`` checks this against the layouts XLA picks
+    over a grid of widths. Elsewhere (pages of fewer than 128 rows without
+    whole head rows, scales of 3, 6 or 12 heads) XLA may put pages or heads
+    outermost, where no page is one block: the kernels then read the
+    row-major view, and the step relays the pool out around the layer
+    loop; no served configuration pages that way."""
+    ps, hkv, *dk = page
+    words = 4 // jnp.dtype(dtype).itemsize          # rows in a 32-bit word
+    fits = hkv % 8 == 0 or hkv in (words, 2 * words, 4 * words)
+    if dk and dk[0] % 128 == 0:
+        return ROW_MAJOR if fits else HEAD_MAJOR
+    return ROWS_MINOR if ps % 128 == 0 and (dk or fits) else ROW_MAJOR
+
+
+def stored_view(x, view: str):
+    """The stacked pools ``x``, ``(n, P, ps, hkv[, dk])``, in ``view``'s
+    order: on the TPU the bytes XLA stores, so the view is a bitcast."""
+    if view == HEAD_MAJOR:
+        return jnp.moveaxis(x, 3, 2)
+    if view == ROWS_MINOR:
+        return jnp.moveaxis(x, 2, -1)
+    return x
+
+
+def page_head(x_ref, s_ref, i: int, h: int, dtype, view: str,
+              scale_view: str):
+    """KV head ``h`` of buffered page ``i`` of an all-heads page block read
+    in ``view`` (``page_view``), in ``dtype``: ``(tile, rows_minor)``, the
+    tile ``(ps, dk)``, or ``(dk, ps)`` where ``rows_minor``. Dequantized in
+    VMEM when the page's scale block ``s_ref``, read in ``scale_view``,
+    rides along (None = pool stored as-is): scales stored rows-minor are a
+    ``(1, ps)`` row, others a ``(ps, 1)`` column, and the codes are
+    transposed (in fp32) where they lie across their scales, so each
+    element's multiply is ``dequantize_kv``'s."""
+    x = x_ref[i, :, h] if view == ROW_MAJOR else x_ref[i, h]
+    rows_minor = view == ROWS_MINOR
+    if s_ref is None:
+        return x.astype(dtype), rows_minor
+    if (scale_view == ROWS_MINOR) != rows_minor:
+        x, rows_minor = x.astype(jnp.float32).T, not rows_minor
+    s = (s_ref[i, h:h + 1] if scale_view == ROWS_MINOR
+         else s_ref[i, :, h:h + 1])
+    return dequant_block(x, s, dtype), rows_minor
+
+
 def packs_head_pairs(dtype, hkv: int, dk: int) -> bool:
     """True iff a ``(rows, hkv, dk)`` page stores each row's KV heads two
     to a 32-bit word: bfloat16, an even head count and 128-lane head rows.
@@ -189,16 +262,19 @@ def unpack_head_pair(words, dtype):
 
 
 def consmax_partial(q, k, v, mask, beta, gamma, *, scale: float,
-                    softcap: float, merged: bool):
+                    softcap: float, merged: bool, rows_minor: bool = False):
     """One KV block's ConSmax partial ``p @ v`` in fp32: q (m, dk), k/v
-    (rows, dk), ``mask`` (m, rows), beta/gamma (m, 1). No running max and no
-    denominator, so partials of different blocks combine by addition."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+    (rows, dk) — or (dk, rows) tiles with ``rows_minor`` — ``mask``
+    (m, rows), beta/gamma (m, 1). No running max and no denominator, so
+    partials of different blocks combine by addition."""
+    dk_axis = 0 if rows_minor else 1
+    s = jax.lax.dot_general(q, k, (((1,), (dk_axis,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if softcap > 0:
         s = softcap * jnp.tanh(s / softcap)
     p = jnp.where(mask, consmax_weights(s, beta, gamma, merged), 0.0)
-    return jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+    return jax.lax.dot_general(p.astype(v.dtype), v,
+                               (((1,), (1 - dk_axis,)), ((), ())),
                                preferred_element_type=jnp.float32)
 
 
@@ -390,7 +466,8 @@ def dequantize_kv(q, scale, out_dtype=jnp.float32):
 
 def dequant_block(x, scale, out_dtype):
     """In-kernel per-block dequant: ``x`` a (rows, dk) VMEM tile, ``scale``
-    its (rows, 1) fp32 scale column. Identical arithmetic to
+    its (rows, 1) fp32 scale column — or a (dk, rows) tile and its
+    (1, rows) scale row. Identical arithmetic to
     ``dequantize_kv`` (f32 multiply, then cast) so the Pallas kernels and
     the jnp oracles round-trip bit-identically."""
     return (x.astype(jnp.float32) * scale).astype(out_dtype)

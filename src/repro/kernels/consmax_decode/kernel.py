@@ -13,8 +13,9 @@ cross-shard ordering. This is the decode-time analogue of flash-decoding's
 split-KV, but without the log-sum-exp combine step softmax forces.
 
 Both variants read the model's cache layout **directly** — contiguous
-``(b, L, hkv, dk)`` rows or the shared ``(P, ps, hkv, dk)`` page pool — so a
-decode step never traces a transposed (or padded) copy of the cache.
+``(b, L, hkv, dk)`` rows or every layer's shared ``(P, ps, hkv, dk)`` page
+pool, stacked, at a layer index — so a decode step never traces a
+transposed, padded or sliced copy of the cache.
 
 The contiguous kernel's grid program covers a KV shard of **all** hkv
 heads: K/V blocks ``(…, bk, hkv, dk)``, scale blocks ``(…, bk, hkv)``,
@@ -244,9 +245,10 @@ def _pages_per_program(npg: int, page_bytes: int) -> int:
     return max(1, min(npg, PAGE_VMEM_BYTES // page_bytes))
 
 
-def _paged_kernel(tab_ref, len_ref, beta_ref, gamma_ref, q_ref, *refs,
-                  ps: int, ppb: int, npg: int, n_pools: int, packed: bool,
-                  bounded: bool, g: int, window: int, **partial_kw):
+def _paged_kernel(tab_ref, len_ref, lay_ref, beta_ref, gamma_ref, q_ref,
+                  *refs, ps: int, ppb: int, npg: int, n_pools: int,
+                  packed: bool, views: tuple[str, str], bounded: bool,
+                  g: int, window: int, **partial_kw):
     """Program (slot, run): table columns ``[jb * ppb, jb * ppb + ppb)`` of
     slot ``ib``; each column writes its own fp32 partial ``o_ref[0, h, i]``
     per head. Bounded, a column is computed only if it is in the table,
@@ -254,12 +256,14 @@ def _paged_kernel(tab_ref, len_ref, beta_ref, gamma_ref, q_ref, *refs,
     exact zeros. Unbounded, every column in the table is computed, unmapped
     ones from page 0, whose rows sit at kpos >= n.
 
-    ``packed``: the pools are HBM refs of ``(ps * hkv, d)`` word-packed
-    pages, and the program DMAs each column it computes into VMEM scratch
-    itself, all started before the first is waited on, so later pages
-    stream in while earlier ones compute; dead columns are never fetched.
-    Otherwise ``ppb`` is 1 and the refs are the one page (and its scales)
-    the pipeline fetched through the BlockSpec index map."""
+    ``packed``: the pools are HBM refs of every layer's ``(ps * hkv, d)``
+    word-packed pages, and the program DMAs each column it computes from
+    layer ``lay_ref[0]`` into VMEM scratch itself, all started before the
+    first is waited on, so later pages stream in while earlier ones
+    compute; dead columns are never fetched. Otherwise ``ppb`` is 1 and
+    the refs are the one page (and its scales) the pipeline fetched
+    through the BlockSpec index map, read in ``views`` (the K/V and the
+    scale pools' ``CL.page_view``)."""
     pools, (o_ref, *scratch) = refs[:n_pools], refs[n_pools:]
     ib, jb = pl.program_id(0), pl.program_id(1)
     n = len_ref[ib]
@@ -279,8 +283,8 @@ def _paged_kernel(tab_ref, len_ref, beta_ref, gamma_ref, q_ref, *refs,
         bufs, sem = scratch[:n_pools], scratch[n_pools]
 
         def copies(i, page):
-            return [pltpu.make_async_copy(src.at[page], dst.at[i],
-                                          sem.at[c, i])
+            return [pltpu.make_async_copy(src.at[lay_ref[0], page],
+                                          dst.at[i], sem.at[c, i])
                     for c, (src, dst) in enumerate(zip(pools, bufs))]
 
         @pl.loop(0, ppb)
@@ -299,12 +303,16 @@ def _paged_kernel(tab_ref, len_ref, beta_ref, gamma_ref, q_ref, *refs,
                CL.head_rows(gamma_ref, h, g, g)) for h in range(hkv)]
 
     def heads(i):
-        """Each head's (ps, d) K and V tiles of buffered page ``i``."""
+        """Each head's K and V tiles of buffered page ``i``, and whether
+        they are (d, ps) rather than (ps, d)."""
         if not packed:
             ks, vs = bufs[2:] or (None, None)
-            return [(CL.kv_head(bufs[0], ks, i, h, dtype),
-                     CL.kv_head(bufs[1], vs, i, h, dtype))
-                    for h in range(hkv)]
+            out = []
+            for h in range(hkv):
+                k, rows_minor = CL.page_head(bufs[0], ks, i, h, dtype, *views)
+                v, _ = CL.page_head(bufs[1], vs, i, h, dtype, *views)
+                out.append((k, v, rows_minor))
+            return out
         # one pass over the page's 32-bit words: a row's heads 2p and 2p + 1
         # share a word, row p of every hkv/2 in the word view
         out = []
@@ -316,7 +324,7 @@ def _paged_kernel(tab_ref, len_ref, beta_ref, gamma_ref, q_ref, *refs,
                         else slice(None))
                 tiles += CL.unpack_head_pair(words[i, rows, :], dtype)
             out.append(tiles)
-        return zip(*out)
+        return [(k, v, False) for k, v in zip(*out)]
 
     @pl.loop(0, ppb)
     def _compute(i):
@@ -329,41 +337,52 @@ def _paged_kernel(tab_ref, len_ref, beta_ref, gamma_ref, q_ref, *refs,
                     c.wait()
             kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (g, ps), 1)
             mask = CL.kv_mask(n - 1, kpos, n, window)
-            for h, (k, v) in enumerate(heads(i)):
-                o_ref[0, h, i] = CL.consmax_partial(qs[h], k, v, mask,
-                                                    *params[h], **partial_kw)
+            for h, (k, v, rows_minor) in enumerate(heads(i)):
+                o_ref[0, h, i] = CL.consmax_partial(
+                    qs[h], k, v, mask, *params[h], rows_minor=rows_minor,
+                    **partial_kw)
 
         @pl.when(jnp.logical_not(live))
         def _():
             o_ref[0, :, i] = jnp.zeros((hkv, g, d), jnp.float32)
 
 
-def consmax_decode_paged(q, kp, vp, page_table, lengths, beta, gamma, *,
-                         window: int = 0, softcap: float = 0.0,
+def consmax_decode_paged(q, kp, vp, layer, page_table, lengths, beta, gamma,
+                         *, window: int = 0, softcap: float = 0.0,
                          merged: bool = True, scale: float | None = None,
                          fill_bound: bool = True, interpret: bool = False,
                          k_scale=None, v_scale=None):
-    """Paged split-KV ConSmax decode. q: (b, nh, d); kp, vp: shared page
-    pools (P, ps, nkv, d); page_table: (b, max_pages) int32 (-1 = unmapped);
-    lengths: (b,) valid logical rows; beta/gamma: (nh,) fp32.
-    ``k_scale``/``v_scale``: (P, ps, nkv) fp32 per-row-per-head quant scale
-    pools living beside the page table for a quantized (int8/fp8) KV pool —
-    fetched with their pages and upcast per page in VMEM.
+    """Paged split-KV ConSmax decode. q: (b, nh, d); kp, vp: every layer's
+    shared page pools, stacked (n_layers, P, ps, nkv, d); layer: int32
+    scalar, the layer whose pools this call reads; page_table:
+    (b, max_pages) int32 (-1 = unmapped); lengths: (b,) valid logical rows;
+    beta/gamma: (nh,) fp32. ``k_scale``/``v_scale``: (n_layers, P, ps, nkv)
+    fp32 per-row-per-head quant scale pools living beside the page table
+    for a quantized (int8/fp8) KV pool — fetched with their pages and
+    upcast per page in VMEM. A caller holding one layer's pools passes
+    ``pool[None]`` and layer 0.
 
-    Grid (b, runs): program (ib, jb) walks ``ppb`` consecutive columns of
-    slot ib's table row, which rides in as a scalar-prefetch operand — no
-    materialized per-slot contiguous cache. A bfloat16 pool with an even
-    head count and 128-lane head rows (``CL.packs_head_pairs``, qwen2-1.5b)
-    is read as ``(P, ps * nkv, d)``, the same bytes (a bitcast in XLA),
-    left in HBM (``memory_space=ANY``): each program DMAs the live pages
-    of a run of ``ppb`` columns, the most whose K and V fit
+    The stacked pools are the kernel's operands whole, and the layer rides
+    in as a scalar-prefetch operand beside the page table: no layer's pool
+    is sliced out (and so copied) for the call. Grid (b, runs): program
+    (ib, jb) walks ``ppb`` consecutive columns of slot ib's table row —
+    no materialized per-slot contiguous cache. A bfloat16 pool with an
+    even head count and 128-lane head rows (``CL.packs_head_pairs``,
+    qwen2-1.5b) is read as ``(n_layers, P, ps * nkv, d)``, the same bytes
+    (a bitcast in XLA), left in HBM (``memory_space=ANY``): each program
+    DMAs the layer's live pages for a run of ``ppb`` columns, the most
+    whose K and V fit
     ``PAGE_VMEM_BYTES``, and each page yields every head's ``(ps, d)``
     tile from one pass over its 32-bit words. Any other pool (8 KV heads
     of dk 64, int8, fp8) keeps ``ppb = 1``: its page, all heads, arrives
-    through the BlockSpec index map and is sliced per head. A manual DMA
-    of such a page would slice dims that are not tile-aligned, which
-    Mosaic refuses, and an aligned view costs XLA a whole-pool relayout
-    per call.
+    through the BlockSpec index map, ``(layer, page)`` with the layer dim
+    squeezed, and is sliced per head. Such pools, and their scale pools,
+    are read in the order XLA's TPU layout stores them in
+    (``CL.page_view``: granite's 8 heads of dk 64 and fp32 scales with
+    the page's rows on the lanes, qwen2's int8 pool head-major), a
+    bitcast of the stack there. A manual DMA of such a page would slice
+    dims that are not tile-aligned, which Mosaic refuses, and an aligned
+    view costs XLA a whole-pool relayout per call.
 
     Every grid dim stays ``parallel``: page partials are independent (no
     running max, no denominator), one fp32 partial per (slot, head,
@@ -377,7 +396,7 @@ def consmax_decode_paged(q, kp, vp, page_table, lengths, beta, gamma, *,
     entries contribute exact zeros either way.
     """
     b, nh, d = q.shape
-    P, ps, nkv = kp.shape[0], kp.shape[1], kp.shape[2]
+    n_layers, P, ps, nkv = kp.shape[:4]
     g = nh // nkv
     npg = page_table.shape[1]
     if scale is None:
@@ -385,12 +404,16 @@ def consmax_decode_paged(q, kp, vp, page_table, lengths, beta, gamma, *,
 
     tab = page_table.astype(jnp.int32)
     len1 = lengths.astype(jnp.int32)
+    lay = jnp.asarray(layer, jnp.int32).reshape(1)
     npg_live = (CL.live_blocks(jnp.max(len1), ps, npg) if fill_bound
                 else npg)
     pools = [kp, vp] + ([k_scale, v_scale] if k_scale is not None else [])
-    packed = len(pools) == 2 and CL.packs_head_pairs(kp.dtype, nkv, d)
+    views = (CL.page_view(kp.dtype, (ps, nkv, d)),
+             CL.page_view(jnp.float32, (ps, nkv)))
+    packed = (len(pools) == 2 and CL.packs_head_pairs(kp.dtype, nkv, d)
+              and views[0] == CL.ROW_MAJOR)
     if packed:
-        pools = [x.reshape(P, ps * nkv, d) for x in pools]
+        pools = [x.reshape(n_layers, P, ps * nkv, d) for x in pools]
         ppb = _pages_per_program(npg, 2 * CL.tiled_bytes((ps * nkv, d),
                                                          kp.dtype))
         pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
@@ -398,21 +421,26 @@ def consmax_decode_paged(q, kp, vp, page_table, lengths, beta, gamma, *,
                    + [pltpu.SemaphoreType.DMA((2, ppb))])
     else:
         ppb, scratch = 1, []
+        # the stored layouts' views: bitcasts on the TPU
+        pools = [CL.stored_view(x, views[c >= 2])
+                 for c, x in enumerate(pools)]
 
-        def page_map(ib, jb, tab_ref, len_ref):
-            return (jnp.maximum(tab_ref[ib, jb], 0), 0, 0, 0)
+        def page_map(ib, jb, tab_ref, len_ref, lay_ref):
+            return (lay_ref[0], jnp.maximum(tab_ref[ib, jb], 0), 0, 0, 0)
 
-        pool_specs = [pl.BlockSpec((1, *x.shape[1:]),
+        # the layer dim is squeezed: the kernel sees one page
+        pool_specs = [pl.BlockSpec((None, 1, *x.shape[2:]),
                                    lambda *a, n=x.ndim: page_map(*a)[:n])
                       for x in pools]
 
     kernel = functools.partial(_paged_kernel, scale=scale, window=window,
                                softcap=softcap, ps=ps, ppb=ppb, npg=npg,
-                               n_pools=len(pools), packed=packed, g=g,
-                               merged=merged, bounded=fill_bound)
+                               n_pools=len(pools), packed=packed,
+                               views=views, g=g, merged=merged,
+                               bounded=fill_bound)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                       # page table + lengths
+        num_scalar_prefetch=3,               # page table, lengths, layer
         grid=(b, (npg_live + ppb - 1) // ppb),
         in_specs=[smem, smem,                                   # beta, gamma
                   pl.BlockSpec((1, nkv, g, d),
@@ -429,7 +457,7 @@ def consmax_decode_paged(q, kp, vp, page_table, lengths, beta, gamma, *,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-    )(tab, len1, beta.astype(jnp.float32), gamma.astype(jnp.float32),
+    )(tab, len1, lay, beta.astype(jnp.float32), gamma.astype(jnp.float32),
       q.reshape(b, nkv, g, d), *pools)
 
     out = CL.fill_bounded_sum(partials, npg_live)    # the sync-free combine

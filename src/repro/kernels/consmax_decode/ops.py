@@ -1,7 +1,8 @@
 """Jitted public wrappers for the split-KV ConSmax decode kernel.
 
 Both kernels consume the model's cache layout — q (b, 1, H, dk), cache k/v
-(b, L, hkv, dk) (or the (P, ps, hkv, dk) page pools), per-slot cache
+(b, L, hkv, dk) (or the stacked (n_layers, P, ps, hkv, dk) page pools and
+a layer index), per-slot cache
 ``index`` (b,) — directly: the contiguous kernel blocks the hkv axis inside
 its grid, and the paged kernel walks runs of table columns, fetching whole
 pages of every head (by its own DMAs where a bf16 pool packs two heads a
@@ -52,24 +53,26 @@ def consmax_decode_op(q, k, v, index, beta, gamma, *, window=0, softcap=0.0,
 
 @partial(jax.jit, static_argnames=("window", "softcap", "merged", "scale",
                                    "fill_bound", "interpret"))
-def consmax_decode_paged_op(q, kp, vp, page_table, lengths, beta, gamma, *,
-                            window=0, softcap=0.0, merged=True, scale=None,
-                            fill_bound=True, interpret=None, k_scale=None,
-                            v_scale=None):
-    """Paged-pool variant. q: (b, 1, H, dk); kp, vp: shared page pools
-    (P, ps, hkv, dk) in the model's cache layout, each program walking a
-    run of table columns (the kernel says how it fetches and reads a
-    page); page_table: (b, max_pages) int32; lengths: (b,) valid logical
-    rows (index + active, already counting the token written this step).
+def consmax_decode_paged_op(q, kp, vp, layer, page_table, lengths, beta,
+                            gamma, *, window=0, softcap=0.0, merged=True,
+                            scale=None, fill_bound=True, interpret=None,
+                            k_scale=None, v_scale=None):
+    """Paged-pool variant. q: (b, 1, H, dk); kp, vp: every layer's shared
+    page pools, stacked (n_layers, P, ps, hkv, dk) in the model's cache
+    layout, read at ``layer`` (int32 scalar) in place, each program
+    walking a run of table columns (the kernel says how it fetches and
+    reads a page); page_table: (b, max_pages) int32; lengths: (b,) valid
+    logical rows (index + active, already counting the token written this
+    step).
 
     Returns (b, 1, H, dk) in q.dtype. ``fill_bound`` bounds the page-table
     walk by the traced batch-max fill instead of the table's capacity.
-    ``k_scale``/``v_scale``: (P, ps, hkv) fp32 scale pools for a quantized
-    KV pool, fetched with their pages through the table.
+    ``k_scale``/``v_scale``: (n_layers, P, ps, hkv) fp32 scale pools for a
+    quantized KV pool, fetched with their pages through the table.
     """
     interp = _on_cpu() if interpret is None else interpret
-    out = consmax_decode_paged(q[:, 0], kp, vp, page_table, lengths, beta,
-                               gamma, window=window, softcap=softcap,
+    out = consmax_decode_paged(q[:, 0], kp, vp, layer, page_table, lengths,
+                               beta, gamma, window=window, softcap=softcap,
                                merged=merged, scale=scale,
                                fill_bound=fill_bound, interpret=interp,
                                k_scale=k_scale, v_scale=v_scale)

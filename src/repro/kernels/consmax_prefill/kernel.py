@@ -38,8 +38,9 @@ parallel split costs ``ns`` output-sized fp32 partial buffers in HBM; pick
 caches.
 
 The paged variant walks *page-table entries* via a scalar-prefetch
-operand: program (ib, iq, ij) DMAs pool page ``page_table[ib, ij]`` (all
-heads) straight from HBM through its BlockSpec index map. Its page axis
+operand: program (ib, iq, ij) DMAs page ``page_table[ib, ij]`` (all
+heads) of the layer's pool straight from the stacked pools in HBM through
+its BlockSpec index map. Its page axis
 accumulates into VMEM scratch ('arbitrary' trailing dim) instead of
 per-page partial buffers: a chunk's partials are (c*g, d)-sized, so per-page slots would
 cost max_pages_per_slot × chunk-output HBM — at 500k context that is
@@ -80,17 +81,24 @@ MAX_KV_SHARDS = 64
 
 
 def _head_partials(q_ref, k_ref, v_ref, ks_ref, vs_ref, beta_ref,
-                   gamma_ref, mask, *, g: int, **partial_kw):
+                   gamma_ref, mask, *, g: int, views=None, **partial_kw):
     """Yield ``(h, partial)`` for every KV head of an all-heads block: the
-    chunk's (bqg, dk) q rows of head ``h`` against the block's rows."""
+    chunk's (bqg, dk) q rows of head ``h`` against the block's rows — a
+    ``(1, rows, hkv, dk)`` block, or a page read in ``views`` (the K/V
+    and the scale pools' ``CL.page_view``, ``CL.page_head``)."""
     bqg = q_ref.shape[2]
     for h in range(q_ref.shape[1]):
         q = q_ref[0, h]                              # (bqg, d)
+        if views is None:
+            k = CL.kv_head(k_ref, ks_ref, 0, h, q.dtype)
+            v, rows_minor = CL.kv_head(v_ref, vs_ref, 0, h, q.dtype), False
+        else:
+            k, rows_minor = CL.page_head(k_ref, ks_ref, 0, h, q.dtype, *views)
+            v, _ = CL.page_head(v_ref, vs_ref, 0, h, q.dtype, *views)
         yield h, CL.consmax_partial(
-            q, CL.kv_head(k_ref, ks_ref, 0, h, q.dtype),
-            CL.kv_head(v_ref, vs_ref, 0, h, q.dtype), mask,
-            CL.head_rows(beta_ref, h, g, bqg),
-            CL.head_rows(gamma_ref, h, g, bqg), **partial_kw)
+            q, k, v, mask, CL.head_rows(beta_ref, h, g, bqg),
+            CL.head_rows(gamma_ref, h, g, bqg), rows_minor=rows_minor,
+            **partial_kw)
 
 
 def _chunk_mask(idx, kvl, iq, start, rows, *, bqg: int, g: int,
@@ -219,8 +227,8 @@ def consmax_prefill(q, k, v, index, lengths, beta, gamma, *, window: int = 0,
 
 
 # ------------------------------------------------------------- paged KV ----
-def _paged_kernel(tab_ref, idx_ref, kvl_ref, beta_ref, gamma_ref, q_ref,
-                  k_ref, v_ref, *rest, window: int, bqg: int, ps: int,
+def _paged_kernel(tab_ref, idx_ref, kvl_ref, lay_ref, beta_ref, gamma_ref,
+                  q_ref, k_ref, v_ref, *rest, window: int, bqg: int, ps: int,
                   bq: int, g: int, bounded: bool, **partial_kw):
     *scale_refs, o_ref, acc_ref = rest               # quantized KV: (ks, vs)
     ks_ref, vs_ref = scale_refs or (None, None)
@@ -255,20 +263,29 @@ def _paged_kernel(tab_ref, idx_ref, kvl_ref, beta_ref, gamma_ref, q_ref,
         o_ref[0] = acc_ref[...]
 
 
-def consmax_prefill_paged(q, kp, vp, page_table, index, lengths, beta,
-                          gamma, *, window: int = 0, softcap: float = 0.0,
-                          merged: bool = True, scale: float | None = None,
-                          bq: int = 128, fill_bound: bool = True,
-                          interpret: bool = False, k_scale=None,
-                          v_scale=None):
-    """Paged fused prefill. q: (b, c, H, dk) chunk queries; kp, vp: shared
-    page pools (P, ps, hkv, dk) *after* the chunk's K/V were scattered in;
-    page_table: (b, max_pages) int32 (-1 = unmapped); index, lengths: (b,)
-    chunk start positions / real chunk lengths. Returns (b, c, H, dk).
-    ``k_scale``/``v_scale``: (P, ps, hkv) fp32 quant-scale pools beside the
-    page table for a quantized KV pool, gathered through the same page
-    index map and upcast per-page in VMEM.
+def consmax_prefill_paged(q, kp, vp, layer, page_table, index, lengths,
+                          beta, gamma, *, window: int = 0,
+                          softcap: float = 0.0, merged: bool = True,
+                          scale: float | None = None, bq: int = 128,
+                          fill_bound: bool = True, interpret: bool = False,
+                          k_scale=None, v_scale=None):
+    """Paged fused prefill. q: (b, c, H, dk) chunk queries; kp, vp: every
+    layer's shared page pools, stacked (n_layers, P, ps, hkv, dk), *after*
+    the chunk's K/V were scattered in; layer: int32 scalar, the layer this
+    call reads; page_table: (b, max_pages) int32 (-1 = unmapped); index,
+    lengths: (b,) chunk start positions / real chunk lengths. Returns
+    (b, c, H, dk). ``k_scale``/``v_scale``: (n_layers, P, ps, hkv) fp32
+    quant-scale pools beside the page table for a quantized KV pool,
+    gathered through the same page index map and upcast per-page in VMEM.
+    A caller holding one layer's pools passes ``pool[None]`` and layer 0.
 
+    The layer rides in as a scalar-prefetch operand beside the page table,
+    and the index map fetches page ``(layer, page)`` with the layer dim
+    squeezed: no layer's pool is sliced out (and so copied) for the call.
+    The pools and their scale pools are read in the order XLA's TPU layout
+    stores them in (``CL.page_view``: granite's 8 heads of dk 64 with the
+    page's rows on the lanes, qwen2's int8 pool head-major), a bitcast of
+    the stack there, so they are not relaid out either.
     The page axis is the grid's trailing 'arbitrary' dimension accumulating
     into VMEM scratch — a pure ``acc += p @ v`` per page, no (m, l) state —
     because per-page partial buffers would cost max_pages × chunk-output
@@ -285,7 +302,7 @@ def consmax_prefill_paged(q, kp, vp, page_table, index, lengths, beta,
     capacity sweep.
     """
     b, c, H, dk = q.shape
-    P, ps, hkv = kp.shape[0], kp.shape[1], kp.shape[2]
+    ps, hkv = kp.shape[2], kp.shape[3]
     g = H // hkv
     npg = page_table.shape[1]
     if scale is None:
@@ -297,35 +314,44 @@ def consmax_prefill_paged(q, kp, vp, page_table, index, lengths, beta,
     tab = page_table.astype(jnp.int32)
     idx1 = index.astype(jnp.int32)
     kvl1 = (index + lengths).astype(jnp.int32)
+    lay = jnp.asarray(layer, jnp.int32).reshape(1)
 
     npg_live = CL.live_blocks(jnp.max(kvl1), ps, npg) if fill_bound else npg
 
+    views = (CL.page_view(kp.dtype, (ps, hkv, dk)),
+             CL.page_view(jnp.float32, (ps, hkv)))
+    # the stored layouts' views: bitcasts on the TPU
+    kp, vp = (CL.stored_view(x, views[0]) for x in (kp, vp))
     kernel = functools.partial(_paged_kernel, scale=scale, window=window,
                                softcap=softcap, bqg=bqg, ps=ps, bq=bq, g=g,
-                               merged=merged, bounded=fill_bound)
+                               merged=merged, bounded=fill_bound,
+                               views=views)
 
-    def page_map(ib, iq, ij, tab_ref, idx_ref, kvl_ref):
-        return (jnp.maximum(tab_ref[ib, ij], 0), 0, 0, 0)
+    def page_map(ib, iq, ij, tab_ref, idx_ref, kvl_ref, lay_ref):
+        return (lay_ref[0], jnp.maximum(tab_ref[ib, ij], 0), 0, 0, 0)
 
-    def scale_page_map(ib, iq, ij, tab_ref, idx_ref, kvl_ref):
-        return (jnp.maximum(tab_ref[ib, ij], 0), 0, 0)
+    def scale_page_map(*ids):
+        return page_map(*ids)[:4]
 
+    # the layer dim is squeezed: the kernel sees one page
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [
         smem, smem,                                             # beta, gamma
         pl.BlockSpec((1, hkv, bqg, dk),
                      lambda ib, iq, ij, *_: (ib, 0, iq, 0)),    # q
-        pl.BlockSpec((1, ps, hkv, dk), page_map),               # k page
-        pl.BlockSpec((1, ps, hkv, dk), page_map),               # v page
+        pl.BlockSpec((None, 1, *kp.shape[2:]), page_map),       # k page
+        pl.BlockSpec((None, 1, *vp.shape[2:]), page_map),       # v page
     ]
     operands = [beta.astype(jnp.float32), gamma.astype(jnp.float32),
                 CL.fold_gqa(q, hkv), kp, vp]
     if k_scale is not None:
-        in_specs += [pl.BlockSpec((1, ps, hkv), scale_page_map)] * 2
-        operands += [k_scale, v_scale]
+        scales = [CL.stored_view(x, views[1]) for x in (k_scale, v_scale)]
+        in_specs += [pl.BlockSpec((None, 1, *scales[0].shape[2:]),
+                                  scale_page_map)] * 2
+        operands += scales
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,                       # table, index, kv_len
+        num_scalar_prefetch=4,                # table, index, kv_len, layer
         grid=(b, nq, npg_live),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hkv, bqg, dk),
@@ -339,6 +365,6 @@ def consmax_prefill_paged(q, kp, vp, page_table, index, lengths, beta,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(tab, idx1, kvl1, *operands)
+    )(tab, idx1, kvl1, lay, *operands)
 
     return CL.unfold_gqa(out, b, c, H).astype(q.dtype)

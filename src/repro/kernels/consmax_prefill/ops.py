@@ -1,11 +1,11 @@
 """Jitted public wrappers for the fused ConSmax prefill kernels.
 
 Both wrappers consume the model's serving layouts directly — q chunk
-(b, c, H, dk), contiguous cache (b, L, hkv, dk) or page pools
-(P, ps, hkv, dk) plus a page table — so the hot path pays no layout copy
-(mirror of ../consmax_decode/ops.py). On CPU (this container) the kernel
-body executes in interpret mode; on a real TPU backend it compiles through
-Mosaic.
+(b, c, H, dk), contiguous cache (b, L, hkv, dk) or stacked page pools
+(n_layers, P, ps, hkv, dk) plus a layer index and a page table — so the
+hot path pays no layout copy (mirror of ../consmax_decode/ops.py). On CPU
+(this container) the kernel body executes in interpret mode; on a real TPU
+backend it compiles through Mosaic.
 """
 from __future__ import annotations
 
@@ -49,21 +49,23 @@ def consmax_prefill_op(q, k, v, index, lengths, beta, gamma, *, window=0,
 
 @partial(jax.jit, static_argnames=("window", "softcap", "merged", "scale",
                                    "bq", "fill_bound", "interpret"))
-def consmax_prefill_paged_op(q, kp, vp, page_table, index, lengths, beta,
-                             gamma, *, window=0, softcap=0.0, merged=True,
-                             scale=None, bq=128, fill_bound=True,
+def consmax_prefill_paged_op(q, kp, vp, layer, page_table, index, lengths,
+                             beta, gamma, *, window=0, softcap=0.0,
+                             merged=True, scale=None, bq=128, fill_bound=True,
                              interpret=None, k_scale=None, v_scale=None):
-    """Paged-pool variant. kp, vp: shared (P, ps, hkv, dk) pools in the
-    model's cache layout (never copied — the kernel walks page-table
-    entries via scalar prefetch); page_table: (b, max_pages) int32.
-    Returns (b, c, H, dk) in q.dtype. ``fill_bound`` bounds the page walk
-    by the traced batch-max fill instead of the table's capacity.
-    ``k_scale``/``v_scale``: (P, ps, hkv) fp32 scale pools for a quantized
-    KV pool, gathered through the same page-table index map.
+    """Paged-pool variant. kp, vp: every layer's shared pools, stacked
+    (n_layers, P, ps, hkv, dk) in the model's cache layout and read at
+    ``layer`` (int32 scalar) — never sliced or copied: the kernel walks
+    page-table entries via scalar prefetch; page_table: (b, max_pages)
+    int32. Returns (b, c, H, dk) in q.dtype. ``fill_bound`` bounds the
+    page walk by the traced batch-max fill instead of the table's
+    capacity. ``k_scale``/``v_scale``: (n_layers, P, ps, hkv) fp32 scale
+    pools for a quantized KV pool, gathered through the same index map.
     """
     interp = _on_cpu() if interpret is None else interpret
-    return consmax_prefill_paged(q, kp, vp, page_table, index, lengths,
-                                 beta, gamma, window=window, softcap=softcap,
-                                 merged=merged, scale=scale, bq=bq,
-                                 fill_bound=fill_bound, interpret=interp,
+    return consmax_prefill_paged(q, kp, vp, layer, page_table, index,
+                                 lengths, beta, gamma, window=window,
+                                 softcap=softcap, merged=merged, scale=scale,
+                                 bq=bq, fill_bound=fill_bound,
+                                 interpret=interp,
                                  k_scale=k_scale, v_scale=v_scale)

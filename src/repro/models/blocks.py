@@ -129,11 +129,13 @@ def block_apply(p, x, cfg: ModelConfig, kind: str, *, positions=None,
                 kv_chunk=1024, decode_kernel=False, decode_kv_block=256,
                 prefill_kernel=False, prefill_kv_block=512, fill_bound=True,
                 prefill_append=None, decode_active=None, page_table=None,
-                psum_axes=(), moe_counts=False):
+                layer=None, psum_axes=(), moe_counts=False):
     """Returns (x, new_cache, aux_losses, counts): ``counts`` is the
     dropless expert layer's (rows routed, experts hit), (2,) int32, with
     ``moe_counts`` on an expert layer, else None. An expert layer routes
-    only the real rows of a prefill chunk and the active decode slots."""
+    only the real rows of a prefill chunk and the active decode slots.
+    With ``page_table``, ``cache`` holds every layer's paged pools,
+    stacked, and the block works on layer ``layer`` of them."""
     aux = jnp.zeros((), jnp.float32)
     counts = None
     new_cache = cache
@@ -151,7 +153,7 @@ def block_apply(p, x, cfg: ModelConfig, kind: str, *, positions=None,
             prefill_kv_block=prefill_kv_block, fill_bound=fill_bound,
             prefill_append=prefill_append,
             decode_active=decode_active, page_table=page_table,
-            psum_axes=psum_axes)
+            layer=layer, psum_axes=psum_axes)
         if cfg.post_block_norm:
             h = L.norm_apply(p["attn_post_norm"], h, kind=cfg.norm)
         x = x + h

@@ -58,6 +58,10 @@ def lm_apply(p, cfg: ModelConfig, *, tokens=None, embeds=None, cond=None,
 
     tokens: (b, s) int ids (token frontend) | embeds: (b, s, d) stub frontends.
     caches: per-super-layer pytree with leading dim n_super (decode), or None.
+    Contiguous caches are scanned over with the parameters, one layer's
+    slice in and out per step; paged pools (``page_table``) are carried
+    through the scan whole with the layer index, and each layer writes
+    and reads its own pool in place.
     logits_index: traced position — unembed only that row (serving prefill on
     a padded prompt, where the last real token is mid-sequence). A scalar
     selects one row for the whole batch; a (b,) array gathers per-batch rows
@@ -110,7 +114,7 @@ def lm_apply(p, cfg: ModelConfig, *, tokens=None, embeds=None, cond=None,
                              positions=positions)
         x = shard(x, "act_batch,act_seq,act_embed")
 
-    def super_step(x, bp, cache_in):
+    def super_step(x, bp, cache_in, layer=None):
         new_caches = {} if cache_in is not None else None
         aux = jnp.zeros((), jnp.float32)
         counts = jnp.zeros((2,), jnp.int32) if moe_counts else None
@@ -123,7 +127,7 @@ def lm_apply(p, cfg: ModelConfig, *, tokens=None, embeds=None, cond=None,
                 prefill_kernel=prefill_kernel,
                 prefill_kv_block=prefill_kv_block, fill_bound=fill_bound,
                 prefill_append=prefill_append, decode_active=decode_active,
-                page_table=page_table, psum_axes=psum_axes,
+                page_table=page_table, layer=layer, psum_axes=psum_axes,
                 moe_counts=moe_counts)
             aux = aux + a
             if n is not None:
@@ -144,6 +148,18 @@ def lm_apply(p, cfg: ModelConfig, *, tokens=None, embeds=None, cond=None,
         with jax.named_scope("layers"):
             x, (auxs, counts) = jax.lax.scan(body, x, p["blocks"])
         new_caches = None
+    elif page_table is not None:
+        # paged pools ride the carry whole, beside the layer index: each
+        # layer scatters its new rows into, and the kernels read, its own
+        # pool at [l] in place, so no step slices, re-stacks or copies a
+        # pool
+        def body(carry, bp):
+            x, l, c = carry
+            y, c, stats = super_step(x, bp, c, l)
+            return (y, l + 1, c), stats
+        with jax.named_scope("layers"):
+            (x, _, new_caches), (auxs, counts) = jax.lax.scan(
+                body, (x, jnp.zeros((), jnp.int32), caches), p["blocks"])
     else:
         def body(x, xs):
             bp, ci = xs

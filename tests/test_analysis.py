@@ -23,6 +23,7 @@ from repro.analysis.kernel_contracts import (BlockInfo, KernelLaunch,
 from repro.analysis.trace_guard import TraceGuard
 from repro.configs.base import ServeConfig
 from repro.configs.registry import get_config
+from repro.kernels import cache_layout as CL
 from repro.launch import analyze
 from repro.models import transformer as T
 from repro.nn.module import Ctx
@@ -263,17 +264,24 @@ def test_real_serving_kernel_launches_pass_all_contracts(paged):
         assert not check_launch(launch), label
         # one program per (slot or slot-group, q-block, KV shard or page)
         # covering ALL kv heads: no hkv grid axis, and every K/V block ends
-        # in the full (hkv, dk) extents the TPU tiling rule accepts
+        # in full extents the TPU tiling rule accepts — (hkv, dk), or for
+        # a pool read in another stored order (page rows, dk) / (dk, page
+        # rows)
         assert len(launch.grid) == (2 if label.startswith("decode") else 3)
+        view = CL.page_view(CL.kv_cache_dtype(scfg.kv_cache_dtype),
+                            (scfg.page_size, hkv, d))
+        minor = ((hkv, d) if not paged or view == CL.ROW_MAJOR else
+                 (scfg.page_size, d) if view == CL.HEAD_MAJOR else
+                 (d, scfg.page_size))
         kv = [b for b in launch.in_blocks if len(b.block_shape) == 4
-              and b.block_shape[-2:] == (hkv, d) and b.dtype != "float32"]
+              and b.block_shape[-2:] == minor and b.dtype != "float32"]
         assert len(kv) == 2, (label, [b.block_shape for b in
                                       launch.in_blocks])
         assert all(b.memory_space == "smem" for b in launch.in_blocks
                    if len(b.block_shape) == 1)      # beta/gamma (+ scalars)
-    if paged:
-        assert launches[f"decode_{kind}"].num_scalar_prefetch == 2
-        assert launches[f"prefill_{kind}"].num_scalar_prefetch == 3
+    if paged:        # the page table, the fills and the layer index
+        assert launches[f"decode_{kind}"].num_scalar_prefetch == 3
+        assert launches[f"prefill_{kind}"].num_scalar_prefetch == 4
         assert launches[f"prefill_{kind}"].dimension_semantics[-1] == \
             "arbitrary"
         assert launches[f"prefill_{kind}"].scratch_bytes > 0
@@ -292,8 +300,8 @@ def test_paged_decode_page_runs_pass_contracts_at_published_width():
     launch = serving_launches(cfg, scfg)["decode_paged"]
     assert not check_launch(launch)
     assert [b.memory_space for b in launch.in_blocks[-2:]] == ["any", "any"]
-    pages = launch.in_blocks[-1].block_shape[0]
-    assert pages == scfg.num_pages
+    layers, pages = launch.in_blocks[-1].block_shape[:2]   # every layer's
+    assert (layers, pages) == (cfg.n_super_layers, scfg.num_pages)
     assert 0 < launch.scratch_bytes < launch.vmem_working_set() < 16 << 20
 
 

@@ -161,12 +161,13 @@ def test_decode_paged_fill_sweep_bit_parity_and_oracle(fill, variant,
         # the oracle reads what the table maps: unmapped columns page 0
         k, v = (x[jnp.maximum(tab, 0)].reshape(B, -1, *x.shape[2:])
                 for x in (kseen, vseen))
-    bounded = consmax_decode_paged(q, kp, vp, tab, lens, beta, gamma,
-                                   fill_bound=True, interpret=True, **kw,
-                                   **scales)
-    capacity = consmax_decode_paged(q, kp, vp, tab, lens, beta, gamma,
-                                    fill_bound=False, interpret=True, **kw,
-                                    **scales)
+    scales = {n: s[None] for n, s in scales.items()}     # one layer
+    bounded = consmax_decode_paged(q, kp[None], vp[None], 0, tab, lens,
+                                   beta, gamma, fill_bound=True,
+                                   interpret=True, **kw, **scales)
+    capacity = consmax_decode_paged(q, kp[None], vp[None], 0, tab, lens,
+                                    beta, gamma, fill_bound=False,
+                                    interpret=True, **kw, **scales)
     np.testing.assert_array_equal(np.asarray(bounded), np.asarray(capacity))
     ref = consmax_decode_ref(q, k.swapaxes(1, 2), v.swapaxes(1, 2), lens,
                              beta, gamma, **kw)
@@ -212,12 +213,12 @@ def test_prefill_paged_fill_sweep_bit_parity_and_oracle(fill, variant):
                                                           seed=3)
     kw = VARIANTS[variant]
     kp, vp, tab = _paged(k, v, index + lengths)
-    bounded = consmax_prefill_paged(q, kp, vp, tab, index, lengths, beta,
-                                    gamma, bq=4, fill_bound=True,
-                                    interpret=True, **kw)
-    capacity = consmax_prefill_paged(q, kp, vp, tab, index, lengths, beta,
-                                     gamma, bq=4, fill_bound=False,
-                                     interpret=True, **kw)
+    bounded = consmax_prefill_paged(q, kp[None], vp[None], 0, tab, index,
+                                    lengths, beta, gamma, bq=4,
+                                    fill_bound=True, interpret=True, **kw)
+    capacity = consmax_prefill_paged(q, kp[None], vp[None], 0, tab, index,
+                                     lengths, beta, gamma, bq=4,
+                                     fill_bound=False, interpret=True, **kw)
     ref = consmax_prefill_ref(q, k, v, index, lengths, beta, gamma, **kw)
     for ib in range(B):
         n = int(lengths[ib])

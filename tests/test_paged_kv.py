@@ -476,12 +476,215 @@ def test_paged_decode_kernel_matches_jnp_paged_attention():
     gamma = jnp.full((H,), 100.0)
     params = {"beta": beta, "gamma": gamma}
     for window, softcap in ((0, 0.0), (6, 0.0), (0, 30.0)):
-        ref = paged_attention(q, kp, vp, table, index,
+        ref = paged_attention(q, kp[None], vp[None], 0, table, index,
                               jnp.ones((b,), jnp.int32),
                               norm_kind="consmax", norm_params=params,
                               window=window, softcap=softcap, merged=True)
-        got = consmax_decode_paged_op(q, kp, vp, table, index + 1, beta,
-                                      gamma, window=window, softcap=softcap,
-                                      merged=True, scale=1.0)
+        got = consmax_decode_paged_op(q, kp[None], vp[None], 0, table,
+                                      index + 1, beta, gamma, window=window,
+                                      softcap=softcap, merged=True,
+                                      scale=1.0)
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(ref, np.float32), atol=1e-5)
+
+
+# ------------------------------------------- layer-indexed paged kernels ----
+# (H, hkv, dk, pool dtype, window): the packed-DMA decode path (qwen2-1.5b),
+# the BlockSpec path (granite-3-2b), an int8 pool with its scale pools, and
+# mellum2-12b-a2.5b's 1024-row window
+LAYER_CASES = {"packed_qwen2": (12, 2, 128, "bfloat16", 0),
+               "blockspec_granite": (32, 8, 64, "bfloat16", 0),
+               "int8_scales": (12, 2, 128, "int8", 0),
+               "window_mellum2": (32, 4, 128, "bfloat16", 1024)}
+
+
+def _stacked_pools(case, n_layers=3, ps=128, npg=10, b=2):
+    """Three layers of distinct pools, a shuffled table and the per-slot
+    fills; quantized pools come with their scale pools."""
+    from repro.kernels import cache_layout as CL
+    H, hkv, dk, dtype, window = LAYER_CASES[case]
+    P = b * npg + 1
+    key = random.key(5)
+    pools, scales = [], {}
+    for name, i in (("k", 1), ("v", 2)):
+        x = random.normal(random.fold_in(key, i), (n_layers, P, ps, hkv, dk))
+        if dtype == "int8":
+            x, scales[f"{name}_scale"] = CL.quantize_kv(
+                x, CL.kv_cache_dtype(dtype))
+        pools.append(x.astype(CL.kv_cache_dtype(dtype)))
+    perm = np.random.default_rng(0).permutation(P - 1) + 1
+    table = jnp.asarray(perm[:b * npg].reshape(b, npg), jnp.int32)
+    lengths = jnp.asarray([1200, 700], jnp.int32)[:b]
+    beta = jnp.linspace(0.5, 2.5, H)
+    gamma = jnp.full((H,), 100.0)
+    return (*pools, scales, table, lengths, beta, gamma, H, dk, window)
+
+
+@pytest.mark.parametrize("case", sorted(LAYER_CASES))
+def test_layer_indexed_paged_decode_equals_the_one_layer_call(case):
+    """The paged decode kernel on the stacked pools at layer ``l`` is
+    bit-identical to the same kernel on ``pool[l]`` alone."""
+    from repro.kernels.consmax_decode.kernel import consmax_decode_paged
+    kp, vp, scales, table, lengths, beta, gamma, H, dk, window = (
+        _stacked_pools(case))
+    q = random.normal(random.key(6), (table.shape[0], H, dk)
+                      ).astype(jnp.bfloat16)
+    kw = dict(window=window, interpret=True)
+    for l in (1, 2):
+        got = consmax_decode_paged(q, kp, vp, l, table, lengths, beta, gamma,
+                                   **scales, **kw)
+        one = consmax_decode_paged(
+            q, kp[l][None], vp[l][None], 0, table, lengths, beta, gamma,
+            **{n: s[l][None] for n, s in scales.items()}, **kw)
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(one, np.float32))
+
+
+@pytest.mark.parametrize("case", sorted(LAYER_CASES))
+def test_layer_indexed_paged_prefill_equals_the_one_layer_call(case):
+    """The paged prefill kernel on the stacked pools at layer ``l`` is
+    bit-identical to the same kernel on ``pool[l]`` alone."""
+    from repro.kernels.consmax_prefill.kernel import consmax_prefill_paged
+    kp, vp, scales, table, lengths, beta, gamma, H, dk, window = (
+        _stacked_pools(case))
+    c = 16
+    q = random.normal(random.key(7), (table.shape[0], c, H, dk)
+                      ).astype(jnp.bfloat16)
+    index, chunk = lengths - c, jnp.full_like(lengths, c)
+    kw = dict(window=window, bq=8, interpret=True)
+    l = 2
+    got = consmax_prefill_paged(q, kp, vp, l, table, index, chunk, beta,
+                                gamma, **scales, **kw)
+    one = consmax_prefill_paged(
+        q, kp[l][None], vp[l][None], 0, table, index, chunk, beta, gamma,
+        **{n: s[l][None] for n, s in scales.items()}, **kw)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(one, np.float32))
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_rows_minor_paged_kernels_match_paged_attention(kernel):
+    """At granite-3-2b's widths (8 bfloat16 KV heads of dk 64, pages of 128
+    rows) the paged kernels read each layer through the pools' rows-minor
+    view, as compiled for the TPU, and give the jnp ``paged_attention`` of
+    that layer (equal on a CPU); another layer's pools, or K read as V,
+    give outputs more than 2 apart."""
+    from repro.core.attention import paged_attention
+    from repro.kernels import cache_layout as CL
+    from repro.kernels.consmax_decode.kernel import consmax_decode_paged
+    from repro.kernels.consmax_prefill.kernel import consmax_prefill_paged
+    kp, vp, _, table, lengths, beta, gamma, H, dk, _ = (
+        _stacked_pools("blockspec_granite"))
+    assert CL.page_view(kp.dtype, kp.shape[2:]) == CL.ROWS_MINOR
+    b = table.shape[0]
+    c = 1 if kernel == "decode" else 16
+    q = (random.normal(random.key(10), (b, c, H, dk)) * dk ** -0.5
+         ).astype(jnp.bfloat16)
+    index, chunk = lengths - c, jnp.full_like(lengths, c)
+    params = {"beta": beta, "gamma": gamma}
+    for l in (0, 2):
+        want = paged_attention(q, kp, vp, l, table, index, chunk,
+                               norm_kind="consmax", norm_params=params,
+                               merged=True)
+        if kernel == "decode":
+            got = consmax_decode_paged(q[:, 0], kp, vp, l, table, lengths,
+                                       beta, gamma, scale=1.0,
+                                       interpret=True)[:, None]
+        else:
+            got = consmax_prefill_paged(q, kp, vp, l, table, index, chunk,
+                                        beta, gamma, scale=1.0, bq=8,
+                                        interpret=True)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32), atol=1e-5)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_decode_step_writes_each_layer_row_into_its_own_pool(kv_dtype):
+    """One paged decode step through ``lm_apply`` (three layers, the pools
+    carried through the layer scan): layer ``l`` writes exactly its own
+    pool's row at each slot's index, every other row of every layer is
+    bit-unchanged, and every layer's index advances by one."""
+    cfg = get_config("qwen2-1.5b", smoke=True, n_layers=3)
+    params = T.lm_init(Ctx(random.key(0)), cfg)
+    b, P, ps = 2, 6, 4
+    caches = T.init_paged_caches(cfg, b, P, ps, kv_dtype=kv_dtype)
+    key = random.key(1)
+    caches = {blk: {"attn": {
+        name: (a if name == "index" else
+               random.normal(random.fold_in(key, i), a.shape).astype(a.dtype))
+        for i, (name, a) in enumerate(leaves["attn"].items())}}
+        for blk, leaves in caches.items()}
+    index = jnp.asarray([5, 2], jnp.int32)
+    caches = T.set_slot_index(T.set_slot_index(caches, 0, 5), 1, 2)
+    table = jnp.asarray([[3, 1, -1], [0, 4, -1]], jnp.int32)
+    tokens = jnp.asarray([[7], [11]], jnp.int32)
+    _, new, _ = T.lm_apply(params, cfg, tokens=tokens, caches=caches,
+                           positions=index[:, None], merged=True,
+                           page_table=table, decode_kernel=True)
+    written = {(int(table[s, int(index[s]) // ps]), int(index[s]) % ps)
+               for s in range(b)}
+    for name in caches["b0"]["attn"]:
+        old = np.asarray(caches["b0"]["attn"][name])
+        got = np.asarray(new["b0"]["attn"][name])
+        if name == "index":
+            np.testing.assert_array_equal(got, old + 1)
+            continue
+        rows = (old != got).reshape(old.shape[:4] + (-1,)).any(-1)
+        rows = rows.reshape(old.shape[0], P, ps, -1).any(-1)
+        for l in range(cfg.n_layers):
+            changed = {tuple(map(int, r)) for r in np.argwhere(rows[l])}
+            assert changed == written, (name, l, changed)
+        # each layer's own projection: no layer's rows landed in another's
+        pages, page_rows = zip(*sorted(written))
+        fresh = got[:, list(pages), list(page_rows)]
+        assert all(not np.array_equal(fresh[l], fresh[l + 1])
+                   for l in range(cfg.n_layers - 1)), name
+
+
+def test_rows_minor_tiles_give_the_row_major_partial():
+    """A head's (dk, rows) K^T / V^T tiles, as the paged kernels read a
+    pool XLA stores rows-minor, give the partial of its (rows, dk) tiles."""
+    from repro.kernels import cache_layout as CL
+    key = random.key(8)
+    g, rows, hkv, dk = 4, 16, 8, 64
+    q = random.normal(random.fold_in(key, 1), (g, dk)).astype(jnp.bfloat16)
+    k, v = (random.normal(random.fold_in(key, i), (1, rows, hkv, dk)
+                          ).astype(jnp.bfloat16) for i in (2, 3))
+    mask = jnp.arange(rows)[None, :] < jnp.asarray([[11], [16], [3], [0]])
+    beta, gamma = jnp.full((g, 1), 1.5), jnp.full((g, 1), 100.0)
+    kw = dict(scale=1.0, softcap=0.0, merged=True)
+    for h in (0, 5):
+        want = CL.consmax_partial(
+            q, CL.kv_head(k, None, 0, h, q.dtype),
+            CL.kv_head(v, None, 0, h, q.dtype), mask, beta, gamma, **kw)
+        kt, vt = (jnp.moveaxis(x, 1, -1) for x in (k, v))
+        views = (CL.ROWS_MINOR, CL.ROWS_MINOR)
+        (kh, rows_minor), (vh, _) = (
+            CL.page_head(x, None, 0, h, q.dtype, *views) for x in (kt, vt))
+        assert rows_minor
+        got = CL.consmax_partial(q, kh, vh, mask, beta, gamma,
+                                 rows_minor=True, **kw)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("c,index,lengths", [
+    (1, [5, 0, 17], [1, 0, 1]),              # decode, one slot inactive
+    (8, [3, 0, 12], [8, 5, 2]),              # chunks across page edges
+    (8, [14, 16, 40], [8, 8, 8])])           # into unmapped columns
+def test_page_writes_equal_row_writes(c, index, lengths):
+    """Writing whole pages (the write for a pool XLA stores rows-minor)
+    leaves the stacked pool bit-identical to the row scatter: fresh rows
+    land at their pages, pad rows and unmapped columns write nothing."""
+    from repro.core.attention import _paged_cache_write, _paged_page_write
+    n, P, ps, hkv, dk = 3, 12, 8, 2, 16
+    key = random.key(9)
+    pool = random.normal(random.fold_in(key, 1), (n, P, ps, hkv, dk))
+    new = random.normal(random.fold_in(key, 2), (3, c, hkv, dk))
+    table = jnp.asarray([[3, 1, -1, 7], [0, 4, 9, -1], [2, 5, 6, 8]],
+                        jnp.int32)
+    index, lengths = (jnp.asarray(x, jnp.int32) for x in (index, lengths))
+    rows = _paged_cache_write(pool, new, 1, index, lengths, table)
+    pages = _paged_page_write(pool, new, 1, index, lengths, table)
+    np.testing.assert_array_equal(np.asarray(pages), np.asarray(rows))
+    assert not np.array_equal(np.asarray(rows), np.asarray(pool))

@@ -147,10 +147,11 @@ def test_prefill_paged_kernel_matches_paged_attention(window, softcap):
     beta = jnp.linspace(0.5, 2.5, H)
     gamma = jnp.full((H,), 100.0)
     params = {"beta": beta, "gamma": gamma}
-    got = consmax_prefill_paged_op(q, kp, vp, table, index, lengths, beta,
-                                   gamma, window=window, softcap=softcap,
-                                   merged=True, scale=1.0, bq=2)
-    oracle = paged_attention(q, kp, vp, table, index, lengths,
+    got = consmax_prefill_paged_op(q, kp[None], vp[None], 0, table, index,
+                                   lengths, beta, gamma, window=window,
+                                   softcap=softcap, merged=True, scale=1.0,
+                                   bq=2)
+    oracle = paged_attention(q, kp[None], vp[None], 0, table, index, lengths,
                              norm_kind="consmax", norm_params=params,
                              window=window, softcap=softcap, merged=True)
     np.testing.assert_allclose(np.asarray(got, np.float32),

@@ -163,9 +163,11 @@ def test_decode_paged_kernel_quantized_bitexact_vs_dequantized(name):
     lengths = jnp.asarray([20, 11], jnp.int32)
     beta = jnp.linspace(0.5, 2.5, nh)
     gamma = jnp.full((nh,), 100.0)
-    out = consmax_decode_paged_op(q, kq, vq, table, lengths, beta, gamma,
-                                  k_scale=ks, v_scale=vs)
-    yard = consmax_decode_paged_op(q, kd, vd, table, lengths, beta, gamma)
+    out = consmax_decode_paged_op(q, kq[None], vq[None], 0, table, lengths,
+                                  beta, gamma, k_scale=ks[None],
+                                  v_scale=vs[None])
+    yard = consmax_decode_paged_op(q, kd[None], vd[None], 0, table, lengths,
+                                   beta, gamma)
     np.testing.assert_array_equal(np.asarray(out, np.float32),
                                   np.asarray(yard, np.float32))
 
@@ -204,11 +206,56 @@ def test_prefill_paged_kernel_quantized_bitexact_vs_dequantized(name):
     lengths = jnp.asarray([4, 2, 4], jnp.int32)
     beta = jnp.linspace(0.5, 2.5, H)
     gamma = jnp.full((H,), 100.0)
-    out = consmax_prefill_paged_op(q, kq, vq, table, index, lengths, beta,
-                                   gamma, scale=1.0, bq=2,
-                                   k_scale=ks, v_scale=vs)
-    yard = consmax_prefill_paged_op(q, kd, vd, table, index, lengths, beta,
-                                    gamma, scale=1.0, bq=2)
+    out = consmax_prefill_paged_op(q, kq[None], vq[None], 0, table, index,
+                                   lengths, beta, gamma, scale=1.0, bq=2,
+                                   k_scale=ks[None], v_scale=vs[None])
+    yard = consmax_prefill_paged_op(q, kd[None], vd[None], 0, table, index,
+                                    lengths, beta, gamma, scale=1.0, bq=2)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(yard, np.float32))
+
+
+# (hkv, dk): pages of 128 rows that XLA's TPU layout stores head-major (2
+# heads of dk 128, qwen2-1.5b's int8 pool) and rows-minor (8 heads of dk
+# 64); their fp32 scale pages rows-minor
+STORED_VIEWS = {"head_major_2x128": (2, 128), "rows_minor_8x64": (8, 64)}
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+@pytest.mark.parametrize("widths", sorted(STORED_VIEWS))
+@pytest.mark.parametrize("name", QDTYPES)
+def test_paged_kernels_quantized_bitexact_in_stored_views(kernel, widths,
+                                                           name):
+    """Both paged kernels read a quantized pool, and its scales, in the
+    order XLA's TPU layout stores them in (``CL.page_view``), transposing
+    a page's codes where they lie across their scales: still bit-identical
+    to the kernel fed the dequantized pool."""
+    hkv, dk = STORED_VIEWS[widths]
+    b, c, H, ps, P = 2, 4, 2 * hkv, 128, 7
+    dt = CL.kv_cache_dtype(name)
+    assert CL.page_view(dt, (ps, hkv, dk)) != CL.ROW_MAJOR
+    assert CL.page_view(jnp.float32, (ps, hkv)) == CL.ROWS_MINOR
+    key = random.key(4)
+    q = (random.normal(random.fold_in(key, 1), (b, c, H, dk)) * 0.3
+         ).astype(jnp.bfloat16)
+    kq, ks, kd = _quant(random.fold_in(key, 2), (2, P, ps, hkv, dk), name)
+    vq, vs, vd = _quant(random.fold_in(key, 3), (2, P, ps, hkv, dk), name)
+    table = jnp.asarray([[3, 1, 6], [5, 0, -1]], jnp.int32)
+    index = jnp.asarray([300, 140], jnp.int32)
+    lengths = jnp.asarray([c, 2], jnp.int32)
+    beta = jnp.linspace(0.5, 2.5, H)
+    gamma = jnp.full((H,), 100.0)
+    if kernel == "decode":
+        def run(k, v, **sc):
+            return consmax_decode_paged_op(q[:, :1], k, v, 1, table,
+                                           index + 1, beta, gamma, **sc)
+    else:
+        def run(k, v, **sc):
+            return consmax_prefill_paged_op(q, k, v, 1, table, index,
+                                            lengths, beta, gamma, scale=1.0,
+                                            bq=2, **sc)
+    out = run(kq, vq, k_scale=ks, v_scale=vs)
+    yard = run(kd, vd)
     np.testing.assert_array_equal(np.asarray(out, np.float32),
                                   np.asarray(yard, np.float32))
 
